@@ -210,6 +210,8 @@ def run(smoke: bool = False, out_path: str = OUT_PATH):
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     out = OUT_PATH
     if "--out" in sys.argv:
         out = sys.argv[sys.argv.index("--out") + 1]

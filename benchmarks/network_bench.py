@@ -536,6 +536,8 @@ def run(smoke: bool = False, train: bool = False, serving: bool = False):
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     print("name,us_per_call,derived")
     run(smoke="--smoke" in sys.argv, train="--train" in sys.argv,
         serving="--serving" in sys.argv)

@@ -20,6 +20,8 @@ import traceback
 def main() -> None:
     from benchmarks import (attention_bench, kernel_bench, moe_bench,
                             network_bench, paper_table1, roofline_table)
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     print("name,us_per_call,derived")
     suites = [
         ("paper_table1", paper_table1.run),

@@ -40,7 +40,7 @@ from typing import List, Optional, Sequence, Tuple
 from repro import obs
 from repro.core import banking, perfmodel
 from repro.core.banking import TilePlan
-from repro.kernels.ref import check_groups, conv_out_shape, grouped_banks
+from repro.kernels.ref import check_groups, conv_out_shape, lane_legal_banks
 
 SCHEDULER_MODES = ("batch", "kout", "spatial")
 CORE_COUNTS = (1, 2, 4, 8, 16, 20)
@@ -73,15 +73,17 @@ def _divisors(n: int) -> List[int]:
 def candidate_states(oh: int, ow: int, cgrp: int, k: int, groups: int,
                      pool: bool) -> List[Tuple[int, int, int, int]]:
     """All legal (h_tile, w_tile, cin_banks, kout_banks) states for one
-    layer: tile extents from the pool-aligned halving chains, cin banks
-    any divisor of the per-group channel slice, kout banks any
-    group-aligned divisor of K (``kout_banks = groups · m`` with ``m``
-    dividing ``K/groups`` — a bank never straddles a group boundary)."""
-    kouts = [groups * m for m in _divisors(k // groups)]
-    cins = _divisors(cgrp)
-    return [(th, tw, cb, kb)
+    layer: tile heights from the pool-aligned halving chain over the full
+    map width (the tiling ``plan_tiles`` descends), cin banks any
+    lane-legal divisor of the per-group channel slice, kout banks any
+    group-aligned lane-legal divisor of K (``kout_banks = groups · m``
+    with ``m`` dividing ``K/groups`` — a bank never straddles a group
+    boundary)."""
+    kouts = [groups * m for m in _divisors(k // groups)
+             if groups * m == groups or lane_legal_banks(k, groups * m)]
+    cins = [b for b in _divisors(cgrp) if lane_legal_banks(cgrp, b)]
+    return [(th, ow, cb, kb)
             for th in _tile_chain(oh, pool)
-            for tw in _tile_chain(ow, pool)
             for cb in cins
             for kb in kouts]
 
@@ -131,7 +133,7 @@ def autotune_layer(h: int, w: int, c: int, k: int, kh: int = 3, kw: int = 3,
                    acc_bytes: int = 4,
                    out_bytes: Optional[int] = None,
                    cin_banks: int = 4, kout_banks: int = 4,
-                   vmem_budget: Optional[int] = banking.VMEM_BYTES,
+                   vmem_budget: Optional[int] = banking.VMEM_LIMIT_BYTES,
                    cfg: perfmodel.IPCoreConfig = perfmodel.IPCoreConfig(),
                    calib=None, name: str = "conv",
                    psums: Optional[int] = None) -> LayerTune:
@@ -151,7 +153,6 @@ def autotune_layer(h: int, w: int, c: int, k: int, kh: int = 3, kw: int = 3,
     sees would otherwise price the ~stride²× naive sweep)."""
     check_groups(c, k, groups)
     cgrp = c // groups
-    out_bytes_eff = acc_bytes if out_bytes is None else out_bytes
     if psums is None:
         psums = perfmodel.psum_count(h, w, c, k, kh, kw, stride=stride,
                                      padding=padding, groups=groups,
@@ -167,23 +168,14 @@ def autotune_layer(h: int, w: int, c: int, k: int, kh: int = 3, kw: int = 3,
     oh, ow = conv_out_shape(h, w, kh, kw, stride, padding, dilation)
     if pool:
         oh, ow = (oh // 2) * 2, (ow // 2) * 2
-    budget = banking.VMEM_BYTES if vmem_budget is None else vmem_budget
+    budget = banking.VMEM_LIMIT_BYTES if vmem_budget is None else vmem_budget
 
     def build(th: int, tw: int, cbn: int, kbn: int) -> TilePlan:
-        cb, kb = cgrp // cbn, k // kbn
-        in_th = banking.halo_window(th, stride, kh, dilation)
-        in_tw = banking.halo_window(tw, stride, kw, dilation)
-        pth, ptw = (th // 2, tw // 2) if pool else (th, tw)
-        return TilePlan(
-            cin_banks=cbn, kout_banks=kbn, h_tile=th, w_tile=tw,
-            n_h_tiles=-(-oh // th), n_w_tiles=-(-ow // tw),
-            in_h_tile=in_th, in_w_tile=in_tw,
-            image_block_bytes=in_th * in_tw * cb * in_bytes,
-            weight_block_bytes=kh * kw * cb * kb * in_bytes,
-            acc_block_bytes=th * tw * kb * acc_bytes,
-            output_block_bytes=pth * ptw * kb * out_bytes_eff,
-            stride=stride, out_h=oh, out_w=ow, pool=pool,
-            in_bytes=in_bytes, budget=budget, groups=groups)
+        return banking.tile_plan(
+            h, w, c, k, kh, kw, th, tw, cbn, kbn, stride=stride,
+            padding=padding, pool=pool, groups=groups, dilation=dilation,
+            in_bytes=in_bytes, acc_bytes=acc_bytes, out_bytes=out_bytes,
+            budget=budget)
 
     # (cost, structural tie-break, plan): the tie-break prefers fewer
     # tiles, coarser banking, then the sequential kernel — a fixed total
@@ -383,7 +375,7 @@ def route_batch(layers: Sequence[LayerTune], batch: int, n_cores: int,
 
 def autotune_network(plan, cin_banks: int = 4, kout_banks: int = 4,
                      in_bytes: int = 1,
-                     vmem_budget: Optional[int] = banking.VMEM_BYTES,
+                     vmem_budget: Optional[int] = banking.VMEM_LIMIT_BYTES,
                      cfg: perfmodel.IPCoreConfig = perfmodel.IPCoreConfig(),
                      calib=None,
                      modes: Sequence[str] = SCHEDULER_MODES,
@@ -415,8 +407,6 @@ def autotune_network(plan, cin_banks: int = 4, kout_banks: int = 4,
         h, w, c = plan.input_shape if ins[i][0] < 0 else acts[ins[i][0]]
         kh, kw = sp.kernel
         k_, g_ = conv_geometry(sp, c)
-        cb_n, kb_n = grouped_banks(c, k_, g_, want_cin=cin_banks,
-                                   want_kout=kout_banks)
         stride, pad = sp.stride, sp.padding
         if sp.kind == "conv_transpose":
             # tune on the eq stride-1 conv geometry (what the kernel
@@ -430,7 +420,8 @@ def autotune_network(plan, cin_banks: int = 4, kout_banks: int = 4,
             pool=sp.pool, groups=g_, dilation=sp.dilation,
             in_bytes=in_bytes,
             out_bytes=4 if i == last_param else in_bytes,
-            cin_banks=cb_n, kout_banks=kb_n, vmem_budget=vmem_budget,
+            cin_banks=cin_banks, kout_banks=kout_banks,
+            vmem_budget=vmem_budget,
             cfg=cfg, calib=calib, name=names[i],
             psums=psum_rows[names[i]]))
     total = sum(lt.cycles for lt in tunes)

@@ -127,8 +127,9 @@ class PallasBackend:
             cin_banks, kout_banks = plan.cin_banks, plan.kout_banks
         else:
             # no plan → whole map under the paper's 4×4 banking, degraded
-            # to the largest legal divisors (narrow kernel-set shards and
-            # grouped layers would otherwise trip the divisibility assert)
+            # to the largest legal counts (narrow kernel-set shards and
+            # grouped layers would otherwise trip the divisibility assert,
+            # sub-128-channel banks the chip's lane tiling)
             cin_banks, kout_banks = ref.grouped_banks(
                 x.shape[-1], w.shape[-1], groups)
         # tile extents are conv-output pixels; the kernel clamps them to
@@ -193,7 +194,7 @@ class ConvCoreConfig:
     int8: bool = False            # the paper's 8-bit datapath
     wrap8: bool = False           # bit-faithful 8-bit psum wrap (Fig. 6)
     auto_bank: bool = True        # fit spatial tiles + banks to VMEM
-    vmem_budget: int = banking.VMEM_BYTES   # per-core VMEM target
+    vmem_budget: int = banking.VMEM_LIMIT_BYTES   # per-core VMEM target
     kernel: str = "auto"          # conv variant per layer: "auto" lets the
                                   # perfmodel crossover predictor choose
                                   # conv2d_ws_pipe vs conv2d_ws;
@@ -222,14 +223,13 @@ class ConvCore:
         kh, kw, _, k = w_shape
         cfg = self.config
         in_bytes = 1 if cfg.int8 else 4
-        # degrade bank counts to the largest legal divisors (C=1 input
-        # layers, per-group slices, group-aligned kout banks)
-        cb_n, kb_n = banking.grouped_banks(
-            c, k, groups, want_cin=cfg.cin_banks, want_kout=cfg.kout_banks)
+        # plan_tiles degrades the configured banks to the largest legal
+        # counts (C=1 input layers, per-group slices, lane-legal blocks)
         return banking.plan_tiles(
             h, w_, c, k, kh, kw, stride=stride, padding=padding, pool=pool,
             groups=groups, in_bytes=in_bytes, acc_bytes=4,
-            out_bytes=out_bytes, cin_banks=cb_n, kout_banks=kb_n,
+            out_bytes=out_bytes, cin_banks=cfg.cin_banks,
+            kout_banks=cfg.kout_banks,
             vmem_budget=cfg.vmem_budget if cfg.auto_bank else None,
             kernel=cfg.kernel, calib=cfg.calib)
 

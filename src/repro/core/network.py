@@ -458,7 +458,7 @@ class NetworkPlan:
 
     def tile_plans(self, cin_banks: int = 4, kout_banks: int = 4,
                    in_bytes: int = 1,
-                   vmem_budget: Optional[int] = banking.VMEM_BYTES,
+                   vmem_budget: Optional[int] = banking.VMEM_LIMIT_BYTES,
                    kernel: str = "auto", calib=None
                    ) -> List[Optional[banking.TilePlan]]:
         """Per-node spatial-tile × channel-bank plans (None for nodes
@@ -491,8 +491,6 @@ class NetworkPlan:
             h, w, c = self.input_shape if ins[i][0] < 0 else acts[ins[i][0]]
             kh, kw = sp.kernel
             k_, g_ = conv_geometry(sp, c)
-            cb_n, kb_n = banking.grouped_banks(
-                c, k_, g_, want_cin=cin_banks, want_kout=kout_banks)
             stride, pad = sp.stride, sp.padding
             if sp.kind == "conv_transpose":
                 h, w, pad = transpose_eq_conv_geometry(
@@ -503,7 +501,7 @@ class NetworkPlan:
                 padding=pad, pool=sp.pool, groups=g_,
                 dilation=sp.dilation, in_bytes=in_bytes,
                 out_bytes=4 if i == last_param else in_bytes,
-                cin_banks=cb_n, kout_banks=kb_n,
+                cin_banks=cin_banks, kout_banks=kout_banks,
                 vmem_budget=vmem_budget, kernel=kernel, calib=calib))
         return plans
 

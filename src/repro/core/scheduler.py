@@ -35,6 +35,7 @@ charged against the DMA interface.
 
 from __future__ import annotations
 
+import weakref
 from dataclasses import dataclass, replace
 
 import jax
@@ -256,6 +257,10 @@ class MultiCoreScheduler:
     def __init__(self, config: SchedulerConfig = SchedulerConfig()):
         assert config.mode in ("batch", "kout", "spatial"), config.mode
         self.config = config
+        # program → its shard_map over the devices; an entry lives as long
+        # as its program, which whoever compiled it owns (the serving
+        # engine's bounded LRU)
+        self._per_device = weakref.WeakKeyDictionary()
 
     @classmethod
     def from_tune(cls, tune) -> "MultiCoreScheduler":
@@ -282,8 +287,13 @@ class MultiCoreScheduler:
         process a blank image on the last step instead of the host
         crashing (the fabric doesn't care what's in an idle core's BRAMs).
 
-        With enough local devices, one device per IP core (NamedSharding +
-        GSPMD); otherwise vmapped virtual cores on one device.
+        With enough local devices, one device per IP core: the program
+        runs under ``jax.shard_map`` on each device's slice of the batch
+        (XLA will not partition a Mosaic kernel itself, so the split is
+        explicit);
+        otherwise vmapped virtual cores on one device.  The counters
+        ``sched.batch.device`` / ``sched.batch.virtual`` count which of
+        the two served each batch-mode run.
 
         Each run is an ``sched.run`` trace span (mode, cores, batch,
         virtual-vs-device) when obs is enabled — the per-core/mode
@@ -301,15 +311,31 @@ class MultiCoreScheduler:
             x = jnp.concatenate(
                 [x, jnp.zeros((pad, *x.shape[1:]), x.dtype)])
         if jax.device_count() >= cores:
-            from jax.sharding import NamedSharding, PartitionSpec as P
+            obs.metrics.counter("sched.batch.device").inc()
             with obs.span("sched.run", mode="batch", cores=cores, batch=n,
                           padded=pad, virtual=False):
-                mesh = jax.make_mesh((cores,), ("cores",),
-                                     devices=jax.devices()[:cores])
-                x = jax.device_put(x, NamedSharding(mesh, P("cores")))
-                return program(x)[:n]
+                return self._on_devices(program)(x)[:n]
+        obs.metrics.counter("sched.batch.virtual").inc()
         with obs.span("sched.run", mode="batch", cores=cores, batch=n,
                       padded=pad, virtual=True):
             xs = x.reshape(cores, (n + pad) // cores, *x.shape[1:])
             ys = jax.vmap(program)(xs)
             return ys.reshape(n + pad, *ys.shape[2:])[:n]
+
+    def _on_devices(self, program):
+        """``program`` data-parallel over the first ``n_cores`` devices:
+        each device runs it on its own batch shard (jitted once per
+        program)."""
+        fn = self._per_device.get(program)
+        if fn is None:
+            from jax.sharding import PartitionSpec as P
+            cores = self.config.n_cores
+            mesh = jax.make_mesh((cores,), ("cores",),
+                                 devices=jax.devices()[:cores])
+            # a weak reference, so the wrapper (the entry's value) never
+            # keeps its key alive once the program is evicted
+            prog = weakref.ref(program)
+            fn = self._per_device[program] = jax.jit(jax.shard_map(
+                lambda x: prog()(x), mesh=mesh, in_specs=P("cores"),
+                out_specs=P("cores"), check_vma=False))
+        return fn

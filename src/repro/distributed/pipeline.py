@@ -32,17 +32,6 @@ def bubble_fraction(n_stages: int, n_micro: int) -> float:
     return (n_stages - 1) / (n_stages + n_micro - 1)
 
 
-def _shard_map(f, mesh: Mesh, in_specs, out_specs):
-    """Version-compatible shard_map: ``jax.shard_map`` (jax ≥ 0.6,
-    check_vma=) or ``jax.experimental.shard_map`` (0.4.x, check_rep=)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map
-    return shard_map(f, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, check_rep=False)
-
-
 def pipeline_apply(stage_fn: Callable, stage_params, x, *, mesh: Mesh,
                    axis: str, n_micro: int):
     """Run ``stage_fn`` as a pipeline over ``axis``.
@@ -92,8 +81,7 @@ def pipeline_apply(stage_fn: Callable, stage_params, x, *, mesh: Mesh,
         # outputs live on the last stage; psum broadcasts them (others hold 0)
         return jax.lax.psum(outs, axis)
 
-    fn = _shard_map(local, mesh,
-                    in_specs=(P(axis), P()),
-                    out_specs=P())
+    fn = jax.shard_map(local, mesh=mesh, in_specs=(P(axis), P()),
+                       out_specs=P(), check_vma=False)
     y_mb = fn(stage_params, x_mb)
     return y_mb.reshape(B, *x.shape[1:])

@@ -29,19 +29,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from repro.layers.common import ParamSpec, is_spec, resolve_pspec, spec_map
 
 
-def use_mesh(mesh: Mesh):
-    """Version-compatible mesh context manager.
-
-    ``jax.set_mesh`` (jax ≥ 0.6) → ``jax.sharding.use_mesh`` (0.5.x) →
-    the ``Mesh`` object itself (0.4.x, where Mesh is a context manager).
-    All three scope the mesh for jit/shard_map resolution."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    if hasattr(jax.sharding, "use_mesh"):
-        return jax.sharding.use_mesh(mesh)
-    return mesh
-
-
 def _dp_axes(mesh: Mesh) -> Tuple[str, ...]:
     return tuple(a for a in ("pod", "data") if a in mesh.axis_names)
 

@@ -39,19 +39,24 @@ An output tile of ``h_tile × w_tile`` conv-output pixels at tile index
 so adjacent input windows overlap by a halo of ``k − s`` rows/columns
 (k − 1 for the stride-1 case) — re-read from HBM per tile, exactly like
 the FPGA re-DMAs the boundary rows of its image BRAM window.  The input
-BlockSpec uses element-granularity (Unblocked) indexing because halo'd
-windows overlap: block strides (h_tile·s) differ from block extents
-(in_tile).  The padded map is extended with extra zero rows/columns on
-the bottom/right so the LAST tile's window is always in bounds; the
-correspondingly padded output rows are sliced off after the call.
+BlockSpec of a tiled layer is element-indexed (``pl.Element``) because
+halo'd windows overlap: block strides (h_tile·s) differ from block
+extents (in_tile).  An untiled spatial dim spans the whole padded map, so
+a planner that tiles only H (banking.plan_tiles) keeps W, the sublane
+dim, at the array's own extent — the form Mosaic accepts.  The padded map
+is extended with extra zero rows/columns on the bottom/right so the LAST
+tile's window is always in bounds; the correspondingly padded output rows
+are sliced off after the call.
 
 The fused epilogue is tile-local: with ``pool=True`` tile sizes must be
 even (pool-aligned) so no 2×2 pool window straddles a tile edge — tile
 boundaries then land on pool-window boundaries and tiled pooling equals
-whole-map pooling.  core/banking.plan_tiles chooses (h_tile, w_tile,
-cin_banks, kout_banks) jointly so the true VMEM working set (halo'd
-input block + weight block + accumulator scratch + epilogue output
-block, with pipeline double-buffering) fits the budget.
+whole-map pooling.  core/banking.plan_tiles chooses (h_tile, cin_banks,
+kout_banks) jointly so the VMEM working set (halo'd input block + weight
+block + accumulator scratch + epilogue output block, with pipeline
+double-buffering, plus the kernel body's values — all counted as Mosaic
+lays them out) fits ``VMEM_LIMIT_BYTES``, the ``vmem_limit_bytes`` both
+conv kernels pass to Mosaic.
 
 Padding is materialized by zero-padding the feature map before the kernel
 (the FPGA writes zero margins into the image BRAMs); zero padding is exact
@@ -74,8 +79,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.ref import (check_groups, conv_out_shape, dilated_extent,
-                               halo_window, normalize_padding)
+from repro.kernels.ref import (LANES, VMEM_LIMIT_BYTES, check_groups,
+                               conv_out_shape, dilated_extent, halo_window,
+                               normalize_padding)
+
+DMA_SUBLANES = 8    # second-minor alignment of an int8 map sliced by a DMA
 
 
 class ConvGeom(NamedTuple):
@@ -117,15 +125,29 @@ class ConvGeom(NamedTuple):
 def setup_conv(x, w, *, stride: int = 1, padding="VALID", groups: int = 1,
                cin_banks: int = 4, kout_banks: int = 4, h_tile: int = 0,
                w_tile: int = 0, pool: bool = False, requant: bool = False,
-               dilation: int = 1):
+               dilation: int = 1, manual_dma: bool = False):
     """Validate one conv layer pass and materialize its padded input.
 
-    Returns ``(x_padded, geom)`` where ``x_padded`` carries the zero
+    Returns ``(x_padded, w, geom)`` where ``x_padded`` carries the zero
     margins (padding + trailing-tile zero-extension — exact for the
     symmetric zero-point-0 int8 scheme) and ``geom`` is the resolved
     :class:`ConvGeom`.  Raises exactly the errors the kernels contract
     with the planner (banking invariant, group boundaries, sub-2×2
-    pooled outputs, pool-aligned tiles)."""
+    pooled outputs, pool-aligned tiles).
+
+    Where a DMA cuts windows out of the map in HBM (spatial tiles, or
+    ``manual_dma`` — conv2d_ws_pipe's own copies), Mosaic needs the map's
+    two minor dims tile-aligned: the width is zero-extended to a multiple
+    of 8 and, for a dense layer run as one channel bank, the channels
+    (with the weights' input channels) to a multiple of 128.  With
+    ``manual_dma`` the weights' output channels of a single kout bank are
+    zero-extended to a multiple of 128 as well (a VMEM slot of a narrower
+    weight bank cannot be addressed); ``geom.k`` is then the extended
+    count and the caller slices the extra output channels off.  Channel
+    extension applies to int8 maps only: zeros add nothing to an int32
+    accumulator, whereas a longer f32 contraction may round differently
+    (so on the chip the f32 path needs lane-aligned channels).  The
+    cost is the larger padded arrays in HBM."""
     n, h, w_dim, c = x.shape
     kh, kw, c2, k = w.shape
     check_groups(c, k, groups)
@@ -174,10 +196,19 @@ def setup_conv(x, w, *, stride: int = 1, padding="VALID", groups: int = 1,
     # matching garbage output rows/cols are sliced off after the kernel
     extra_h = max(0, (n_th - 1) * th * stride + in_th - hp)
     extra_w = max(0, (n_tw - 1) * tw * stride + in_tw - wp)
-    if pt or pb or pl_ or pr or extra_h or extra_w:
+    extra_c = extra_k = 0
+    if tiled or manual_dma:                    # DMA-aligned HBM layout
+        extra_w += -(wp + extra_w) % DMA_SUBLANES
+        if x.dtype == jnp.int8 and groups == 1:
+            extra_c = -c % LANES if cin_banks == 1 else 0
+            extra_k = -k % LANES if kout_banks == 1 and manual_dma else 0
+    if pt or pb or pl_ or pr or extra_h or extra_w or extra_c:
         # zero margins written into the image BRAMs (exact for zero-point-0)
         x = jnp.pad(x, ((0, 0), (pt, pb + extra_h), (pl_, pr + extra_w),
-                        (0, 0)))
+                        (0, extra_c)))
+    if extra_c or extra_k:
+        w = jnp.pad(w, ((0, 0), (0, 0), (0, extra_c), (0, extra_k)))
+        cgrp, k = cgrp + extra_c, k + extra_k
     hp, wp = hp + extra_h, wp + extra_w
     if pool:
         pth, ptw = th // 2, tw // 2
@@ -196,7 +227,28 @@ def setup_conv(x, w, *, stride: int = 1, padding="VALID", groups: int = 1,
         hp=hp, wp=wp, pth=pth, ptw=ptw, poh=poh, pow_=pow_,
         tiled=tiled, int_path=x.dtype == jnp.int8, requant=requant,
         dilation=dilation)
-    return x, geom
+    return x, w, geom
+
+
+def halo_block_spec(g: ConvGeom, index_map) -> pl.BlockSpec:
+    """BlockSpec of one grid step's halo'd input window.
+
+    ``index_map`` returns ``(batch, h_tile, w_tile, channel_block)``
+    indices.  An untiled layer reads one full-map block per channel bank.
+    A tiled layer's spec is element-indexed (``pl.Element``) in every
+    dim, as Mosaic requires once one dim is: a tiled spatial dim starts
+    at tile · tile_extent · stride and spans the halo'd extent, an
+    untiled one spans the whole padded map."""
+    def elem_map(*ids):
+        b, ty, tx, c = index_map(*ids)
+        return (b, ty * g.th * g.stride if g.n_th > 1 else 0,
+                tx * g.tw * g.stride if g.n_tw > 1 else 0, c * g.cb)
+    if not g.tiled:
+        return pl.BlockSpec((1, g.hp, g.wp, g.cb), index_map)
+    return pl.BlockSpec(
+        (pl.Element(1), pl.Element(g.in_th if g.n_th > 1 else g.hp),
+         pl.Element(g.in_tw if g.n_tw > 1 else g.wp), pl.Element(g.cb)),
+        elem_map)
 
 
 def _conv_kernel(x_ref, w_ref, b_ref, s_ref, o_ref, acc_ref, *, kh: int,
@@ -291,17 +343,20 @@ def conv2d_ws(x, w, bias=None, out_scale=None, *, stride: int = 1,
     cin_banks/kout_banks default to the paper's 4×4 banking; C/groups and
     K must divide by them (the paper's divisible-by-4 invariant, §4.1 —
     ``ref.grouped_banks`` degrades the defaults legally for grouped
-    layers).
+    layers).  Mosaic further needs each channel block to be the full
+    extent or a multiple of 128 lanes; the planner and the backends only
+    pick such counts (``ref.divisor_banks``), while interpret mode runs
+    any divisor.
     """
-    x, g = setup_conv(x, w, stride=stride, padding=padding, groups=groups,
-                      cin_banks=cin_banks, kout_banks=kout_banks,
-                      h_tile=h_tile, w_tile=w_tile, pool=pool,
-                      requant=out_scale is not None, dilation=dilation)
+    x, w, g = setup_conv(x, w, stride=stride, padding=padding,
+                         groups=groups, cin_banks=cin_banks,
+                         kout_banks=kout_banks, h_tile=h_tile,
+                         w_tile=w_tile, pool=pool,
+                         requant=out_scale is not None, dilation=dilation)
     n, kh, kw, k = g.n, g.kh, g.kw, g.k
     th, tw, n_th, n_tw = g.th, g.tw, g.n_th, g.n_tw
-    in_th, in_tw, hp, wp = g.in_th, g.in_tw, g.hp, g.wp
     pth, ptw, poh, pow_ = g.pth, g.ptw, g.poh, g.pow_
-    cb, kb, cgrp, bpg, tiled = g.cb, g.kb, g.cgrp, g.bpg, g.tiled
+    cb, kb, bpg = g.cb, g.kb, g.bpg
 
     acc_dtype = jnp.int32 if g.int_path else jnp.float32
     if bias is None:
@@ -315,24 +370,9 @@ def conv2d_ws(x, w, bias=None, out_scale=None, *, stride: int = 1,
         (k,))
 
     # the channel index of the input block carries the GROUP offset: kout
-    # bank ko belongs to group ko // bpg, whose cin slice starts at
-    # (ko // bpg) · C/groups — the cin sweep (co) walks only that slice.
+    # bank ko belongs to group ko // bpg, whose cin slice starts at block
+    # (ko // bpg) · cin_banks — the cin sweep (co) walks only that slice.
     # Dense convs have bpg == kout_banks, so the offset is always 0.
-    if tiled:
-        # overlapping halo'd windows: element-granularity indexing (block
-        # stride th·s ≠ block extent in_th)
-        x_spec = pl.BlockSpec(
-            (1, in_th, in_tw, cb),
-            lambda b, ty, tx, ko, co: (b, ty * th * stride,
-                                       tx * tw * stride,
-                                       (ko // bpg) * cgrp + co * cb),
-            indexing_mode=pl.unblocked)
-    else:
-        x_spec = pl.BlockSpec(
-            (1, hp, wp, cb),
-            lambda b, ty, tx, ko, co: (b, 0, 0,
-                                       (ko // bpg) * cin_banks + co))
-
     kernel = functools.partial(
         _conv_kernel, kh=kh, kw=kw, stride=stride, cin_banks=cin_banks,
         relu=relu, pool=pool, requant=requant, acc_dtype=acc_dtype,
@@ -341,19 +381,22 @@ def conv2d_ws(x, w, bias=None, out_scale=None, *, stride: int = 1,
         kernel,
         grid=(n, n_th, n_tw, kout_banks, cin_banks),
         in_specs=[
-            x_spec,
+            halo_block_spec(g, lambda b, ty, tx, ko, co: (
+                b, ty, tx, (ko // bpg) * cin_banks + co)),
             pl.BlockSpec((kh, kw, cb, kb),
                          lambda b, ty, tx, ko, co: (0, 0, co, ko)),
-            pl.BlockSpec((kb,), lambda b, ty, tx, ko, co: (ko,)),
-            pl.BlockSpec((kb,), lambda b, ty, tx, ko, co: (ko,)),
+            pl.BlockSpec((1, kb), lambda b, ty, tx, ko, co: (0, ko)),
+            pl.BlockSpec((1, kb), lambda b, ty, tx, ko, co: (0, ko)),
         ],
         out_specs=pl.BlockSpec((1, pth, ptw, kb),
                                lambda b, ty, tx, ko, co: (b, ty, tx, ko)),
         out_shape=jax.ShapeDtypeStruct(
             (n, n_th * pth, n_tw * ptw, k), out_dtype),
         scratch_shapes=[pltpu.VMEM((th, tw, kb), acc_dtype)],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
-    )(x, w, bias, scale)
+    )(x, w, bias.reshape(1, k), scale.reshape(1, k))
     if (n_th * pth, n_tw * ptw) != (poh, pow_):
         out = out[:, :poh, :pow_]
     return out

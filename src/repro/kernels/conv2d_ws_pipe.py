@@ -18,10 +18,10 @@ load/compute/store) written out by hand:
   (tile, ko) grid step starts the FIRST slab of the next, so the pipe
   never drains between kernel sets or spatial tiles (scratch buffers and
   semaphores persist across the sequential TPU grid);
-* the fused epilogue (ReLU → 2×2 max-pool → requantize) writes into a
-  ping-pong OUTPUT buffer whose VMEM→HBM store overlaps the next tile's
-  compute; the store from slot ``s`` is only waited two grid steps later,
-  when the slot is about to be reused (and drained at the final step).
+* the fused epilogue (ReLU → 2×2 max-pool → requantize) writes the
+  output block, whose VMEM→HBM store Pallas overlaps with the next grid
+  step (an output BlockSpec: the block may be as narrow as the map, which
+  Mosaic accepts there and refuses in a hand-written DMA window).
 
 Logical iteration space is IDENTICAL to ``conv2d_ws`` — the
 (N, h_tiles, w_tiles, kout, cin) sweep with co innermost — except the cin
@@ -33,8 +33,12 @@ the int32 and the f32 accumulator paths (asserted across the full
 stride × padding × epilogue × groups × tiling space in
 tests/test_pipeline_kernel.py).
 
-VMEM working set: 2·input + 2·weight + 2·output ping-pong blocks plus the
-accumulator scratch — exactly the bytes ``banking.TilePlan.
+The input map is laid out for the DMA windows by ``setup_conv``
+(``manual_dma=True``: width to a multiple of 8, a single channel bank to
+a multiple of 128 channels, zero-filled).
+
+VMEM working set: 2·input + 2·weight + 2·output blocks plus the
+accumulator scratch — the laid-out bytes ``banking.TilePlan.
 working_set_bytes`` already budgets (the implicit pipeline double-buffers
 the same blocks), so any plan that fits the sequential kernel fits this
 one.  ``banking.plan_tiles(kernel="auto")`` consults
@@ -57,14 +61,23 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.conv2d_ws import setup_conv
+from repro.kernels.ref import VMEM_LIMIT_BYTES
 
 
-def _pipe_kernel(x_hbm, w_hbm, b_ref, s_ref, o_hbm, xb, wb, ob, acc_ref,
-                 in_sem, w_sem, out_sem, *, kh: int, kw: int, stride: int,
+def _window(ref, dim: int, start, size: int):
+    """Index of one DMA window along ``dim`` of ``ref``: the static full
+    slice where the window spans the whole dim (an untiled spatial dim,
+    a single channel bank), else a dynamic ``pl.ds``.  Mosaic accepts an
+    unaligned extent on a tiled (second-minor or minor) dim only as the
+    full extent."""
+    return slice(None) if size == ref.shape[dim] else pl.ds(start, size)
+
+
+def _pipe_kernel(x_hbm, w_hbm, b_ref, s_ref, o_ref, xb, wb, acc_ref,
+                 in_sem, w_sem, *, kh: int, kw: int, stride: int,
                  cin_banks: int, kout_banks: int, th: int, tw: int,
-                 pth: int, ptw: int, cb: int, kb: int, cgrp: int, bpg: int,
-                 relu: bool, pool: bool, requant: bool, acc_dtype,
-                 dilation: int = 1):
+                 cb: int, kb: int, cgrp: int, bpg: int, relu: bool,
+                 pool: bool, requant: bool, acc_dtype, dilation: int = 1):
     b, ty, tx, ko = (pl.program_id(i) for i in range(4))
     n_th, n_tw = pl.num_programs(1), pl.num_programs(2)
     n_steps = pl.num_programs(0) * n_th * n_tw * kout_banks
@@ -87,25 +100,15 @@ def _pipe_kernel(x_hbm, w_hbm, b_ref, s_ref, o_hbm, xb, wb, ob, acc_ref,
         kernel's BlockSpec index maps."""
         coff = (sko // bpg) * cgrp + sco * cb
         in_dma = pltpu.make_async_copy(
-            x_hbm.at[sb, pl.ds(sty * th * stride, xb.shape[1]),
-                     pl.ds(stx * tw * stride, xb.shape[2]),
-                     pl.ds(coff, cb)],
+            x_hbm.at[sb, _window(x_hbm, 1, sty * th * stride, xb.shape[1]),
+                     _window(x_hbm, 2, stx * tw * stride, xb.shape[2]),
+                     _window(x_hbm, 3, coff, cb)],
             xb.at[slot], in_sem.at[slot])
         w_dma = pltpu.make_async_copy(
-            w_hbm.at[:, :, pl.ds(sco * cb, cb), pl.ds(sko * kb, kb)],
+            w_hbm.at[:, :, _window(w_hbm, 2, sco * cb, cb),
+                     _window(w_hbm, 3, sko * kb, kb)],
             wb.at[slot], w_sem.at[slot])
         return in_dma, w_dma
-
-    def out_copy(s):
-        """The epilogue store of grid step ``s``: output ping-pong slot
-        ``s % 2`` → that step's (tile, kout bank) HBM region."""
-        sb, sty, stx, sko = coords(s)
-        slot = jax.lax.rem(s, 2)
-        return pltpu.make_async_copy(
-            ob.at[slot],
-            o_hbm.at[sb, pl.ds(sty * pth, pth), pl.ds(stx * ptw, ptw),
-                     pl.ds(sko * kb, kb)],
-            out_sem.at[slot])
 
     # Warm-up: the very first grid step primes the pipe with slab 0;
     # every later slab is prefetched by its predecessor.
@@ -158,9 +161,8 @@ def _pipe_kernel(x_hbm, w_hbm, b_ref, s_ref, o_hbm, xb, wb, ob, acc_ref,
 
     jax.lax.fori_loop(0, cin_banks, cin_step, 0)
 
-    # Fused epilogue, then the overlapped store: the VMEM→HBM copy of this
-    # tile drains while the NEXT grid step computes — its slot is only
-    # waited on two steps later, right before reuse.
+    # Fused epilogue into the output block; Pallas stores it to HBM while
+    # the next grid step computes.
     y = acc_ref[...]
     if relu:
         y = jnp.maximum(y, 0)
@@ -169,22 +171,7 @@ def _pipe_kernel(x_hbm, w_hbm, b_ref, s_ref, o_hbm, xb, wb, ob, acc_ref,
     if requant:
         y = jnp.clip(jnp.round(y.astype(jnp.float32) * s_ref[...]),
                      -128, 127)
-
-    @pl.when(step >= 2)
-    def _reclaim():                                 # slot reused: drain it
-        out_copy(step - 2).wait()
-
-    oslot = jax.lax.rem(step, 2)
-    ob[oslot] = y.astype(ob.dtype)
-    out_copy(step).start()
-
-    @pl.when(step == n_steps - 1)
-    def _drain():                                   # kernel end: all stores
-        out_copy(step).wait()
-
-        @pl.when(step >= 1)
-        def _():
-            out_copy(step - 1).wait()
+    o_ref[0] = y.astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -199,23 +186,29 @@ def conv2d_ws_pipe(x, w, bias=None, out_scale=None, *, stride: int = 1,
     DMA (see the module docstring).  Same signature, same contracts, same
     results bit-for-bit; ``banking.plan_tiles`` decides per layer which
     variant a compiled network runs (``TilePlan.pipelined``)."""
-    x, g = setup_conv(x, w, stride=stride, padding=padding, groups=groups,
-                      cin_banks=cin_banks, kout_banks=kout_banks,
-                      h_tile=h_tile, w_tile=w_tile, pool=pool,
-                      requant=out_scale is not None, dilation=dilation)
+    k = w.shape[3]
+    x, w, g = setup_conv(x, w, stride=stride, padding=padding,
+                         groups=groups, cin_banks=cin_banks,
+                         kout_banks=kout_banks, h_tile=h_tile,
+                         w_tile=w_tile, pool=pool,
+                         requant=out_scale is not None, dilation=dilation,
+                         manual_dma=True)
     acc_dtype = jnp.int32 if g.int_path else jnp.float32
     if bias is None:
-        bias = jnp.zeros((g.k,), acc_dtype)
+        bias = jnp.zeros((k,), acc_dtype)
     bias = bias.astype(acc_dtype)
     out_dtype = jnp.int8 if g.requant else acc_dtype
     scale = jnp.broadcast_to(
         jnp.asarray(1.0 if out_scale is None else out_scale, jnp.float32),
-        (g.k,))
+        (k,))
+    # setup_conv may have zero-extended the kernel sets (g.k ≥ k)
+    bias = jnp.pad(bias, (0, g.k - k)).reshape(1, g.k)
+    scale = jnp.pad(scale, (0, g.k - k)).reshape(1, g.k)
 
     kernel = functools.partial(
         _pipe_kernel, kh=g.kh, kw=g.kw, stride=g.stride,
         cin_banks=g.cin_banks, kout_banks=g.kout_banks, th=g.th, tw=g.tw,
-        pth=g.pth, ptw=g.ptw, cb=g.cb, kb=g.kb, cgrp=g.cgrp, bpg=g.bpg,
+        cb=g.cb, kb=g.kb, cgrp=g.cgrp, bpg=g.bpg,
         relu=relu, pool=pool, requant=g.requant, acc_dtype=acc_dtype,
         dilation=g.dilation)
     out = pl.pallas_call(
@@ -223,26 +216,28 @@ def conv2d_ws_pipe(x, w, bias=None, out_scale=None, *, stride: int = 1,
         grid=(g.n, g.n_th, g.n_tw, g.kout_banks),
         in_specs=[
             # feature map + weights stay in HBM: the kernel moves them
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
             # bias/scale per-bank blocks are tiny: implicit pipeline
-            pl.BlockSpec((g.kb,), lambda b, ty, tx, ko: (ko,)),
-            pl.BlockSpec((g.kb,), lambda b, ty, tx, ko: (ko,)),
+            pl.BlockSpec((1, g.kb), lambda b, ty, tx, ko: (0, ko)),
+            pl.BlockSpec((1, g.kb), lambda b, ty, tx, ko: (0, ko)),
         ],
-        out_specs=pl.BlockSpec(memory_space=pltpu.ANY),
+        out_specs=pl.BlockSpec((1, g.pth, g.ptw, g.kb),
+                               lambda b, ty, tx, ko: (b, ty, tx, ko)),
         out_shape=jax.ShapeDtypeStruct(
             (g.n, g.n_th * g.pth, g.n_tw * g.ptw, g.k), out_dtype),
         scratch_shapes=[
-            pltpu.VMEM((2, g.in_th, g.in_tw, g.cb), x.dtype),   # ping-pong in
+            pltpu.VMEM((2, g.in_th if g.n_th > 1 else g.hp,     # ping-pong in
+                        g.in_tw if g.n_tw > 1 else g.wp, g.cb), x.dtype),
             pltpu.VMEM((2, g.kh, g.kw, g.cb, g.kb), w.dtype),   # ping-pong w
-            pltpu.VMEM((2, g.pth, g.ptw, g.kb), out_dtype),     # ping-pong out
             pltpu.VMEM((g.th, g.tw, g.kb), acc_dtype),          # accumulator
             pltpu.SemaphoreType.DMA((2,)),                      # input slabs
             pltpu.SemaphoreType.DMA((2,)),                      # weight slabs
-            pltpu.SemaphoreType.DMA((2,)),                      # output stores
         ],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
     )(x, w, bias, scale)
-    if (g.n_th * g.pth, g.n_tw * g.ptw) != (g.poh, g.pow_):
-        out = out[:, :g.poh, :g.pow_]
+    if (g.n_th * g.pth, g.n_tw * g.ptw, g.k) != (g.poh, g.pow_, k):
+        out = out[:, :g.poh, :g.pow_, :k]
     return out

@@ -3,13 +3,17 @@ matmuls that dominate transformers (a 1×1 convolution *is* a GEMM; this is
 the TPU-native statement of the IP-core architecture — DESIGN.md §4).
 
 Same four mechanisms as conv2d_ws:
-* grid = (N-blocks, K-blocks, M-blocks), m innermost → the weight block
-  w[kb, nb] stays VMEM-resident across the whole M (token) stream
-  (weight-stationary: the Weight Loader);
 * contraction (K) banking with output-block revisiting & accumulation
-  (channel banks → PSUM accumulation into the output BRAM);
+  (channel banks → PSUM accumulation into the output BRAM): grid =
+  (N-blocks, M-blocks, K-blocks), k innermost, so an output block stays
+  VMEM-resident across its whole contraction sweep and is written back
+  once — on the chip an output block is flushed when its index changes,
+  so the contraction must be the innermost axis;
 * bias preload at the first contraction bank (M5);
 * Pallas double-buffered block DMA = the load/compute pipeline (M4).
+
+Blocks are multiples of 128 or the full extent (the MXU/lane tiling
+Mosaic accepts); ``_pick`` legalizes the requested sizes.
 
 int8×int8→int32 supported (the 8-bit datapath).
 """
@@ -22,9 +26,11 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.ref import LANES
+
 
 def _mm_kernel(x_ref, w_ref, b_ref, o_ref, *, acc_dtype):
-    ko = pl.program_id(1)
+    ko = pl.program_id(2)
 
     @pl.when(ko == 0)
     def _init():
@@ -36,11 +42,12 @@ def _mm_kernel(x_ref, w_ref, b_ref, o_ref, *, acc_dtype):
 
 
 def _pick(total: int, target: int) -> int:
-    """Largest divisor of ``total`` that is ≤ target (tile-friendly)."""
-    t = min(target, total)
-    while total % t:
-        t -= 1
-    return t
+    """Largest divisor of ``total`` that is ≤ target and a multiple of 128
+    lanes; the full extent when there is none (e.g. a 1000-class head)."""
+    t = min(target, total) // LANES * LANES
+    while t and total % t:
+        t -= LANES
+    return t or total
 
 
 @functools.partial(jax.jit, static_argnames=("bm", "bk", "bn", "interpret"))
@@ -49,8 +56,8 @@ def matmul_ws(x, w, bias=None, *, bm: int = 256, bk: int = 512, bn: int = 256,
     """x: [M,K] @ w: [K,N] (+bias [N]) → [M,N] (f32, or int32 for int8 in).
 
     Default blocks: bm×bk×bn = 256×512×256 → VMEM working set
-    (x 256×512 + w 512×256 + out 256×256) ≈ 0.9 MiB in bf16/f32 with double
-    buffering — far under the ~128 MiB v5e budget, MXU-aligned (×128).
+    (x 256×512 + w 512×256 + out 256×256) ≈ 2.5 MiB in f32 with double
+    buffering — well under the 16 MiB scoped VMEM, MXU-aligned (×128).
     """
     m, k = x.shape
     k2, n = w.shape
@@ -65,14 +72,14 @@ def matmul_ws(x, w, bias=None, *, bm: int = 256, bk: int = 512, bn: int = 256,
 
     out = pl.pallas_call(
         functools.partial(_mm_kernel, acc_dtype=acc_dtype),
-        grid=(n // bn, k // bk, m // bm),
+        grid=(n // bn, m // bm, k // bk),
         in_specs=[
-            pl.BlockSpec((bm, bk), lambda no, ko, mo: (mo, ko)),
-            pl.BlockSpec((bk, bn), lambda no, ko, mo: (ko, no)),
-            pl.BlockSpec((bn,), lambda no, ko, mo: (no,)),
+            pl.BlockSpec((bm, bk), lambda no, mo, ko: (mo, ko)),
+            pl.BlockSpec((bk, bn), lambda no, mo, ko: (ko, no)),
+            pl.BlockSpec((1, bn), lambda no, mo, ko: (0, no)),
         ],
-        out_specs=pl.BlockSpec((bm, bn), lambda no, ko, mo: (mo, no)),
+        out_specs=pl.BlockSpec((bm, bn), lambda no, mo, ko: (mo, no)),
         out_shape=jax.ShapeDtypeStruct((m, n), acc_dtype),
         interpret=interpret,
-    )(x, w, bias)
+    )(x, w, bias.reshape(1, n))
     return out

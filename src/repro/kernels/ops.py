@@ -189,10 +189,10 @@ def conv2d(x, w, bias=None, *, stride: int = 1, padding="VALID",
 
     ``groups`` selects grouped channel contraction (w: [KH,KW,C/groups,K];
     1 = dense, ``groups == C`` = depthwise — the MobileNet workload
-    family).  For grouped layers the requested bank counts re-legalize
-    through ``ref.grouped_banks`` (cin banks must divide the per-group
-    slice, kout banks split along group boundaries); dense layers keep
-    the strict paper invariant.
+    family).  The requested bank counts re-legalize through
+    ``ref.grouped_banks`` (cin banks must divide the per-group slice,
+    kout banks split along group boundaries, and each channel block is
+    the full extent or a multiple of 128 lanes, as Mosaic requires).
 
     float in → f32 out; int8 in → int32 out.  ``out_scale`` requantizes
     in-kernel (acc × scale → int8) on EITHER accumulator path — int32 for
@@ -227,12 +227,11 @@ def conv2d(x, w, bias=None, *, stride: int = 1, padding="VALID",
     if wrap8 and out_scale is not None:
         raise ValueError("wrap8 and out_scale are mutually exclusive: the "
                          "Fig. 6 wrap path has no requantize stage")
-    if groups > 1:
-        # re-legalize the requested banking for the group structure (the
-        # kernel rejects banks that straddle group boundaries)
-        cin_banks, kout_banks = _ref.grouped_banks(
-            x.shape[3], w.shape[3], groups, want_cin=cin_banks,
-            want_kout=kout_banks)
+    # re-legalize the requested banking: divisors of the (per-group)
+    # channel slices, kout banks on group boundaries, lane-legal blocks
+    cin_banks, kout_banks = _ref.grouped_banks(
+        x.shape[3], w.shape[3], groups, want_cin=cin_banks,
+        want_kout=kout_banks)
     if (out_scale is None and not wrap8
             and jnp.issubdtype(jnp.result_type(x), jnp.floating)):
         pad = _ref.normalize_padding(padding, w.shape[0], w.shape[1],
@@ -379,10 +378,9 @@ def conv2d_transpose(x, w, bias=None, *, stride: int = 1, padding="VALID",
     ordinary strided conv, dW the channel-swapped weight-grad GEMMs — so
     upsampling layers train through the same paper dataflow.
     """
-    if groups > 1:
-        cin_banks, kout_banks = _ref.grouped_banks(
-            x.shape[3], w.shape[3], groups, want_cin=cin_banks,
-            want_kout=kout_banks)
+    cin_banks, kout_banks = _ref.grouped_banks(
+        x.shape[3], w.shape[3], groups, want_cin=cin_banks,
+        want_kout=kout_banks)
     kh, kw = w.shape[0], w.shape[1]
     (oh, ow), _ = _ref.conv_transpose_eq_params(
         x.shape[1], x.shape[2], kh, kw, stride, padding, dilation,

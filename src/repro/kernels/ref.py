@@ -68,14 +68,29 @@ def halo_window(tile: int, stride: int, k: int, dilation: int = 1) -> int:
     return (tile - 1) * stride + dilated_extent(k, dilation)
 
 
+LANES = 128     # TPU vreg lane width: the minor dim of every VMEM tile
+# Scoped VMEM a conv kernel may use: the kernels pass it to Mosaic as
+# ``vmem_limit_bytes`` and banking.plan_tiles budgets against it, so the
+# planner's promise and the compiler's limit are one number.
+VMEM_LIMIT_BYTES = 16 * 1024 * 1024
+
+
+def lane_legal_banks(dim: int, banks: int) -> bool:
+    """Whether splitting ``dim`` channels into ``banks`` blocks gives a
+    channel block Mosaic accepts as the minor (lane) dimension: the whole
+    extent (one bank) or a multiple of 128 lanes."""
+    return banks == 1 or (dim % banks == 0 and (dim // banks) % LANES == 0)
+
+
 def divisor_banks(dim: int, want: int) -> int:
-    """Largest bank count ≤ ``want`` that divides ``dim`` — how the paper's
-    divisible-by-4 invariant degrades for awkward channel counts (e.g. the
-    C=1 input layer of a grayscale network runs on a single image BMG).
-    Lives here (with the other shared shape math) so kernels and the core
-    planner agree without a layering inversion."""
+    """Largest bank count ≤ ``want`` that splits ``dim`` into lane-legal
+    blocks (``lane_legal_banks``): the paper's 4-way banking only
+    survives where each bank still holds a multiple of 128 channels, and
+    anything narrower runs as one full-extent bank.  Lives here (with the
+    other shared shape math) so kernels and the core planner agree
+    without a layering inversion."""
     b = max(1, min(want, dim))
-    while dim % b:
+    while not lane_legal_banks(dim, b):
         b -= 1
     return b
 

@@ -3,11 +3,16 @@ cell from ShapeDtypeStructs only (no allocation), and extract the roofline
 terms from the compiled artifact.
 
 MUST set the fake-device flag before any other import — jax locks the
-device count on first init.
+device count on first init.  A CPU tool: it pins ``JAX_PLATFORMS=cpu``
+for itself and the one child process it starts per cell, so no process
+of it ever claims an accelerator (a chip belongs to one process at a
+time, and this parent has touched JAX).
 """
 
 import os
 import tempfile
+
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 # Dump the module right after SPMD partitioning: that HLO carries the TRUE
 # tensor dtypes (bf16 collectives) and per-device shapes.  The final CPU
@@ -127,7 +132,7 @@ def lower_cell(arch: str, shape_name: str, mesh_kind: str,
     if overrides:
         cfg = dataclasses.replace(cfg, **overrides)
 
-    with sharding.use_mesh(mesh):
+    with jax.set_mesh(mesh):
         if shape.kind == "train":
             sspecs = _state_specs(cfg)
             state = shape_structs(sspecs)

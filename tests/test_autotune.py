@@ -223,15 +223,17 @@ def test_zoo_networks_tune_leq_greedy_and_one_differs():
 
 
 def test_crossover_verdict_flips_with_fitted_overhead():
-    # the README worked example: a tiny DMA-bound layer the analytic
-    # 16-cycle overhead routes to the pipelined kernel flips back to
-    # sequential once a fitted table says slabs cost 64 cycles each
-    plan16 = banking.plan_tiles(6, 6, 8, 8, in_bytes=1, kernel="auto")
-    assert plan16.pipelined
-    plan64 = banking.plan_tiles(
-        6, 6, 8, 8, in_bytes=1, kernel="auto",
-        calib=CalibrationTable(pipeline_overhead_cycles=64.0))
-    assert not plan64.pipelined
+    # a tiny DMA-bound layer (6×6×8 ⊛ 8 streamed through 4 row tiles —
+    # one lane-legal bank each way) that the analytic 16-cycle overhead
+    # routes to the pipelined kernel flips back to sequential once a
+    # fitted table says slabs cost 128 cycles each
+    plan16 = banking.plan_tiles(6, 6, 8, 8, in_bytes=1, kernel="auto",
+                                vmem_budget=120 * 1024)
+    assert plan16.n_tiles == 4 and plan16.pipelined
+    plan128 = banking.plan_tiles(
+        6, 6, 8, 8, in_bytes=1, kernel="auto", vmem_budget=120 * 1024,
+        calib=CalibrationTable(pipeline_overhead_cycles=128.0))
+    assert not plan128.pipelined
 
 
 def test_tuned_plans_execute_bit_exact():

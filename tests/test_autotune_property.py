@@ -34,7 +34,8 @@ def _layer_shapes(draw):
 
 @settings(max_examples=30, deadline=None)
 @given(shape=_layer_shapes(),
-       budget=st.sampled_from([64 * 1024, 512 * 1024, banking.VMEM_BYTES]))
+       budget=st.sampled_from([64 * 1024, 512 * 1024,
+                               banking.VMEM_LIMIT_BYTES]))
 def test_autotuned_plan_fits_and_respects_groups(shape, budget):
     lt = autotune_layer(**shape, vmem_budget=budget, calib=_CALIB)
     tp = lt.plan

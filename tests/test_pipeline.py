@@ -23,7 +23,6 @@ def test_pipeline_matches_sequential():
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
         import jax, jax.numpy as jnp, numpy as np
         from repro.distributed.pipeline import pipeline_apply
-        from repro.distributed.sharding import use_mesh
 
         mesh = jax.make_mesh((4,), ("stage",), devices=jax.devices()[:4])
         rng = np.random.default_rng(0)
@@ -44,7 +43,7 @@ def test_pipeline_matches_sequential():
                 h = stage_fn(jax.tree.map(lambda t: t[s], params), h)
             return h
 
-        with use_mesh(mesh):
+        with jax.set_mesh(mesh):
             y_pipe = pipeline_apply(stage_fn, params, x, mesh=mesh,
                                     axis="stage", n_micro=n_micro)
         y_seq = sequential(params, x)
@@ -60,7 +59,7 @@ def test_pipeline_matches_sequential():
         def loss_seq(p):
             return jnp.sum(jnp.square(sequential(p, x)))
 
-        with use_mesh(mesh):
+        with jax.set_mesh(mesh):
             g1 = jax.grad(loss_pipe)(params)
         g2 = jax.grad(loss_seq)(params)
         for a, b in zip(jax.tree.leaves(g1), jax.tree.leaves(g2)):

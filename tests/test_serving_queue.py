@@ -369,3 +369,77 @@ def test_close_drains_queued_work():
         assert f.result(timeout=60).shape == (10,)
     with pytest.raises(RuntimeError):
         eng.submit_async(np.zeros((12, 12, 1), np.float32))
+
+
+def test_batch_mode_runs_on_real_devices():
+    """n_cores=4 with four devices present: MultiCoreScheduler's batch
+    mode splits each formed batch across the devices (shard_map), never
+    the vmapped virtual cores, and serves logits bit-identical to one
+    device."""
+    _run_on_four_devices("""
+        out = {}
+        for cores in (1, 4):
+            with ContinuousBatchingEngine(batch=4, n_cores=cores,
+                                          backend="pallas") as eng:
+                eng.add_model(qnet)
+                out[cores] = eng.submit(x)
+        np.testing.assert_array_equal(out[1], out[4])
+        assert obs.metrics.counter("sched.batch.device").value == 2
+        assert obs.metrics.counter("sched.batch.virtual").value == 0
+    """)
+
+
+def test_device_programs_live_as_long_as_the_lru_entry():
+    """With n_cores=4 on four devices the scheduler keeps one shard_map
+    wrapper per program, and only while the engine's LRU keeps that
+    program: evict and recompile, and the scheduler holds one entry, not
+    one per compile.  The recompiled program serves the same logits."""
+    _run_on_four_devices("""
+        import gc
+        other = network.lenet(input_shape=(16, 16, 1))
+        y = rng.normal(size=(4, 16, 16, 1)).astype(np.float32)
+        qother = network.quantize_network(other, other.init_params(rng), y)
+        with ContinuousBatchingEngine(batch=4, n_cores=4, backend="pallas",
+                                      cache_capacity=1) as eng:
+            a = eng.add_model(qnet, name="a")
+            eng.add_model(qother, name="b")          # evicts a
+            sched = eng._models[a].sched
+            first = eng.submit(x, model=a)           # recompiles a
+            eng.submit(y, model="b")                 # evicts a again
+            second = eng.submit(x, model=a)          # and recompiles it
+            gc.collect()
+            assert len(sched._per_device) == 1, len(sched._per_device)
+            assert eng.metrics.counter("cache.evictions").value >= 3
+        np.testing.assert_array_equal(first, second)
+    """)
+
+
+def _run_on_four_devices(body: str) -> None:
+    """Run ``body`` in a subprocess that sees four CPU devices, after a
+    prelude that builds a quantized 12×12 lenet (``qnet``, images ``x``,
+    ``rng``), so the four-device flag never leaks into this process."""
+    import os
+    import subprocess
+    import sys
+    import textwrap
+    prelude = """
+        import os
+        os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+        import jax, numpy as np
+        from repro import obs
+        from repro.core import network
+        from repro.serving.batching import ContinuousBatchingEngine
+        assert jax.device_count() == 4
+        plan = network.lenet(input_shape=(12, 12, 1))
+        rng = np.random.default_rng(0)
+        params = plan.init_params(rng)
+        x = rng.normal(size=(8, 12, 12, 1)).astype(np.float32)
+        qnet = network.quantize_network(plan, params, x)
+    """
+    script = (textwrap.dedent(prelude) + textwrap.dedent(body)
+              + 'print("DEVICES_OK")\n')
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, PYTHONPATH="src", JAX_PLATFORMS="cpu")
+    r = subprocess.run([sys.executable, "-c", script], capture_output=True,
+                       text=True, env=env, cwd=repo, timeout=560)
+    assert "DEVICES_OK" in r.stdout, r.stdout[-1500:] + r.stderr[-4000:]

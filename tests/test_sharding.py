@@ -28,7 +28,7 @@ def test_sharded_train_step_matches_single_device():
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import jax, jax.numpy as jnp, numpy as np
         from repro.configs.base import get_config, reduce_config
-        from repro.distributed.sharding import ShardingPlan, use_mesh
+        from repro.distributed.sharding import ShardingPlan
         from repro.launch.mesh import make_debug_mesh
         from repro.layers.common import materialize, shape_structs, ParamSpec
         from repro.models import lm
@@ -61,7 +61,7 @@ def test_sharded_train_step_matches_single_device():
         st_sh = plan.param_shardings(full_specs)
         b_sh = plan.input_shardings(jax.tree.map(
             lambda t: jax.ShapeDtypeStruct(t.shape, t.dtype), batch))
-        with use_mesh(mesh):
+        with jax.set_mesh(mesh):
             sh_step = jax.jit(make_train_step(cfg, hp, act_rules=plan.acts),
                               in_shardings=(st_sh, b_sh),
                               out_shardings=(st_sh, None))
@@ -87,7 +87,7 @@ def test_sharded_decode_step_runs():
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import jax, jax.numpy as jnp, numpy as np
         from repro.configs.base import get_config, reduce_config
-        from repro.distributed.sharding import ShardingPlan, use_mesh
+        from repro.distributed.sharding import ShardingPlan
         from repro.launch.mesh import make_debug_mesh
         from repro.layers.common import materialize, shape_structs
         from repro.models import lm
@@ -101,7 +101,7 @@ def test_sharded_decode_step_runs():
         plan = ShardingPlan(mesh=mesh, fsdp=False, mode="decode")
         p_sh = plan.param_shardings(lm.param_specs(cfg))
         c_sh = plan.cache_shardings(cspecs)
-        with use_mesh(mesh):
+        with jax.set_mesh(mesh):
             cache = jax.tree.map(
                 lambda s, sh: jax.device_put(
                     jnp.zeros(s.shape, jnp.dtype(s.dtype)), sh),

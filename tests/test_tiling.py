@@ -143,8 +143,9 @@ if HAVE_HYPOTHESIS:
     @settings(max_examples=30, deadline=None)
     def test_plan_tiles_invariants(h, w, c, k, stride, pool):
         """plan_tiles: working set fits the budget (or nothing can shrink
-        further), tiles are pool-aligned, banks divide the channels, and
-        tiles cover the output."""
+        further), tiles are pool-aligned row bands of the full width,
+        banks divide the channels into lane-legal blocks, and tiles cover
+        the output."""
         budget = 1 << 20                       # 1 MiB: forces real tiling
         oh, ow = ref.conv_out_shape(h, w, 3, 3, stride, "SAME")
         if pool and (oh < 2 or ow < 2):
@@ -153,6 +154,9 @@ if HAVE_HYPOTHESIS:
                        pool=pool, in_bytes=1, out_bytes=1,
                        vmem_budget=budget)
         assert c % p.cin_banks == 0 and k % p.kout_banks == 0
+        assert ref.lane_legal_banks(c, p.cin_banks)
+        assert ref.lane_legal_banks(k, p.kout_banks)
+        assert p.w_tile == p.out_w
         if pool:
             assert p.h_tile % 2 == 0 and p.w_tile % 2 == 0
         assert p.n_h_tiles * p.h_tile >= p.out_h
@@ -162,10 +166,12 @@ if HAVE_HYPOTHESIS:
         assert p.image_block_bytes == p.in_h_tile * p.in_w_tile * cb
         assert p.acc_block_bytes == p.h_tile * p.w_tile * kb * 4
         if not p.fits_vmem:
-            # only legal when maximally split: minimal tiles AND banks
+            # only legal when maximally split: minimal row bands AND no
+            # lane-legal bank doubling left
             min_tile = 2 if pool else 1
-            assert p.h_tile <= min_tile and p.w_tile <= min_tile
-            assert cb == 1 and kb == 1
+            assert p.h_tile <= min_tile
+            assert not ref.lane_legal_banks(c, 2 * p.cin_banks)
+            assert not ref.lane_legal_banks(k, 2 * p.kout_banks)
 
 
 # ---------------------------------------------------------------------------
@@ -185,11 +191,22 @@ def test_bankplan_counts_acc_and_output_separately():
 
 
 def test_tileplan_working_set_separates_acc():
+    """The working set counts blocks as Mosaic lays them out (lanes to
+    128, sublanes to the dtype tile): double-buffered input, weight,
+    int8 output, bias and scale blocks, the int32 accumulator once, and
+    the kernel body's values (input window, one tap slice, four
+    accumulator-sized values)."""
     p = plan_tiles(64, 64, 8, 8, in_bytes=1, out_bytes=1, pool=False,
                    vmem_budget=None)
-    assert p.working_set_bytes == (
-        2 * (p.image_block_bytes + p.weight_block_bytes
-             + p.output_block_bytes) + p.acc_block_bytes)
+    lay = banking.laid_out_bytes
+    assert p.working_set_bytes == (                  # VALID: 64 → 62
+        2 * (lay((64, 64, 8), 1) + lay((3, 3, 8, 8), 1)
+             + lay((62, 62, 8), 1) + 2 * lay((1, 8), 4))
+        + lay((62, 62, 8), 4)
+        + lay((64, 64, 8), 1) + lay((62 * 62, 8), 1)
+        + 4 * lay((62 * 62, 8), 4))
+    assert lay((62, 62, 8), 1) == 62 * 64 * 128      # 32-row int8 tiles
+    assert lay((62, 62, 8), 4) == 62 * 64 * 128 * 4  # 8-row int32 tiles
     assert p.acc_block_bytes == p.h_tile * p.w_tile * (8 // p.kout_banks) * 4
 
 
@@ -228,10 +245,14 @@ def test_convcore_plans_tiles_for_large_maps():
     core = ConvCore(ConvCoreConfig(int8=True))
     plan = core.plan((1, 512, 512, 64), (3, 3, 64, 64), 1, "SAME")
     assert plan.tiled and plan.fits_vmem
-    # small maps keep the whole-map single tile and paper 4×4 banking
+    # small maps keep the whole-map single tile; the paper's 4×4 banking
+    # degrades to lane-legal banks (full-extent or 128-channel blocks)
     small = core.plan((1, 28, 28, 8), (3, 3, 8, 8), 1, "SAME")
     assert not small.tiled
-    assert small.cin_banks == 4 and small.kout_banks == 4
+    assert small.cin_banks == 1 and small.kout_banks == 1
+    wide = core.plan((1, 14, 14, 256), (3, 3, 256, 512), 1, "SAME")
+    assert not wide.tiled
+    assert wide.cin_banks == 2 and wide.kout_banks == 4
 
 
 @pytest.mark.parametrize("pool", [False, True])
@@ -311,7 +332,8 @@ def test_large_map_layer_exceeds_budget_and_runs_tiled():
     the VMEM budget; the planned tiled kernel is bit-exact vs ref."""
     whole = plan_tiles(512, 512, 64, 64, stride=1, padding="SAME",
                        in_bytes=1, out_bytes=4, vmem_budget=None)
-    assert whole.working_set_bytes > banking.VMEM_BYTES   # seed couldn't fit
+    # the seed's whole-map dataflow could not fit
+    assert whole.working_set_bytes > banking.VMEM_LIMIT_BYTES
     p = plan_tiles(512, 512, 64, 64, stride=1, padding="SAME",
                    in_bytes=1, out_bytes=4)
     assert p.tiled and p.fits_vmem
