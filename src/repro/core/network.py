@@ -778,61 +778,75 @@ def int8_forward(qnet: QuantizedNetwork, x: jax.Array, *, backend,
     computes; under ``jax.jit`` the hook only fires at trace time, so
     the compiled path must pass None (the compiler enforces nothing —
     profiling a jitted program through the hook is simply meaningless,
-    not unsafe)."""
+    not unsafe).
+
+    Every operation carries its place in the walk as a
+    ``jax.named_scope`` in its metadata: ``input`` (the quantize), the
+    node's name, and ``output`` (the final dequantize), so a profile maps
+    each device operation to its node.  Scopes change no computation."""
     plan = qnet.plan
     ins = plan.resolved_inputs()
     geoms = plan.conv_geometries()     # resolved (features, groups)
     merges = qnet.merge_scales or (None,) * len(plan.layers)
-    names = plan.node_names() if node_hook is not None else None
-    qin = jnp.clip(jnp.round(x.astype(jnp.float32) / qnet.in_scale),
-                   -128, 127).astype(jnp.int8)
+    names = plan.node_names()
+    with jax.named_scope("input"):
+        qin = jnp.clip(jnp.round(x.astype(jnp.float32) / qnet.in_scale),
+                       -128, 127).astype(jnp.int8)
     acts: List[jax.Array] = []
     for i, (sp, w, b, rq, ms, tp) in enumerate(zip(
             plan.layers, qnet.weights, qnet.biases, qnet.requants,
             merges, tile_plans)):
-        src = [qin if j < 0 else acts[j] for j in ins[i]]
-        h = src[0]
-        if sp.kind in ("conv", "conv_transpose"):
-            op = (backend.conv_transpose if sp.kind == "conv_transpose"
-                  else backend.conv)
-            h = op(h, w, b, stride=sp.stride,
-                   padding=sp.padding, groups=geoms[i][1],
-                   dilation=sp.dilation,
-                   relu=sp.relu, pool=sp.pool, out_scale=rq,
-                   plan=tp)
-            if rq is None:                       # final conv: dequantize
+        with jax.named_scope(names[i]):
+            h = _int8_node(sp, [qin if j < 0 else acts[j] for j in ins[i]],
+                           w, b, rq, ms, tp, geoms[i], backend)
+        if rq is None and sp.kind in ("conv", "conv_transpose", "dense"):
+            with jax.named_scope("output"):      # final layer: dequantize
                 h = h.astype(jnp.float32) * qnet.out_dequant
-        elif sp.kind == "pool":
-            # max-pool commutes with the monotone int8 mapping
-            h = ref.maxpool2d_ref(h, sp.size)
-        elif sp.kind == "avgpool":
-            # window mean rounds back onto the same int8 grid
-            h = ref.avgpool2d_ref(h, sp.size)
-        elif sp.kind == "globalpool":
-            h = ref.global_avgpool_ref(h)
-        elif sp.kind == "flatten":
-            h = h.reshape(h.shape[0], -1)
-        elif sp.kind == "dense":
-            acc = backend.matmul(h, w, b)        # int32
-            if sp.relu:
-                acc = jnp.maximum(acc, 0)
-            if rq is None:
-                h = acc.astype(jnp.float32) * qnet.out_dequant
-            else:
-                h = ref.requantize_ref(acc, rq)
-        elif sp.kind == "add":
-            # int32-free residual add: both branches requantize onto
-            # the merge node's shared int8 grid, then saturating add
-            h = ref.add_requant_ref(src[0], src[1], ms[0], ms[1],
-                                    relu=sp.relu)
-        elif sp.kind == "concat":
-            h = jnp.concatenate(
-                [ref.requantize_ref(s, m) for s, m in zip(src, ms)],
-                axis=-1)
         acts.append(h)
         if node_hook is not None:
             node_hook(i, names[i], sp, h)
     return acts[-1]
+
+
+def _int8_node(sp, src: List[jax.Array], w, b, rq, ms, tp, geom,
+               backend) -> jax.Array:
+    """One node of ``int8_forward`` on its int8 inputs ``src``; the final
+    conv or dense layer (``rq`` None) returns its int32 accumulator."""
+    h = src[0]
+    if sp.kind in ("conv", "conv_transpose"):
+        op = (backend.conv_transpose if sp.kind == "conv_transpose"
+              else backend.conv)
+        h = op(h, w, b, stride=sp.stride,
+               padding=sp.padding, groups=geom[1],
+               dilation=sp.dilation,
+               relu=sp.relu, pool=sp.pool, out_scale=rq,
+               plan=tp)
+    elif sp.kind == "pool":
+        # max-pool commutes with the monotone int8 mapping
+        h = ref.maxpool2d_ref(h, sp.size)
+    elif sp.kind == "avgpool":
+        # window mean rounds back onto the same int8 grid
+        h = ref.avgpool2d_ref(h, sp.size)
+    elif sp.kind == "globalpool":
+        h = ref.global_avgpool_ref(h)
+    elif sp.kind == "flatten":
+        h = h.reshape(h.shape[0], -1)
+    elif sp.kind == "dense":
+        h = backend.matmul(h, w, b)              # int32
+        if sp.relu:
+            h = jnp.maximum(h, 0)
+        if rq is not None:
+            h = ref.requantize_ref(h, rq)
+    elif sp.kind == "add":
+        # int32-free residual add: both branches requantize onto
+        # the merge node's shared int8 grid, then saturating add
+        h = ref.add_requant_ref(src[0], src[1], ms[0], ms[1],
+                                relu=sp.relu)
+    elif sp.kind == "concat":
+        h = jnp.concatenate(
+            [ref.requantize_ref(s, m) for s, m in zip(src, ms)],
+            axis=-1)
+    return h
 
 
 def make_int8_program(qnet: QuantizedNetwork,

@@ -5,14 +5,19 @@ One import surface for everything observable in the runtime:
 
 * ``obs.span("compile")`` / ``obs.span("layer:conv1", psums=...)`` —
   nestable trace spans (obs/trace.py) exported as Chrome
-  ``chrome://tracing`` JSON that Perfetto loads directly;
+  ``chrome://tracing`` JSON that Perfetto loads directly, and, once jax
+  is imported, also opened as ``jax.profiler.TraceAnnotation``s, so a
+  ``jax.profiler`` trace shows them on its host plane beside the
+  device's operations;
 * ``obs.metrics`` — the process-global :class:`MetricsRegistry`
   (obs/metrics.py): counters, gauges, p50/p90/p99 histograms, JSONL
   export, ``reset()`` for tests;
+* ``obs.watch_compiles()`` — counts every jit cache miss in
+  ``obs.metrics``' ``jax.compiles`` counter;
 * ``obs.profile.profile_network`` — per-layer wall time / psums /
   achieved GOPS / calibrated-model prediction over any compiled
-  ``NetworkPlan`` program, plus the live drift detector
-  (obs/profile.py).
+  ``NetworkPlan`` program, plus the drift detector (obs/profile.py),
+  both called explicitly, offline.
 
 **Disabled by default, zero overhead when disabled.**  ``obs.span``
 checks one module flag and returns a shared no-op context manager; the
@@ -21,7 +26,7 @@ and cannot observe it.  Enable with ``obs.enable()`` or by exporting
 ``REPRO_OBS=1`` before import.  ``obs.metrics`` is live regardless of
 the flag — incrementing a counter is nanoseconds and serving code
 (``ConvNetEngine.stats``) depends on its counts — but nothing *records
-spans* or *profiles layers* unless enabled.
+spans* unless enabled.
 
 ``obs.dump(dir)`` writes the trace (``obs_trace.json``) and the metrics
 (``obs_metrics.jsonl``) — the CI ``obs-smoke`` lane uploads both.
@@ -33,6 +38,7 @@ process the runtime runs in.
 from __future__ import annotations
 
 import os
+import threading
 from typing import Any, Optional
 
 from repro.obs.metrics import (Counter, Gauge, Histogram,  # noqa: F401
@@ -84,6 +90,44 @@ def instant(name: str, **args: Any) -> None:
     disabled."""
     if _enabled:
         tracer.instant(name, **args)
+
+
+COMPILES = "jax.compiles"
+COMPILE_MARK = "jax.compile"
+# once per program a jit cache miss lowers for the backend (nested jits
+# inline into it; the backend then compiles it or the persistent cache
+# serves it)
+_LOWERING_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+_compiles_lock = threading.Lock()
+_compiles_watched = False
+
+
+def _on_jax_duration(event: str, duration_secs: float, **kwargs) -> None:
+    if event != _LOWERING_EVENT:
+        return
+    metrics.counter(COMPILES).inc()
+    import jax
+    with jax.profiler.TraceAnnotation(COMPILE_MARK,
+                                      fun=str(kwargs.get("fun_name", ""))):
+        pass
+
+
+def watch_compiles() -> None:
+    """Count every jit cache miss, each new program lowered whether the
+    persistent cache or XLA then serves it, in ``metrics``'
+    ``jax.compiles`` counter (live regardless of the flag; an eager op on
+    a new shape is a program too), and mark each one as a zero-length
+    ``jax.compile`` event in whatever ``jax.profiler`` trace is running,
+    so a trace shows when it happened.  Imports jax; registers its
+    ``jax.monitoring`` listener once per process."""
+    global _compiles_watched
+    with _compiles_lock:
+        if _compiles_watched:
+            return
+        from jax import monitoring
+        metrics.counter(COMPILES)
+        monitoring.register_event_duration_secs_listener(_on_jax_duration)
+        _compiles_watched = True
 
 
 def dump(out_dir: str = ".", prefix: str = "obs") -> Optional[dict]:

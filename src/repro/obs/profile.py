@@ -1,4 +1,4 @@
-"""Per-layer profiler + live model-drift detection.
+"""Per-layer profiler + model-drift detection.
 
 PR 7 made the cost model *calibrated* (benchmarks/calibrate.py fits a
 ``CalibrationTable`` onto the §5.2 terms) but only compared it against
@@ -26,9 +26,9 @@ reality inside offline benchmark scripts (``network_bench``'s
   in the trace, so a Perfetto view shows *where* the model lost the
   machine.
 
-Profiling imports jax lazily and is only ever called explicitly (or by
-the engine when obs is enabled) — the obs package itself stays
-dependency-free.
+Profiling imports jax lazily and is only ever called explicitly, offline:
+an eager layer walk compiles op by op, so it never runs on a serving
+thread.  The obs package itself stays dependency-free.
 """
 
 from __future__ import annotations

@@ -22,15 +22,21 @@ Design constraints (the reason this is not a logging veneer):
   checks one global flag and returns a singleton no-op context manager;
   no allocation, no clock read, no lock.  Tier-1 numerics and the §5.2
   anchor assertions run with tracing disabled and must not be able to
-  tell it exists.
+  tell it exists;
+* **on the device trace's clock** — once jax is imported, each live span
+  also opens a ``jax.profiler.TraceAnnotation`` of the same name and
+  args, so a ``jax.profiler`` trace holds the spans on its host plane,
+  beside the device's operations.
 
-Dependency-free by construction: stdlib only.
+Dependency-free by construction: stdlib only; jax is used only when
+something else has already imported it.
 """
 
 from __future__ import annotations
 
 import json
 import os
+import sys
 import threading
 import time
 from typing import Any, Dict, List, Optional
@@ -59,12 +65,24 @@ class _NoopSpan:
 NOOP_SPAN = _NoopSpan()
 
 
+def _profiler_annotation(name: str, args: Dict[str, Any]):
+    """A ``jax.profiler.TraceAnnotation`` for a span, or None before jax
+    is imported.  Args the profiler cannot hold become strings."""
+    jax = sys.modules.get("jax")
+    profiler = getattr(jax, "profiler", None)
+    if profiler is None:
+        return None
+    return profiler.TraceAnnotation(name, **{
+        k: v if isinstance(v, (bool, int, float, str)) else str(v)
+        for k, v in args.items()})
+
+
 class Span:
     """One live span: a context manager that records a complete trace
     event on exit — including when the body raises (the event is
     recorded with an ``error`` arg and the exception propagates)."""
 
-    __slots__ = ("tracer", "name", "args", "_t0", "_parent")
+    __slots__ = ("tracer", "name", "args", "_t0", "_parent", "_annotation")
 
     def __init__(self, tracer: "Tracer", name: str, args: Dict[str, Any]):
         self.tracer = tracer
@@ -72,6 +90,7 @@ class Span:
         self.args = args
         self._t0 = 0
         self._parent: Optional[str] = None
+        self._annotation = None
 
     def set(self, **args: Any) -> "Span":
         """Attach/override args on the live span (e.g. results computed
@@ -84,11 +103,17 @@ class Span:
         if stack:
             self._parent = stack[-1].name
         stack.append(self)
+        self._annotation = _profiler_annotation(self.name, self.args)
+        if self._annotation is not None:
+            self._annotation.__enter__()
         self._t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
         t1 = time.perf_counter_ns()
+        if self._annotation is not None:
+            self._annotation.__exit__(exc_type, exc, tb)
+            self._annotation = None
         stack = self.tracer._stack()
         # exception safety: pop THIS span even if an inner span leaked
         while stack and stack[-1] is not self:

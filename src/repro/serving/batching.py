@@ -47,10 +47,21 @@ Three pieces, composable and individually testable:
 
 Telemetry (all through the PR 9 obs layer, in the engine's own
 ``MetricsRegistry``): ``queue.depth`` / ``queue.depth.peak`` gauges,
-``queue_wait_us`` + ``batch_device_us`` + honest enqueue→result
-``request_latency_us`` histograms, ``batch_formed.{full,deadline,drain}``
-and ``cache.{hits,misses,evictions}`` counters, ``batch_fill``, and
-``route.<mode>`` counters when routing is live.
+``queue_wait_us`` + honest enqueue→result ``request_latency_us``
+histograms, ``batch_formed.{full,deadline,drain}`` and
+``cache.{hits,misses,evictions}`` counters, ``batch_fill``, and
+``route.<mode>`` counters when routing is live; jit cache misses in the
+global ``jax.compiles`` counter (``obs.watch_compiles``).
+
+With obs enabled the worker thread's time splits into spans that do not
+overlap, each also on a ``jax.profiler`` trace's host plane:
+``engine.wait`` (forming a batch, or waiting on the queue for work),
+``engine.stage`` (stacking and padding the batch's images),
+``engine.put`` (the host-to-device copy), ``sched.run`` (the launch),
+``engine.ready`` (waiting for the device result), ``engine.fetch`` (the
+device-to-host copy) and ``engine.resolve`` (setting each request's
+result, the futures' callbacks included).  Their args are per batch:
+``reason``, ``fill``, ``n``.
 """
 
 from __future__ import annotations
@@ -290,9 +301,7 @@ class ContinuousBatchingEngine:
     next batch launches.
 
     Per-request latency (``request_latency_us`` → ``latency_
-    percentiles()``) is **enqueue→result** — it includes queue wait,
-    unlike the old submit-and-wait accounting, which survives as
-    ``batch_device_us`` (dispatch→materialized batch wall).
+    percentiles()``) is **enqueue→result** — it includes queue wait.
 
     ``route=True`` + a per-model ``tune`` (NetworkTunePlan) routes each
     formed batch through the scheduler mode ``autotune.route_batch``
@@ -302,8 +311,7 @@ class ContinuousBatchingEngine:
     def __init__(self, *, batch: int = 8, n_cores: int = 1,
                  backend: str = "pallas", deadline_ms: float = 5.0,
                  bulk_aging_ms: float = 50.0, cache_capacity: int = 4,
-                 max_inflight: int = 2, calib=None, drift_band=None,
-                 route: bool = False,
+                 max_inflight: int = 2, calib=None, route: bool = False,
                  clock: Callable[[], int] = time.perf_counter_ns):
         if batch < 1:
             raise ValueError(f"batch must be >= 1, got {batch}")
@@ -327,7 +335,6 @@ class ContinuousBatchingEngine:
                         for r in FORMATION_REASONS}
         self._latency = self.metrics.histogram("request_latency_us")
         self._queue_wait = self.metrics.histogram("queue_wait_us")
-        self._device = self.metrics.histogram("batch_device_us")
         self._fill = self.metrics.histogram(
             "batch_fill", bounds=[i / 16 for i in range(1, 17)])
         self._models: Dict[str, _Model] = {}
@@ -339,9 +346,7 @@ class ContinuousBatchingEngine:
         self._worker_lock = threading.Lock()
         self._stopping = False
         self.max_inflight = max_inflight
-        self.layer_profile = None          # first obs'd batch, any model
-        self.drift_events: tuple = ()
-        self._drift_band = drift_band
+        obs.watch_compiles()
 
     # -- model registry ------------------------------------------------------
 
@@ -510,7 +515,7 @@ class ContinuousBatchingEngine:
         while True:
             fb = None
             retire_idle = False
-            with self.queue.cond:
+            with obs.span("engine.wait"), self.queue.cond:
                 while not self._stopping:
                     fb = self.queue.form_locked(
                         self.batch, drain=self._drain_waiters > 0)
@@ -551,23 +556,6 @@ class ContinuousBatchingEngine:
         while self._inflight:
             self._retire_one()
 
-    def _maybe_profile(self, entry: _Model, chunk: np.ndarray,
-                       tile_plans, cfg) -> None:
-        """One-off layer-at-a-time profile of the first observed batch
-        (obs enabled only) — the per-layer breakdown + live drift check
-        a running server can't get from offline benches."""
-        import jax.numpy as jnp
-
-        from repro.obs.profile import DriftDetector, profile_network
-        drift = None
-        if self.calib is not None:
-            drift = DriftDetector(self._drift_band) if self._drift_band \
-                else DriftDetector()
-        self.layer_profile = profile_network(
-            entry.qnet, jnp.asarray(chunk), core_config=cfg,
-            tile_plans=tile_plans, calib=self.calib, drift=drift)
-        self.drift_events = self.layer_profile.drift
-
     def _route_for(self, entry: _Model,
                    n_real: int) -> Tuple[str, Any, Optional[str]]:
         """(backend_name, scheduler, routed-mode) for one formed batch.
@@ -592,7 +580,7 @@ class ContinuousBatchingEngine:
         return cached
 
     def _dispatch(self, fb: FormedBatch) -> None:
-        import jax.numpy as jnp
+        import jax
         entry = self._models[fb.model]
         n_real = len(fb.requests)
         pad = self.batch - n_real
@@ -603,42 +591,40 @@ class ContinuousBatchingEngine:
         self._fill.observe(n_real / self.batch)
         if pad:
             self._padded.inc(pad)
-        chunk = np.stack([r.image for r in fb.requests])
-        if pad:
-            chunk = np.concatenate(
-                [chunk, np.zeros((pad, *entry.input_shape), np.float32)])
-        backend_name, sched, routed = self._route_for(entry, n_real)
-        program, tile_plans, cfg = self._compiled(entry, backend_name)
-        if obs.enabled() and self.layer_profile is None:
-            self._maybe_profile(entry, chunk, tile_plans, cfg)
-        t0 = self.clock()
-        with obs.span("engine.batch", network=entry.qnet.plan.name,
-                      model=entry.name, fill=n_real / self.batch,
-                      padded=pad, reason=fb.reason,
-                      **({"routed": routed} if routed else {})):
-            dev = sched.run(program, jnp.asarray(chunk))
+        span_args = {"reason": fb.reason, "fill": n_real / self.batch,
+                     "n": n_real}
+        with obs.span("engine.stage", **span_args):
+            chunk = np.stack([r.image for r in fb.requests])
+            if pad:
+                chunk = np.concatenate(
+                    [chunk, np.zeros((pad, *entry.input_shape), np.float32)])
+        backend_name, sched, _ = self._route_for(entry, n_real)
+        program, _, _ = self._compiled(entry, backend_name)
+        with obs.span("engine.put", **span_args):
+            x = jax.device_put(chunk)
+        dev = sched.run(program, x)
         # async dispatch: the device result stays unmaterialized; the
         # next batch forms and launches while this one computes
-        self._inflight.append((dev, fb, t0))
+        self._inflight.append((dev, fb, span_args))
 
     def _retire_one(self) -> None:
-        dev, fb, t0 = self._inflight.popleft()
+        dev, fb, span_args = self._inflight.popleft()
         try:
-            logits = np.asarray(dev)          # blocks on the device
+            with obs.span("engine.ready", **span_args):
+                dev.block_until_ready()
+            with obs.span("engine.fetch", **span_args):
+                logits = np.asarray(dev)
         except BaseException as e:
             for r in fb.requests:
                 if not r.future.done():
                     r.future.set_exception(e)
             return
         now = self.clock()
-        # dispatch→materialized wall: equals device time when the queue
-        # drains faster than the device, an upper bound when batches
-        # stack up behind max_inflight
-        self._device.observe((now - t0) / 1e3)
         self._batches.inc()
-        for i, r in enumerate(fb.requests):
-            self._latency.observe((now - r.enqueue_ns) / 1e3)
-            r.future.set_result(logits[i])
+        with obs.span("engine.resolve", **span_args):
+            for i, r in enumerate(fb.requests):
+                self._latency.observe((now - r.enqueue_ns) / 1e3)
+                r.future.set_result(logits[i])
 
     # -- stats / lifecycle ---------------------------------------------------
 
@@ -662,8 +648,7 @@ class ContinuousBatchingEngine:
 
     def latency_percentiles(self) -> Dict[str, float]:
         """p50/p90/p99 (+count/mean) of honest enqueue→result latency in
-        µs (queue wait INCLUDED — the old batch-wall-only number lives
-        on as ``batch_device_us``)."""
+        µs (queue wait INCLUDED)."""
         return self._latency.summary()
 
     def close(self, timeout: float = SUBMIT_TIMEOUT_S) -> None:

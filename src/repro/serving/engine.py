@@ -160,18 +160,18 @@ class ConvNetEngine:
 
     Telemetry: counters (requests / batches / padded), the honest
     enqueue→result ``request_latency_us`` histogram (queue wait
-    INCLUDED — the pre-queue batch-wall-only number lives on as
-    ``batch_device_us``), ``queue_wait_us``, ``batch_fill``, queue-depth
-    gauges, formation-reason and program-cache counters — all in the
-    per-engine ``.metrics`` registry.  With obs ENABLED
-    (``obs.enable()`` / ``REPRO_OBS=1``) compiles and batches get trace
-    spans and the first batch triggers a one-off layer-at-a-time profile
-    (``.layer_profile``; ``calib`` + ``drift_band`` arm the live drift
-    check whose hits land in ``.drift_events``)."""
+    INCLUDED), ``queue_wait_us``, ``batch_fill``, queue-depth gauges,
+    formation-reason and program-cache counters — all in the per-engine
+    ``.metrics`` registry.  With obs ENABLED (``obs.enable()`` /
+    ``REPRO_OBS=1``) compiles get ``engine.compile`` spans and the worker
+    thread's states (``engine.wait``, ``engine.stage``, ``engine.put``,
+    ``sched.run``, ``engine.ready``, ``engine.fetch``,
+    ``engine.resolve``) get spans that also land in ``jax.profiler``
+    traces, beside the device's operations."""
 
     def __init__(self, qnet, *, batch: int = 8, n_cores: int = 1,
                  backend: str = "pallas", tune=None, calib=None,
-                 drift_band=None, deadline_ms: float = 5.0,
+                 deadline_ms: float = 5.0,
                  bulk_aging_ms: float = 50.0, max_inflight: int = 2,
                  route: bool = False):
         from repro.serving.batching import ContinuousBatchingEngine
@@ -184,7 +184,7 @@ class ConvNetEngine:
             batch=batch, n_cores=n_cores, backend=backend,
             deadline_ms=deadline_ms, bulk_aging_ms=bulk_aging_ms,
             cache_capacity=4, max_inflight=max_inflight, calib=calib,
-            drift_band=drift_band, route=route)
+            route=route)
         self.model = self.engine.add_model(qnet, tune=tune)
 
     @property
@@ -195,14 +195,6 @@ class ConvNetEngine:
     def stats(self) -> Dict[str, int]:
         """Backward-compatible counter view (the old ad-hoc dict)."""
         return self.engine.stats
-
-    @property
-    def layer_profile(self):
-        return self.engine.layer_profile
-
-    @property
-    def drift_events(self):
-        return self.engine.drift_events
 
     def latency_percentiles(self) -> Dict[str, float]:
         """p50/p90/p99 (+count/mean) of per-request enqueue→result

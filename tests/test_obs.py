@@ -7,6 +7,7 @@ leaked spans or metric counts.
 """
 
 import json
+import time
 
 import numpy as np
 import pytest
@@ -291,29 +292,112 @@ def test_engine_stats_and_percentiles():
     pct = eng.latency_percentiles()
     assert pct["count"] == 3
     assert 0 < pct["p50"] <= pct["p90"] <= pct["p99"]
-    # obs disabled: no spans recorded, no profile taken
+    # obs disabled: no spans recorded
     assert len(obs.tracer) == 0
-    assert eng.layer_profile is None
+
+
+WORKER_SPANS = ("engine.wait", "engine.stage", "engine.put", "sched.run",
+                "engine.ready", "engine.fetch", "engine.resolve")
 
 
 def test_engine_obs_enabled_profiles_first_batch():
+    """With obs on, the worker thread's states are spans that do not
+    overlap, each with per-batch args; the eager layer walk is gone."""
     from repro.serving.engine import ConvNetEngine
     qnet, _ = _lenet_qnet()
     obs.enable()
     eng = ConvNetEngine(qnet, batch=2, backend="pallas")
     rng = np.random.default_rng(1)
-    imgs = rng.normal(size=(2, *qnet.plan.input_shape)).astype(np.float32)
+    imgs = rng.normal(size=(3, *qnet.plan.input_shape)).astype(np.float32)
     eng.submit(imgs)
-    assert eng.layer_profile is not None
-    assert eng.layer_profile.layer_names == list(qnet.plan.node_names())
-    assert eng.drift_events == ()            # no calib → no drift check
-    names = [e["name"] for e in obs.tracer.events()]
-    assert "engine.compile" in names and "engine.batch" in names
-    # obs off → same engine records nothing more
+    deadline = time.monotonic() + 60      # the last resolve span may still
+    while sum(e["name"] == "engine.resolve"            # be closing
+              for e in obs.tracer.events()) < 2 and time.monotonic() < deadline:
+        time.sleep(0.01)
+    events = obs.tracer.events()
+    names = [e["name"] for e in events]
+    assert "engine.compile" in names and "engine.batch" not in names
+    assert not hasattr(eng, "layer_profile")
+    assert not any(n.startswith("layer:") for n in names)
+    worker = sorted((e for e in events if e["name"] in WORKER_SPANS),
+                    key=lambda e: e["ts"])
+    assert {e["name"] for e in worker} == set(WORKER_SPANS)
+    assert len({e["tid"] for e in worker}) == 1       # the worker thread
+    for a, b in zip(worker, worker[1:]):
+        assert a["ts"] + a["dur"] <= b["ts"] + 1e-3    # no overlap (µs)
+    for name in WORKER_SPANS[1:]:
+        per_batch = [e["args"] for e in worker if e["name"] == name]
+        if name == "sched.run":                 # the padded launch
+            assert [a["batch"] for a in per_batch] == [2, 2]
+            continue
+        assert [(a["n"], a["fill"]) for a in per_batch] == [(2, 1.0),
+                                                           (1, 0.5)]
+        assert per_batch[0]["reason"] == "full"
+        assert per_batch[1]["reason"] in ("drain", "deadline")
+    # obs off → same engine records nothing more (once the wait span that
+    # was open when it went off has ended)
     obs.disable()
+    eng.submit(imgs)
     n = len(obs.tracer)
     eng.submit(imgs)
     assert len(obs.tracer) == n
+
+
+def test_span_opens_profiler_annotation_only_once_jax_is_loaded(
+        monkeypatch):
+    """A live span opens a jax.profiler.TraceAnnotation with its name and
+    args; before jax is imported it opens none (obs stays importable
+    without jax)."""
+    import sys
+
+    import jax
+    from repro.obs import trace as trace_mod
+    opened = []
+
+    class Annotation:
+        def __init__(self, name, **args):
+            opened.append((name, args))
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            return False
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Annotation)
+    obs.enable()
+    with obs.span("engine.stage", reason="full", n=8, shape=(8, 3)):
+        pass
+    assert opened == [("engine.stage",
+                       {"reason": "full", "n": 8, "shape": "(8, 3)"})]
+    monkeypatch.delitem(sys.modules, "jax")
+    assert trace_mod._profiler_annotation("x", {}) is None
+    with obs.span("no_jax"):
+        pass
+    assert len(opened) == 1
+    assert [e["name"] for e in obs.tracer.events()] == ["engine.stage",
+                                                        "no_jax"]
+
+
+def test_watch_compiles_counts_each_new_trace_once():
+    """One listener however often it is asked for; each jit cache miss
+    (a new shape) counts one program, its nested jits none, and a cached
+    call none, with obs off too."""
+    import jax
+    import jax.numpy as jnp
+    obs.watch_compiles()
+    obs.watch_compiles()
+    f = jax.jit(lambda x: x * 2 + 1)
+    x3, x5 = jnp.ones(3), jnp.ones(5)
+    counter = obs.metrics.counter(obs.COMPILES)
+    before = counter.value
+    f(x3).block_until_ready()
+    after_one = counter.value
+    f(x3).block_until_ready()
+    assert counter.value == after_one
+    f(x5).block_until_ready()
+    assert after_one - before == 1 and counter.value - after_one == 1
+    assert len(obs.tracer) == 0
 
 
 def test_obs_dump_writes_both_artifacts(tmp_path):
