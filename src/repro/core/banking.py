@@ -8,7 +8,7 @@ TPU the analogous resource is VMEM: a grid step's working set is
 
     2 × (halo'd image block + weight block + epilogue output block
          + bias block + scale block) + accumulator scratch
-      + the kernel body's values (conv_vmem_bytes)
+      [+ tap patch scratch] + the kernel body's values (conv_vmem_bytes)
 
 — the ×2 is Pallas's load/compute pipeline double-buffering (M4) of the
 DMA'd blocks; the accumulator scratch is a single persistent VMEM buffer
@@ -50,12 +50,13 @@ from dataclasses import dataclass, replace
 from typing import Optional
 
 from repro.kernels.ref import (LANES, VMEM_LIMIT_BYTES, check_groups,
-                               conv_out_shape, dilated_extent, grouped_banks,
-                               halo_window, lane_legal_banks,
+                               conv_out_shape, dilated_extent, folds_taps,
+                               grouped_banks, halo_window, lane_legal_banks,
                                normalize_padding)
 from repro.kernels.ref import divisor_banks as _ref_divisor_banks
 
 VMEM_BYTES_V5E = 128 * 1024 * 1024   # legacy generous budget (BankPlan)
+ACC_VALUES = 3
 
 
 def laid_out_bytes(shape, itemsize: int) -> int:
@@ -202,30 +203,53 @@ class TilePlan:
         return tiled / whole if whole else 1.0
 
 
+def _round_up(n: int, m: int) -> int:
+    return -(-n // m) * m
+
+
 def conv_vmem_bytes(in_h: int, in_w: int, cb: int, kh: int, kw: int,
                     kb: int, th: int, tw: int, pool: bool, in_bytes: int,
-                    out_bytes: int, acc_bytes: int) -> int:
+                    out_bytes: int, acc_bytes: int, stride: int = 1) -> int:
     """Laid-out VMEM working set of one conv grid step.
 
     Blocks: two buffers each of the (in_h × in_w × cb) input window, the
     (kh × kw × cb × kb) weight bank, the epilogue output block and the
-    (1 × kb) bias and scale blocks, plus the (th × tw × kb) accumulator
-    scratch once.  Kernel body: Mosaic keeps the body's values in VMEM
-    too — the loaded input window, one tap's (th·tw × cb) slice, and up
-    to four accumulator-sized values (the running sum, a tap's matmul
-    result, their sum, the epilogue's f32 copy).  Against the scoped
-    allocation Mosaic reported for a vgg_imagenet layer (112×112×32 → 64,
-    both kernels, two tile heights) compiled for a v5e, this count ran
-    11–23% high: a safe promise, not a tight one."""
+    (1 × kb) bias and scale blocks.  Scratch and the kernel body's values
+    follow the body's form (``ref.folds_taps``, ``conv2d_ws.conv_slab``):
+
+    * taps folded into one contraction: the (th·W × kb) accumulator, W
+      the window width as the DMA lays it out (a multiple of 8), and the
+      (M × kh·kw·cb) tap patch, M = (th−1)·W + tw, each tap's column
+      block a whole number of 128-lane vregs (``setup_conv`` extends the
+      channels of an int8 layer that runs one cin bank); the body holds
+      the window and its flattened copy, one tap's rows, and two
+      accumulator-sized values (the slab's matmul result and the sum it
+      is added into; the epilogue's map and its f32 copy reuse them);
+    * one dot per tap: the (th·tw × kb) accumulator; the body holds the
+      window, one tap's (th·tw × cb) slice, and four accumulator-sized
+      values (the slab's running sum, a tap's matmul result, their sum,
+      the epilogue's f32 copy).
+
+    Against the scoped allocation Mosaic reports for VGG-16's conv layers
+    compiled for a v5e at batch 8, this count runs 1.7–3.9× high for
+    conv2d_ws_pipe, and higher for conv2d_ws, whose Pallas-pipelined
+    blocks that figure leaves out (tests/test_chip_compile.py checks
+    conv4_2 and conv5_3): a safe promise, not a tight one."""
     pth, ptw = (th // 2, tw // 2) if pool else (th, tw)
-    acc = laid_out_bytes((th * tw, kb), acc_bytes)
     window = laid_out_bytes((in_h, in_w, cb), in_bytes)
-    blocks = (2 * (window + laid_out_bytes((kh, kw, cb, kb), in_bytes)
-                   + laid_out_bytes((pth, ptw, kb), out_bytes)
-                   + 2 * laid_out_bytes((1, kb), 4))
-              + laid_out_bytes((th, tw, kb), acc_bytes))
-    body = window + laid_out_bytes((th * tw, cb), in_bytes) + 4 * acc
-    return blocks + body
+    blocks = 2 * (window + laid_out_bytes((kh, kw, cb, kb), in_bytes)
+                  + laid_out_bytes((pth, ptw, kb), out_bytes)
+                  + 2 * laid_out_bytes((1, kb), 4))
+    if not folds_taps(tw, stride, kh, kw):
+        acc = laid_out_bytes((th * tw, kb), acc_bytes)
+        body = window + laid_out_bytes((th * tw, cb), in_bytes) + 4 * acc
+        return blocks + acc + body
+    wide = _round_up(in_w, 8)
+    rows = (th - 1) * wide + tw
+    acc = laid_out_bytes((th * wide, kb), acc_bytes)
+    patch = laid_out_bytes((rows, kh * kw * _round_up(cb, LANES)), in_bytes)
+    body = 2 * window + laid_out_bytes((rows, cb), in_bytes) + 2 * acc
+    return blocks + acc + patch + body
 
 
 def tile_plan(h: int, w: int, c: int, k: int, kh: int, kw: int, th: int,
@@ -260,7 +284,7 @@ def tile_plan(h: int, w: int, c: int, k: int, kh: int, kw: int, th: int,
         output_block_bytes=pth * ptw * kb * out_bytes,
         working_set_bytes=conv_vmem_bytes(
             blk_h, blk_w, cb, kh, kw, kb, th, tw, pool, in_bytes, out_bytes,
-            acc_bytes),
+            acc_bytes, stride),
         stride=stride, out_h=oh, out_w=ow, pool=pool, in_bytes=in_bytes,
         budget=budget, groups=groups)
 

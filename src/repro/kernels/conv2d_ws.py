@@ -14,12 +14,21 @@ Mapping of the FPGA architecture (DESIGN.md §3):
   window of the padded map into VMEM;
 * the weight block (the Weight Loader contents) is VMEM-resident for the
   whole spatial sweep of a grid step — weight-stationary;
-* the accumulator is a VMEM scratch block (the output BRAMs), revisited
-  across the cin sweep and *initialized with the bias at cin step 0* —
-  the paper's bias-preload trick (M5), so bias costs zero extra passes;
-* the KH×KW window is computed as KH·KW shifted (HW×Cb)@(Cb×Kb) MXU
-  matmuls — the systolic-array form of "9 MACs + adder tree" per PCORE;
-  stride-s convolution reads the shifted slices with stride s;
+* the accumulator is a 2-D (pixels × Kb) VMEM scratch block (the output
+  BRAMs), revisited across the cin sweep and *initialized with the bias
+  at cin step 0* — the paper's bias-preload trick (M5), so bias costs
+  zero extra passes; it takes its (H, W, Kb) map shape only in the
+  epilogue, so no int32 value changes layout inside the cin sweep;
+* the KH×KW window is the systolic-array form of "9 MACs + adder tree"
+  per PCORE (``conv_slab``, shared with conv2d_ws_pipe).  Where the tile
+  width is off the 8-row sublane tile (VGG-16's 28² and 14² maps), the
+  KH·KW taps are folded into one contraction: each tap is a contiguous
+  row run of the window flattened over its full width, copied into a
+  (rows × KH·KW·Cb) patch in VMEM, and one (KH·KW·Cb)-deep MXU matmul
+  adds the cin slab into the accumulator.  Elsewhere each tap runs its
+  own (HW×Cb)@(Cb×Kb) matmul on the shifted slice, the taps summed
+  before one accumulator update per slab; stride-s convolution reads
+  the shifted slices with stride s;
 * on the LAST cin step the fused epilogue runs in VMEM before writeback —
   ReLU → 2×2 max-pool → requantize(int8) — the FPGA "post-process in the
   output BRAMs before DMA-out" idiom, so a conv+relu+pool layer costs one
@@ -53,10 +62,10 @@ even (pool-aligned) so no 2×2 pool window straddles a tile edge — tile
 boundaries then land on pool-window boundaries and tiled pooling equals
 whole-map pooling.  core/banking.plan_tiles chooses (h_tile, cin_banks,
 kout_banks) jointly so the VMEM working set (halo'd input block + weight
-block + accumulator scratch + epilogue output block, with pipeline
-double-buffering, plus the kernel body's values — all counted as Mosaic
-lays them out) fits ``VMEM_LIMIT_BYTES``, the ``vmem_limit_bytes`` both
-conv kernels pass to Mosaic.
+block + epilogue output block, with pipeline double-buffering, plus the
+accumulator and tap-patch scratch and the kernel body's values — all
+counted as Mosaic lays them out) fits ``VMEM_LIMIT_BYTES``, the
+``vmem_limit_bytes`` both conv kernels pass to Mosaic.
 
 Padding is materialized by zero-padding the feature map before the kernel
 (the FPGA writes zero margins into the image BRAMs); zero padding is exact
@@ -80,8 +89,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.ref import (LANES, VMEM_LIMIT_BYTES, check_groups,
-                               conv_out_shape, dilated_extent, halo_window,
-                               normalize_padding)
+                               conv_out_shape, dilated_extent, folds_taps,
+                               halo_window, normalize_padding)
 
 DMA_SUBLANES = 8    # second-minor alignment of an int8 map sliced by a DMA
 
@@ -251,13 +260,96 @@ def halo_block_spec(g: ConvGeom, index_map) -> pl.BlockSpec:
         elem_map)
 
 
-def _conv_kernel(x_ref, w_ref, b_ref, s_ref, o_ref, acc_ref, *, kh: int,
-                 kw: int, stride: int, cin_banks: int, relu: bool,
-                 pool: bool, requant: bool, acc_dtype, dilation: int = 1):
-    co = pl.program_id(4)
+def conv_slab(x, w, acc_ref, patch_ref, *, th: int, tw: int, stride: int,
+              dilation: int, acc_dtype):
+    """Add one cin slab's convolution into the accumulator — the compute
+    body both conv kernels share, so their results agree bit for bit.
 
-    th, tw, kb = acc_ref.shape
-    cb = x_ref.shape[3]
+    ``x`` is the slab's halo'd input window [in_th, W, CB], ``w`` its
+    weight bank [KH, KW, CB, KB], ``acc_ref`` the 2-D accumulator.  The
+    form follows the shape (``ref.folds_taps``; ``conv_scratch`` sizes
+    the scratch to match):
+
+    * taps folded into one contraction (``patch_ref`` given): the window
+      is flattened to [in_th·W, CB] rows, so tap (dy, dx) is the
+      contiguous run of M = (TH−1)·W + TW rows from dilation·(dy·W + dx)
+      and output pixel (y, x) is accumulator row y·W + x; the columns
+      x ≥ TW between the rows are computed and dropped by the epilogue.
+      Each tap's rows are copied into column block t = dy·KW + dx of the
+      [M, KH·KW·CB] patch, and one MXU dot against the bank reshaped to
+      [KH·KW·CB, KB] (a merge of leading dims) adds the slab into the
+      [TH·W, KB] accumulator: no value changes layout;
+    * otherwise one dot per tap on its shifted slice (every stride-th row
+      and column), the dots summed and added into the [TH·TW, KB]
+      accumulator once per slab.  A 1×1 kernel is one tap."""
+    kh, kw, cb, kb = w.shape
+    if patch_ref is not None:
+        wide = x.shape[1]
+        rows = patch_ref.shape[0]
+        flat = x.reshape(x.shape[0] * wide, cb)
+        for dy in range(kh):
+            for dx in range(kw):
+                t = dy * kw + dx
+                off = dilation * (dy * wide + dx)
+                patch_ref[:, t * cb:(t + 1) * cb] = jax.lax.slice(
+                    flat, (off, 0), (off + rows, cb))
+        acc_ref[:rows] += jnp.dot(patch_ref[...],
+                                  w.reshape(kh * kw * cb, kb),
+                                  preferred_element_type=acc_dtype)
+        return
+    part = None
+    for dy in range(kh):
+        for dx in range(kw):
+            xs = jax.lax.slice(
+                x, (dy * dilation, dx * dilation, 0),
+                (dy * dilation + (th - 1) * stride + 1,
+                 dx * dilation + (tw - 1) * stride + 1, cb),
+                (stride, stride, 1)).reshape(th * tw, cb)
+            d = jnp.dot(xs, w[dy, dx], preferred_element_type=acc_dtype)
+            part = d if part is None else part + d
+    acc_ref[...] += part
+
+
+def conv_epilogue(acc_ref, s_ref, *, th: int, tw: int, relu: bool,
+                  pool: bool, requant: bool):
+    """The fused epilogue both conv kernels run on the finished
+    accumulator: the FPGA post-processes the output BRAMs (activation,
+    pooling, requantization) before writeback.  The 2-D accumulator takes
+    its [TH, TW, KB] map shape here, once per grid step, dropping the
+    columns a folded body computes past TW.  Tile-local: pool-aligned
+    tiles guarantee no 2×2 window straddles a tile edge, so per-tile
+    pooling == whole-map pooling."""
+    kb = acc_ref.shape[1]
+    wide = acc_ref.shape[0] // th
+    y = acc_ref[...].reshape(th, wide, kb)
+    if wide != tw:
+        y = y[:, :tw]
+    if relu:
+        y = jnp.maximum(y, 0)
+    if pool:
+        y = jnp.max(y.reshape(th // 2, 2, tw // 2, 2, kb), axis=(1, 3))
+    if requant:
+        y = jnp.clip(jnp.round(y.astype(jnp.float32) * s_ref[...]),
+                     -128, 127)
+    return y
+
+
+def conv_scratch(g: ConvGeom, dtype, acc_dtype) -> list:
+    """VMEM scratch of the compute body (``conv_slab``): the 2-D
+    accumulator and, where the body folds the taps, the tap patch; a
+    folded accumulator spans the window's full width W."""
+    if not folds_taps(g.tw, g.stride, g.kh, g.kw):
+        return [pltpu.VMEM((g.th * g.tw, g.kb), acc_dtype)]
+    wide = g.in_tw if g.n_tw > 1 else g.wp
+    return [pltpu.VMEM((g.th * wide, g.kb), acc_dtype),
+            pltpu.VMEM(((g.th - 1) * wide + g.tw, g.kh * g.kw * g.cb), dtype)]
+
+
+def _conv_kernel(x_ref, w_ref, b_ref, s_ref, o_ref, acc_ref, patch_ref=None,
+                 *, th: int, tw: int, stride: int, cin_banks: int,
+                 relu: bool, pool: bool, requant: bool, acc_dtype,
+                 dilation: int = 1):
+    co = pl.program_id(4)
 
     # M5: bias preload — initialize the accumulator with the bias on the
     # first channel bank, exactly like preloading the output BRAMs.
@@ -266,39 +358,14 @@ def _conv_kernel(x_ref, w_ref, b_ref, s_ref, o_ref, acc_ref, *, kh: int,
         acc_ref[...] = jnp.broadcast_to(
             b_ref[...].astype(acc_dtype), acc_ref.shape)
 
-    acc = acc_ref[...]                                 # [TH, TW, KB]
-    x = x_ref[0]                                       # [in_th, in_tw, CB]
-    # KH×KW shifted matmuls — the 9-MAC adder tree on the MXU; stride-s
-    # output pixels read every s-th input row/column of the shifted slab;
-    # a dilated kernel's taps sit dilation pixels apart
-    for dy in range(kh):
-        for dx in range(kw):
-            xs = jax.lax.slice(
-                x, (dy * dilation, dx * dilation, 0),
-                (dy * dilation + (th - 1) * stride + 1,
-                 dx * dilation + (tw - 1) * stride + 1, cb),
-                (stride, stride, 1)).reshape(th * tw, cb)
-            wk = w_ref[dy, dx]                         # [CB, KB]
-            acc = acc + jnp.dot(
-                xs, wk, preferred_element_type=acc_dtype
-            ).reshape(th, tw, kb)
-    acc_ref[...] = acc
+    conv_slab(x_ref[0], w_ref[...], acc_ref, patch_ref, th=th, tw=tw,
+              stride=stride, dilation=dilation, acc_dtype=acc_dtype)
 
-    # Fused epilogue on the last cin step: the FPGA post-processes the
-    # output BRAMs (activation, pooling, requantization) before writeback.
-    # Tile-local: pool-aligned tiles guarantee no 2×2 window straddles a
-    # tile edge, so per-tile pooling == whole-map pooling.
     @pl.when(co == cin_banks - 1)
     def _epilogue():
-        y = acc_ref[...]
-        if relu:
-            y = jnp.maximum(y, 0)
-        if pool:
-            y = jnp.max(y.reshape(th // 2, 2, tw // 2, 2, kb), axis=(1, 3))
-        if requant:
-            y = jnp.clip(jnp.round(y.astype(jnp.float32) * s_ref[...]),
-                         -128, 127)
-        o_ref[0] = y.astype(o_ref.dtype)
+        o_ref[0] = conv_epilogue(
+            acc_ref, s_ref, th=th, tw=tw, relu=relu, pool=pool,
+            requant=requant).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -374,7 +441,7 @@ def conv2d_ws(x, w, bias=None, out_scale=None, *, stride: int = 1,
     # (ko // bpg) · cin_banks — the cin sweep (co) walks only that slice.
     # Dense convs have bpg == kout_banks, so the offset is always 0.
     kernel = functools.partial(
-        _conv_kernel, kh=kh, kw=kw, stride=stride, cin_banks=cin_banks,
+        _conv_kernel, th=th, tw=tw, stride=stride, cin_banks=cin_banks,
         relu=relu, pool=pool, requant=requant, acc_dtype=acc_dtype,
         dilation=dilation)
     out = pl.pallas_call(
@@ -392,7 +459,7 @@ def conv2d_ws(x, w, bias=None, out_scale=None, *, stride: int = 1,
                                lambda b, ty, tx, ko, co: (b, ty, tx, ko)),
         out_shape=jax.ShapeDtypeStruct(
             (n, n_th * pth, n_tw * ptw, k), out_dtype),
-        scratch_shapes=[pltpu.VMEM((th, tw, kb), acc_dtype)],
+        scratch_shapes=conv_scratch(g, x.dtype, acc_dtype),
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,

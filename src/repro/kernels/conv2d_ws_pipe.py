@@ -27,21 +27,23 @@ Logical iteration space is IDENTICAL to ``conv2d_ws`` — the
 (N, h_tiles, w_tiles, kout, cin) sweep with co innermost — except the cin
 sweep runs as an in-kernel ``fori_loop`` instead of a grid dimension (the
 accumulator lives in the same VMEM scratch either way).  The compute body
-performs the same KH·KW shifted MXU matmuls on the same operand blocks in
-the same order, so results are **bit-exact** against ``conv2d_ws`` on both
-the int32 and the f32 accumulator paths (asserted across the full
-stride × padding × epilogue × groups × tiling space in
+is ``conv2d_ws``'s own (``conv_slab``: the KH·KW taps folded into one
+contraction per cin slab where the tile width is off the sublane tile,
+one dot per tap elsewhere; then ``conv_epilogue``), run on the same
+operand blocks in the same order, so results are **bit-exact** against
+``conv2d_ws`` on both the int32 and the f32 accumulator paths (asserted
+across the full stride × padding × epilogue × groups × tiling space in
 tests/test_pipeline_kernel.py).
 
 The input map is laid out for the DMA windows by ``setup_conv``
 (``manual_dma=True``: width to a multiple of 8, a single channel bank to
 a multiple of 128 channels, zero-filled).
 
-VMEM working set: 2·input + 2·weight + 2·output blocks plus the
-accumulator scratch — the laid-out bytes ``banking.TilePlan.
-working_set_bytes`` already budgets (the implicit pipeline double-buffers
-the same blocks), so any plan that fits the sequential kernel fits this
-one.  ``banking.plan_tiles(kernel="auto")`` consults
+VMEM working set: 2·input + 2·weight + 2·output blocks plus the compute
+body's scratch (accumulator, and the tap patch where the taps fold) — the
+laid-out bytes ``banking.TilePlan.working_set_bytes`` already budgets (the
+implicit pipeline double-buffers the same blocks), so any plan that fits
+the sequential kernel fits this one.  ``banking.plan_tiles(kernel="auto")`` consults
 ``perfmodel.pipeline_estimate`` to choose per layer; the backend
 dispatches on ``TilePlan.pipelined``.
 
@@ -60,7 +62,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.conv2d_ws import setup_conv
+from repro.kernels.conv2d_ws import (conv_epilogue, conv_scratch, conv_slab,
+                                     setup_conv)
 from repro.kernels.ref import VMEM_LIMIT_BYTES
 
 
@@ -73,11 +76,11 @@ def _window(ref, dim: int, start, size: int):
     return slice(None) if size == ref.shape[dim] else pl.ds(start, size)
 
 
-def _pipe_kernel(x_hbm, w_hbm, b_ref, s_ref, o_ref, xb, wb, acc_ref,
-                 in_sem, w_sem, *, kh: int, kw: int, stride: int,
-                 cin_banks: int, kout_banks: int, th: int, tw: int,
-                 cb: int, kb: int, cgrp: int, bpg: int, relu: bool,
-                 pool: bool, requant: bool, acc_dtype, dilation: int = 1):
+def _pipe_kernel(x_hbm, w_hbm, b_ref, s_ref, o_ref, xb, wb, in_sem, w_sem,
+                 acc_ref, patch_ref=None, *, stride: int, cin_banks: int,
+                 kout_banks: int, th: int, tw: int, cb: int, kb: int,
+                 cgrp: int, bpg: int, relu: bool, pool: bool, requant: bool,
+                 acc_dtype, dilation: int = 1):
     b, ty, tx, ko = (pl.program_id(i) for i in range(4))
     n_th, n_tw = pl.num_programs(1), pl.num_programs(2)
     n_steps = pl.num_programs(0) * n_th * n_tw * kout_banks
@@ -140,38 +143,19 @@ def _pipe_kernel(x_hbm, w_hbm, b_ref, s_ref, o_ref, xb, wb, acc_ref,
             for dma in slab_copies(nb, nty, ntx, nko, nco, 1 - slot):
                 dma.start()
 
-        acc = acc_ref[...]                          # [TH, TW, KB]
-        x = xb[slot]                                # [in_th, in_tw, CB]
-        # KH×KW shifted matmuls — identical operand blocks, identical
-        # order to conv2d_ws's grid step, hence bit-exact accumulation
-        # (dilated taps sit dilation pixels apart, exactly as there)
-        for dy in range(kh):
-            for dx in range(kw):
-                xs = jax.lax.slice(
-                    x, (dy * dilation, dx * dilation, 0),
-                    (dy * dilation + (th - 1) * stride + 1,
-                     dx * dilation + (tw - 1) * stride + 1, cb),
-                    (stride, stride, 1)).reshape(th * tw, cb)
-                wk = wb[slot, dy, dx]               # [CB, KB]
-                acc = acc + jnp.dot(
-                    xs, wk, preferred_element_type=acc_dtype
-                ).reshape(th, tw, kb)
-        acc_ref[...] = acc
+        # conv2d_ws's compute body on the same operand blocks in the same
+        # order, hence bit-exact accumulation
+        conv_slab(xb[slot], wb[slot], acc_ref, patch_ref, th=th, tw=tw,
+                  stride=stride, dilation=dilation, acc_dtype=acc_dtype)
         return 0
 
     jax.lax.fori_loop(0, cin_banks, cin_step, 0)
 
     # Fused epilogue into the output block; Pallas stores it to HBM while
     # the next grid step computes.
-    y = acc_ref[...]
-    if relu:
-        y = jnp.maximum(y, 0)
-    if pool:
-        y = jnp.max(y.reshape(th // 2, 2, tw // 2, 2, kb), axis=(1, 3))
-    if requant:
-        y = jnp.clip(jnp.round(y.astype(jnp.float32) * s_ref[...]),
-                     -128, 127)
-    o_ref[0] = y.astype(o_ref.dtype)
+    o_ref[0] = conv_epilogue(
+        acc_ref, s_ref, th=th, tw=tw, relu=relu, pool=pool,
+        requant=requant).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -206,7 +190,7 @@ def conv2d_ws_pipe(x, w, bias=None, out_scale=None, *, stride: int = 1,
     scale = jnp.pad(scale, (0, g.k - k)).reshape(1, g.k)
 
     kernel = functools.partial(
-        _pipe_kernel, kh=g.kh, kw=g.kw, stride=g.stride,
+        _pipe_kernel, stride=g.stride,
         cin_banks=g.cin_banks, kout_banks=g.kout_banks, th=g.th, tw=g.tw,
         cb=g.cb, kb=g.kb, cgrp=g.cgrp, bpg=g.bpg,
         relu=relu, pool=pool, requant=g.requant, acc_dtype=acc_dtype,
@@ -230,10 +214,9 @@ def conv2d_ws_pipe(x, w, bias=None, out_scale=None, *, stride: int = 1,
             pltpu.VMEM((2, g.in_th if g.n_th > 1 else g.hp,     # ping-pong in
                         g.in_tw if g.n_tw > 1 else g.wp, g.cb), x.dtype),
             pltpu.VMEM((2, g.kh, g.kw, g.cb, g.kb), w.dtype),   # ping-pong w
-            pltpu.VMEM((g.th, g.tw, g.kb), acc_dtype),          # accumulator
             pltpu.SemaphoreType.DMA((2,)),                      # input slabs
             pltpu.SemaphoreType.DMA((2,)),                      # weight slabs
-        ],
+        ] + conv_scratch(g, x.dtype, acc_dtype),                # acc, patch
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,

@@ -75,6 +75,21 @@ LANES = 128     # TPU vreg lane width: the minor dim of every VMEM tile
 VMEM_LIMIT_BYTES = 16 * 1024 * 1024
 
 
+ACC_SUBLANES = 8    # sublane tile of a 32-bit value (int32 / f32 accumulator)
+
+
+def folds_taps(tw: int, stride: int, kh: int, kw: int) -> bool:
+    """Whether the conv kernels' compute body (``conv2d_ws.conv_slab``)
+    folds the KH·KW taps into one contraction over the flattened input
+    window: for a stride-1 kernel of several taps whose tile width is off
+    the 32-bit sublane tile.  There a per-tap dot's (TH·TW, KB) result
+    changes layout on its way into the accumulator; at aligned widths the
+    per-tap dots are cheaper than copying the taps into a patch (both
+    measured on a v5e, PERF.md).  The one rule the kernels and the VMEM
+    planner share."""
+    return stride == 1 and kh * kw > 1 and tw % ACC_SUBLANES != 0
+
+
 def lane_legal_banks(dim: int, banks: int) -> bool:
     """Whether splitting ``dim`` channels into ``banks`` blocks gives a
     channel block Mosaic accepts as the minor (lane) dimension: the whole

@@ -11,6 +11,10 @@ the tile plans the int8 vgg_imagenet program runs on the chip:
   ``program_tile_plans`` emits for vgg_imagenet's first two layers at
   batch 8 — plans that must be spatially tiled once VMEM is counted as
   laid out;
+* both conv kernels under VGG-16's conv4_2 plan (28×28×512 → 512, banks
+  4×4) and conv5_3 plan (14×14×512 → 512 with the fused pool), where
+  the map width is off the sublane tile: the planner's VMEM count must
+  cover the scoped allocation Mosaic reports for the kernel;
 * ``matmul_ws`` for the 8×256 @ 256×1000 int8 classifier head.
 
 The topology is described inside a module-scoped fixture, never while a
@@ -19,14 +23,17 @@ and every test worker imports this file.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.core import network
+from repro.core import banking, network
 from repro.core.convcore import ConvCoreConfig
+from repro.kernels import conv2d_ws as seq_mod
+from repro.kernels import conv2d_ws_pipe as pipe_mod
 from repro.kernels.conv2d_ws import conv2d_ws
 from repro.kernels.conv2d_ws_pipe import conv2d_ws_pipe
 from repro.kernels.matmul_ws import matmul_ws
@@ -87,6 +94,42 @@ def test_vgg_imagenet_tiled_layer_plans(one_chip, layer, kernel):
                       pool=sp.pool, interpret=False)
     compiled = _compile(f, one_chip, *_conv_shapes(h, w, c, sp.features))
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def _scoped_vmem_bytes(monkeypatch, mod, kernel, sharding, shapes, **kw):
+    """The scoped VMEM Mosaic allocates for ``kernel``: compiled under a
+    4 KiB limit, the compiler refuses it and names the size it needed."""
+    monkeypatch.setattr(mod, "VMEM_LIMIT_BYTES", 4096)
+
+    def f(x, wt, b, s):                # unjitted, so the limit is traced
+        return kernel.__wrapped__(x, wt, b, s, interpret=False, **kw)
+    with pytest.raises(Exception, match="Scoped allocation") as err:
+        _compile(f, sharding, *shapes)
+    size, unit = re.search(r"Scoped allocation with size ([\d.]+)([KM]?)",
+                           str(err.value)).groups()
+    return float(size) * {"": 1, "K": 2**10, "M": 2**20}[unit]
+
+
+@pytest.mark.parametrize("kernel,mod", [(conv2d_ws, seq_mod),
+                                        (conv2d_ws_pipe, pipe_mod)],
+                         ids=["conv2d_ws", "conv2d_ws_pipe"])
+@pytest.mark.parametrize("hw,pool", [(28, False), (14, True)],
+                         ids=["conv4_2", "conv5_3"])
+def test_vgg16_deep_layer_plans_fit_mosaic(one_chip, monkeypatch, kernel,
+                                           mod, hw, pool):
+    tp = banking.plan_tiles(hw, hw, 512, 512, 3, 3, padding="SAME",
+                            pool=pool, in_bytes=1, out_bytes=1)
+    assert (tp.cin_banks, tp.kout_banks, tp.tiled) == (4, 4, False), tp
+    kw = dict(padding="SAME", cin_banks=4, kout_banks=4, relu=True,
+              pool=pool)
+    shapes = _conv_shapes(hw, hw, 512, 512)
+
+    def f(x, wt, b, s):
+        return kernel(x, wt, b, s, interpret=False, **kw)
+    assert "tpu_custom_call" in _compile(f, one_chip, *shapes).as_text()
+    scoped = _scoped_vmem_bytes(monkeypatch, mod, kernel, one_chip, shapes,
+                                **kw)
+    assert tp.working_set_bytes >= scoped, (tp.working_set_bytes, scoped)
 
 
 def test_matmul_classifier_head(one_chip):

@@ -148,18 +148,30 @@ def test_pool_floor_semantics_odd_output():
     np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-4)
 
 
+@pytest.mark.parametrize("h,w,kh,stride,dilation,cin_banks", [
+    pytest.param(12, 12, 3, 2, 1, 1, id="stride2"),
+    pytest.param(9, 7, 3, 1, 1, 2, id="w7"),
+    pytest.param(14, 14, 3, 1, 1, 4, id="w14-4banks"),
+    pytest.param(12, 14, 2, 1, 1, 2, id="2x2"),
+    pytest.param(10, 14, 1, 1, 1, 2, id="1x1"),
+    pytest.param(14, 14, 3, 1, 2, 2, id="dilation2"),
+])
 @pytest.mark.parametrize("per_channel", [False, True])
-def test_int8_fused_epilogue_exact(per_channel):
+def test_int8_fused_epilogue_exact(per_channel, h, w, kh, stride, dilation,
+                                   cin_banks):
     """The production path: int8 in, fused ReLU→pool→requantize, int8 out —
-    bit-exact vs the int32 oracle chain."""
-    x, wgt = _i8(1, 12, 12, 8), _i8(3, 3, 8, 8)
+    bit-exact vs the int32 oracle chain, with the taps folded into one
+    contraction at widths off the sublane tile, for 1, 4 and 9 taps,
+    dilated, over several cin banks."""
+    x, wgt = _i8(1, h, w, 8), _i8(kh, kh, 8, 8)
     b = jnp.asarray(RNG.integers(-500, 500, size=(8,)), jnp.int32)
     scale = (jnp.asarray(RNG.uniform(5e-4, 2e-3, size=(8,)), jnp.float32)
              if per_channel else jnp.float32(1e-3))
-    got = ops.conv2d(x, wgt, b, stride=2, padding="SAME", relu=True,
-                     pool=True, out_scale=scale)
-    want = ref.conv2d_epilogue_ref(x, wgt, b, stride=2, padding="SAME",
-                                   relu=True, pool=True, out_scale=scale)
+    kw = dict(stride=stride, padding="SAME", relu=True, pool=True,
+              dilation=dilation)
+    got = conv2d_ws(x, wgt, b, scale, cin_banks=cin_banks, kout_banks=2,
+                    interpret=True, **kw)
+    want = ref.conv2d_epilogue_ref(x, wgt, b, out_scale=scale, **kw)
     assert got.dtype == jnp.int8
     np.testing.assert_array_equal(got, want)
 

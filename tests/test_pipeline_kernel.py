@@ -80,24 +80,48 @@ def test_pipe_bit_exact_grouped(groups):
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
-def test_pipe_bit_exact_fused_epilogue_requant():
-    """ReLU → 2×2 max-pool → requantize, tiled: the epilogue runs on the
-    ping-pong output buffer and its store overlaps the next tile."""
-    x, w = _i8(2, 16, 16, 8), _i8(3, 3, 8, 16)
+# Shapes of the tap-folded compute body (``conv2d_ws.conv_slab``):
+# (H, W, KH=KW, dilation, cin_banks, (h_tile, w_tile)); 0 = whole map.
+FOLD_CASES = [
+    pytest.param(9, 7, 3, 1, 2, (0, 0), id="w7"),
+    pytest.param(14, 14, 3, 1, 4, (0, 0), id="w14-4banks"),
+    pytest.param(12, 14, 2, 1, 2, (6, 0), id="2x2-tiled"),
+    pytest.param(10, 14, 1, 1, 2, (0, 0), id="1x1"),
+    pytest.param(14, 14, 3, 2, 2, (0, 0), id="dilation2"),
+]
+
+
+@pytest.mark.parametrize("h,w,kh,dilation,cin_banks,tile", [
+    pytest.param(16, 16, 3, 1, 2, (8, 8), id="tiled8x8")] + FOLD_CASES)
+def test_pipe_bit_exact_fused_epilogue_requant(h, w, kh, dilation,
+                                               cin_banks, tile):
+    """ReLU → 2×2 max-pool → requantize, at widths off the sublane tile,
+    with 1, 4 and 9 taps, dilated and tiled: the epilogue runs on the
+    ping-pong output buffer and its store overlaps the next tile, and
+    both kernels equal the int8 reference chain bit for bit."""
+    x, wt = _i8(2, h, w, 8), _i8(kh, kh, 8, 16)
     b = jnp.asarray(RNG.integers(-500, 500, (16,)), jnp.int32)
-    out = _both(x, w, b, out_scale=0.015, stride=1, padding="SAME",
-                relu=True, pool=True, cin_banks=2, kout_banks=4,
-                h_tile=8, w_tile=8)
+    kw = dict(stride=1, padding="SAME", relu=True, pool=True,
+              dilation=dilation)
+    out = _both(x, wt, b, out_scale=0.015, cin_banks=cin_banks,
+                kout_banks=4, h_tile=tile[0], w_tile=tile[1], **kw)
     assert out.dtype == jnp.int8
+    want = ref.conv2d_epilogue_ref(x, wt, b, out_scale=0.015, **kw)
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(want))
 
 
-def test_pipe_bit_exact_float_accumulator():
+@pytest.mark.parametrize("h,w,kh,dilation,cin_banks,tile", [
+    pytest.param(13, 11, 3, 1, 2, (4, 8), id="tiled4x8")] + FOLD_CASES)
+def test_pipe_bit_exact_float_accumulator(h, w, kh, dilation, cin_banks,
+                                          tile):
     """The f32 accumulator path: bitwise equality requires the pipelined
-    kernel to accumulate in exactly the sequential order (co-major, then
-    the KH×KW taps) — allclose would hide a reordering."""
-    x, w, b = _f32(1, 13, 11, 8), _f32(3, 3, 8, 8), _f32(8)
-    _both(x, w, b, stride=1, padding="SAME", relu=True,
-          cin_banks=2, kout_banks=2, h_tile=4, w_tile=8)
+    kernel to accumulate in exactly the sequential order (co-major, each
+    slab's taps in one contraction) — allclose would hide a
+    reordering."""
+    x, wt, b = _f32(1, h, w, 8), _f32(kh, kh, 8, 8), _f32(8)
+    _both(x, wt, b, stride=1, padding="SAME", relu=True, dilation=dilation,
+          cin_banks=cin_banks, kout_banks=2, h_tile=tile[0],
+          w_tile=tile[1])
 
 
 def test_pipe_bit_exact_1x1_pointwise():
@@ -244,19 +268,30 @@ if HAVE_HYPOTHESIS:
         lay = banking.laid_out_bytes
         for p in plans.values():
             # explicit ping-pong buffers: 2 input + 2 weight + 2 output
-            # (+ bias/scale) slots, the single accumulator and the kernel
-            # body's values, each as laid out in VMEM — first principles,
-            # must equal the planner's promise
+            # (+ bias/scale) slots, then the compute body's scratch and
+            # values, each as laid out in VMEM — first principles, must
+            # equal the planner's promise.  A width off the 8-row sublane
+            # tile folds the taps: accumulator over the window's padded
+            # width, tap patch (nine 128-lane column blocks), window and
+            # its flattened copy, one tap's rows, two accumulator-sized
+            # values; otherwise per-tap dots: the accumulator, window,
+            # one tap slice and four accumulator-sized values.
             cgb, kgb = c // groups // p.cin_banks, k // p.kout_banks
             th, tw = p.h_tile, p.w_tile
             win = lay((p.in_h_tile if p.tiled else h + 2, w + 2, cgb), 1)
-            acc = lay((th * tw, kgb), 4)
             pth, ptw = (th // 2, tw // 2) if pool else (th, tw)
-            pingpong = (2 * (win + lay((3, 3, cgb, kgb), 1)
-                             + lay((pth, ptw, kgb), 1)
-                             + 2 * lay((1, kgb), 4))
-                        + lay((th, tw, kgb), 4)
-                        + win + lay((th * tw, cgb), 1) + 4 * acc)
+            pingpong = 2 * (win + lay((3, 3, cgb, kgb), 1)
+                            + lay((pth, ptw, kgb), 1)
+                            + 2 * lay((1, kgb), 4))
+            if tw % 8:
+                wide = -(-(w + 2) // 8) * 8
+                rows = (th - 1) * wide + tw
+                acc = lay((th * wide, kgb), 4)
+                pingpong += (acc + lay((rows, 9 * 128), 1) + 2 * win
+                             + lay((rows, cgb), 1) + 2 * acc)
+            else:
+                acc = lay((th * tw, kgb), 4)
+                pingpong += acc + win + lay((th * tw, cgb), 1) + 4 * acc
             assert p.working_set_bytes == pingpong
             assert p.fits_vmem == (pingpong <= budget)
             # legality under degradation, dense and grouped

@@ -193,18 +193,22 @@ def test_bankplan_counts_acc_and_output_separately():
 def test_tileplan_working_set_separates_acc():
     """The working set counts blocks as Mosaic lays them out (lanes to
     128, sublanes to the dtype tile): double-buffered input, weight,
-    int8 output, bias and scale blocks, the int32 accumulator once, and
-    the kernel body's values (input window, one tap slice, four
-    accumulator-sized values)."""
+    int8 output, bias and scale blocks, then the compute body's scratch
+    and values.  62 is off the 8-row sublane tile, so the body folds the
+    taps: the int32 accumulator over the window's 64-wide rows, the tap
+    patch (61·64 + 62 rows, nine taps of one 128-lane block each), the
+    window and its flattened copy, one tap's rows, and two
+    accumulator-sized values."""
     p = plan_tiles(64, 64, 8, 8, in_bytes=1, out_bytes=1, pool=False,
                    vmem_budget=None)
     lay = banking.laid_out_bytes
+    rows = 61 * 64 + 62
     assert p.working_set_bytes == (                  # VALID: 64 → 62
         2 * (lay((64, 64, 8), 1) + lay((3, 3, 8, 8), 1)
              + lay((62, 62, 8), 1) + 2 * lay((1, 8), 4))
-        + lay((62, 62, 8), 4)
-        + lay((64, 64, 8), 1) + lay((62 * 62, 8), 1)
-        + 4 * lay((62 * 62, 8), 4))
+        + lay((62 * 64, 8), 4) + lay((rows, 9 * 128), 1)
+        + 2 * lay((64, 64, 8), 1) + lay((rows, 8), 1)
+        + 2 * lay((62 * 64, 8), 4))
     assert lay((62, 62, 8), 1) == 62 * 64 * 128      # 32-row int8 tiles
     assert lay((62, 62, 8), 4) == 62 * 64 * 128 * 4  # 8-row int32 tiles
     assert p.acc_block_bytes == p.h_tile * p.w_tile * (8 // p.kout_banks) * 4
