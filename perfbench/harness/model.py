@@ -26,14 +26,17 @@ def seed_key(seed: int, stream: int):
 
 
 def build_plan(cfg: dict):
-    """The ``NetworkPlan`` that ``cfg["layers"]`` describes."""
+    """The ``NetworkPlan`` that ``cfg["layers"]`` describes: each layer's
+    ``kind`` names its ``repro.core.network`` constructor; a merge (``add``,
+    ``concat``) takes its branches from ``inputs``, any other node at most
+    one input from there."""
     from repro.core import network
     layers = []
     for sp in cfg["layers"]:
         args = {k: v for k, v in sp.items() if k not in ("kind", "inputs")}
         kind = sp["kind"]
-        if kind == "concat":
-            layers.append(network.concat(*sp["inputs"], **args))
+        if kind in ("add", "concat"):
+            layers.append(getattr(network, kind)(*sp["inputs"], **args))
             continue
         if sp.get("inputs"):
             (args["input"],) = sp["inputs"]

@@ -7,6 +7,8 @@ Nothing here imports the program under test.
 
 from __future__ import annotations
 
+from typing import Optional
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -36,6 +38,40 @@ def maxpool2(x):
     """2x2 max pool with stride 2; an odd trailing row or column is dropped."""
     return jax.lax.reduce_window(x, -jnp.inf, jax.lax.max, (1, 2, 2, 1),
                                  (1, 2, 2, 1), "VALID")
+
+
+def conv(x, w, b, stride: int = 1, padding: str = "SAME", dilation: int = 1,
+         groups: int = 1):
+    """Convolution with ``stride``, SAME or VALID zero ``padding``, taps
+    ``dilation`` apart and ``groups`` channel groups (``w`` is
+    [KH, KW, C/groups, K]; SAME pads as TensorFlow does, the extra row or
+    column at the bottom or right)."""
+    y = jax.lax.conv_general_dilated(
+        x, w, window_strides=(stride, stride), padding=padding,
+        rhs_dilation=(dilation, dilation),
+        dimension_numbers=("NHWC", "HWIO", "NHWC"),
+        feature_group_count=groups, precision=HIGHEST)
+    return y + b
+
+
+def depthwise(x, w, b, stride: int = 1, padding: str = "SAME"):
+    """Depthwise convolution: each channel of ``x`` filtered by its own
+    kernel, ``w`` [KH, KW, 1, C]."""
+    return conv(x, w, b, stride, padding, groups=x.shape[-1])
+
+
+def maxpool(x, size: int, stride: Optional[int] = None,
+            padding: str = "VALID"):
+    """``size`` x ``size`` max pool with ``stride`` (None: ``size``); SAME
+    pads with -inf, so a padded position never wins."""
+    s = stride or size
+    return jax.lax.reduce_window(x, -jnp.inf, jax.lax.max,
+                                 (1, size, size, 1), (1, s, s, 1), padding)
+
+
+def global_mean(x):
+    """Mean over the map, [N, H, W, C] -> [N, C]."""
+    return jnp.mean(x, axis=(1, 2))
 
 
 def dense(x, w, b):

@@ -65,3 +65,13 @@ def gen_late_p95_ms(run) -> Optional[float]:
     if run.mix["loop"] != "open" or rec.attempted == 0:
         return None
     return traffic.percentile((rec.sent_ns - rec.due_ns) / 1e6, 95.0)
+
+
+def latency_p95_ms(run) -> Optional[float]:
+    """95th percentile of the open loop's latency, due to answered, over
+    every request of the window (a failed one waited on to the grace)."""
+    rec = run.record
+    if run.mix["loop"] != "open" or rec.attempted == 0:
+        return None
+    return traffic.percentile(
+        traffic.latencies_ms(rec, float(run.mix["grace_s"])), 95.0)

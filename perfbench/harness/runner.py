@@ -146,6 +146,9 @@ def measure(st, seed: int, seconds: float, trace: bool) -> traffic.Record:
          f"{len(rec.answers)} answers kept for the check; host peak RSS "
          f"{resource.getrusage(resource.RUSAGE_SELF).ru_maxrss / 2**20:.2f}"
          " GiB")
+    if st.mix["loop"] == "open":
+        grace = float(st.mix["grace_s"])
+        _log(f"open loop: {traffic.open_summary(rec, grace)}")
     return rec
 
 

@@ -244,6 +244,30 @@ def percentile(values, p: float) -> float:
     return float(xs[lo] + (xs[hi] - xs[lo]) * (x - lo))
 
 
+def open_summary(rec: Record, grace_s: float) -> dict:
+    """Where an open-loop window lost its time, for the run log: the
+    latency tail, how late the generator sent, the engine's queue depth,
+    the p95 of each fifth of the window by due time, and the longest gaps
+    between two answers (a host or device stall shows as one)."""
+    lat = latencies_ms(rec, grace_s)
+    late = (rec.sent_ns - rec.due_ns) / 1e6
+    fifth = np.minimum((rec.due_ns - rec.t0_ns) * 5
+                       // max(rec.t1_ns - rec.t0_ns, 1), 4)
+    done = np.sort(rec.done_ns[rec.done_ns > 0])
+    gaps = np.sort(np.diff(done))[::-1] / 1e6 if done.size > 1 else []
+    return {
+        "latency_ms_p50_p95_p99_max": [
+            round(percentile(lat, p), 3) for p in (50, 95, 99, 100)],
+        "over_100_ms": int(np.sum(lat > 100.0)),
+        "late_ms_p95_max": [round(percentile(late, p), 3) for p in (95, 100)],
+        "queue_depth_1s_end": [rec.queue_depth_start, rec.queue_depth_end],
+        "p95_ms_by_fifth": [round(percentile(lat[fifth == k], 95), 3)
+                            for k in range(5) if np.any(fifth == k)],
+        "answer_gaps_ms_top5": [round(float(g), 3) for g in gaps[:5]],
+        "answer_gaps_over_30_ms": int(np.sum(np.asarray(gaps) > 30.0)),
+    }
+
+
 def latencies_ms(rec: Record, grace_s: float) -> np.ndarray:
     """Per request, due to answered in ms; a failed request counts as
     waited on until the grace period ran out."""

@@ -87,3 +87,22 @@ def test_failed_and_unanswered_requests_count_as_failed():
     np.testing.assert_allclose(
         lat[bad], (rec.t1_ns + grace * 1e9 - rec.due_ns[bad]) / 1e6)
     assert (lat[~bad] < grace * 1e3).all()
+
+
+def test_open_loop_tail_reader_and_summary():
+    import types
+
+    from perfbench.harness import readers
+    grace = 0.5
+    mix = {"loop": "open", "rate_per_s": 500.0, "grace_s": grace}
+    rec = _run(StandIn(batch=4, lose={5}), mix)
+    lat = traffic.latencies_ms(rec, grace)
+    p95 = readers.latency_p95_ms(types.SimpleNamespace(record=rec, mix=mix))
+    assert p95 == traffic.percentile(lat, 95.0)
+    s = traffic.open_summary(rec, grace)
+    assert s["latency_ms_p50_p95_p99_max"][1] == round(p95, 3)
+    assert s["latency_ms_p50_p95_p99_max"][3] == round(float(lat.max()), 3)
+    assert s["over_100_ms"] == int((lat > 100.0).sum()) > 0
+    assert len(s["p95_ms_by_fifth"]) == 5
+    closed = types.SimpleNamespace(record=rec, mix={"loop": "closed"})
+    assert readers.latency_p95_ms(closed) is None
