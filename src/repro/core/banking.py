@@ -41,7 +41,8 @@ way).  core/perfmodel.tile_traffic prices that re-read.
 Stride / padding awareness: the image window lives in the *padded* map
 (the FPGA writes zero margins into the image BRAMs) and the accumulator
 block is the *strided* conv output, so plans stay correct for SAME /
-stride-2 / pooled layers.
+stride-2 / pooled layers; a strided layer's VMEM is counted on the
+space-to-depth form the kernels run (``tile_plan``).
 """
 
 from __future__ import annotations
@@ -52,7 +53,7 @@ from typing import Optional
 from repro.kernels.ref import (LANES, VMEM_LIMIT_BYTES, check_groups,
                                conv_out_shape, dilated_extent, folds_taps,
                                grouped_banks, halo_window, lane_legal_banks,
-                               normalize_padding)
+                               normalize_padding, phase_split)
 from repro.kernels.ref import divisor_banks as _ref_divisor_banks
 
 VMEM_BYTES_V5E = 128 * 1024 * 1024   # legacy generous budget (BankPlan)
@@ -209,8 +210,10 @@ def _round_up(n: int, m: int) -> int:
 
 def conv_vmem_bytes(in_h: int, in_w: int, cb: int, kh: int, kw: int,
                     kb: int, th: int, tw: int, pool: bool, in_bytes: int,
-                    out_bytes: int, acc_bytes: int, stride: int = 1) -> int:
-    """Laid-out VMEM working set of one conv grid step.
+                    out_bytes: int, acc_bytes: int) -> int:
+    """Laid-out VMEM working set of one conv grid step, in the stride-1
+    geometry the kernels run (a strided layer's space-to-depth form,
+    ``tile_plan``).
 
     Blocks: two buffers each of the (in_h × in_w × cb) input window, the
     (kh × kw × cb × kb) weight bank, the epilogue output block and the
@@ -240,7 +243,7 @@ def conv_vmem_bytes(in_h: int, in_w: int, cb: int, kh: int, kw: int,
     blocks = 2 * (window + laid_out_bytes((kh, kw, cb, kb), in_bytes)
                   + laid_out_bytes((pth, ptw, kb), out_bytes)
                   + 2 * laid_out_bytes((1, kb), 4))
-    if not folds_taps(tw, stride, kh, kw):
+    if not folds_taps(tw, kh, kw):
         acc = laid_out_bytes((th * tw, kb), acc_bytes)
         body = window + laid_out_bytes((th * tw, cb), in_bytes) + 4 * acc
         return blocks + acc + body
@@ -261,7 +264,13 @@ def tile_plan(h: int, w: int, c: int, k: int, kh: int, kw: int, th: int,
     """The TilePlan of one (h_tile, w_tile, cin_banks, kout_banks) state —
     the single geometry builder ``plan_tiles`` and the autotuner share.
     An untiled spatial dim is one full-extent block of the padded map (as
-    conv2d_ws's BlockSpec has it), a tiled one the halo'd window."""
+    conv2d_ws's BlockSpec has it), a tiled one the halo'd window.
+
+    The block and halo fields describe the layer as written; the VMEM
+    working set is counted on the stride-1 layer the kernels run: a
+    stride-s layer's space-to-depth form (``conv2d_ws.setup_conv``), with
+    ``ref.phase_split``'s taps per axis and its phases stacked along the
+    channels, over a map of OH+taps−1 rows."""
     out_bytes = acc_bytes if out_bytes is None else out_bytes
     (pt, pb), (pl_, pr) = normalize_padding(padding, kh, kw, stride, h, w,
                                             dilation)
@@ -272,8 +281,15 @@ def tile_plan(h: int, w: int, c: int, k: int, kh: int, kw: int, th: int,
     n_th, n_tw = -(-oh // th), -(-ow // tw)
     in_th = halo_window(th, stride, kh, dilation)
     in_tw = halo_window(tw, stride, kw, dilation)
-    blk_h = in_th if n_th > 1 else max(in_th, h + pt + pb)
-    blk_w = in_tw if n_tw > 1 else max(in_tw, w + pl_ + pr)
+    (qh, ph), (qw, pw) = (phase_split(kh, stride, dilation),
+                          phase_split(kw, stride, dilation))
+    if stride > 1:          # the space-to-depth map: rows OH + taps − 1
+        oh0, ow0 = conv_out_shape(h, w, kh, kw, stride, padding, dilation)
+        blk_h = th + qh - 1 if n_th > 1 else max(th, oh0) + qh - 1
+        blk_w = tw + qw - 1 if n_tw > 1 else max(tw, ow0) + qw - 1
+    else:
+        blk_h = in_th if n_th > 1 else max(in_th, h + pt + pb)
+        blk_w = in_tw if n_tw > 1 else max(in_tw, w + pl_ + pr)
     pth, ptw = (th // 2, tw // 2) if pool else (th, tw)
     return TilePlan(
         cin_banks=cin_banks, kout_banks=kout_banks, h_tile=th, w_tile=tw,
@@ -283,8 +299,8 @@ def tile_plan(h: int, w: int, c: int, k: int, kh: int, kw: int, th: int,
         acc_block_bytes=th * tw * kb * acc_bytes,
         output_block_bytes=pth * ptw * kb * out_bytes,
         working_set_bytes=conv_vmem_bytes(
-            blk_h, blk_w, cb, kh, kw, kb, th, tw, pool, in_bytes, out_bytes,
-            acc_bytes, stride),
+            blk_h, blk_w, ph * pw * cb, qh, qw, kb, th, tw, pool, in_bytes,
+            out_bytes, acc_bytes),
         stride=stride, out_h=oh, out_w=ow, pool=pool, in_bytes=in_bytes,
         budget=budget, groups=groups)
 

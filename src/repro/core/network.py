@@ -87,10 +87,11 @@ class LayerSpec:
     kind: "conv" | "conv_transpose" | "pool" | "avgpool" | "globalpool" |
     "flatten" | "dense" | "add" | "concat".  ``pool=True`` on a conv
     layer fuses the 2×2/2 max-pool into the kernel epilogue (one HBM
-    round-trip); standalone "pool" / "avgpool" layers are the unfused
-    fallbacks, and "globalpool" is the global average pool
-    ([N,H,W,C] → [N,C]) that lets classifier heads skip the flatten +
-    giant-dense pattern.
+    round-trip); standalone "pool" / "avgpool" layers are
+    ``size``×``size`` windows at ``stride`` under ``padding`` (their
+    constructors default to stride = size and VALID), and "globalpool" is
+    the global average pool ([N,H,W,C] → [N,C]) that lets classifier
+    heads skip the flatten + giant-dense pattern.
 
     ``dilation`` (conv kinds) spaces the kernel taps by inserting
     ``dilation−1`` zeros between them (rhs dilation — the dilated-context
@@ -117,11 +118,11 @@ class LayerSpec:
     kind: str
     features: int = 0                      # conv: K; dense: output dim
     kernel: Tuple[int, int] = (3, 3)
-    stride: int = 1
-    padding: ref.Padding = "SAME"
+    stride: int = 1                        # conv kinds and pools
+    padding: ref.Padding = "SAME"          # conv kinds and pools
     relu: bool = False
     pool: bool = False                     # conv only: fused 2×2 max-pool
-    size: int = 2                          # "pool"/"avgpool": window/stride
+    size: int = 2                          # "pool"/"avgpool": window
     groups: int = 1                        # conv only: 1=dense, −1=depthwise
     dilation: int = 1                      # conv kinds: kernel-tap spacing
     name: Optional[str] = None             # node label for skip references
@@ -194,14 +195,28 @@ def depthwise(kernel: int = 3, stride: int = 1,
                      groups=DEPTHWISE, name=name, inputs=_single(input))
 
 
-def maxpool(size: int = 2, name: Optional[str] = None,
+def maxpool(size: int = 2, stride: Optional[int] = None,
+            padding: ref.Padding = "VALID", name: Optional[str] = None,
             input: Optional[str] = None) -> LayerSpec:
-    return LayerSpec("pool", size=size, name=name, inputs=_single(input))
+    """Standalone max pool: ``size``×``size`` windows ``stride`` apart
+    (default: the window, so windows tile the map) under ``padding``.
+    SAME pads as ``ref.normalize_padding`` does (ResNet's 3×3/2 stem pool
+    takes 112² to 56²), with values that never win a window: −128 on the
+    int8 path, −inf on the float one."""
+    return LayerSpec("pool", size=size,
+                     stride=size if stride is None else stride,
+                     padding=padding, name=name, inputs=_single(input))
 
 
-def avgpool(size: int = 2, name: Optional[str] = None,
+def avgpool(size: int = 2, stride: Optional[int] = None,
+            padding: ref.Padding = "VALID", name: Optional[str] = None,
             input: Optional[str] = None) -> LayerSpec:
-    return LayerSpec("avgpool", size=size, name=name, inputs=_single(input))
+    """Standalone average pool, windows as ``maxpool``'s; a padded window
+    averages the map positions it covers, and the int8 path rounds the
+    mean back onto the input's grid (``ref.avgpool2d_ref``)."""
+    return LayerSpec("avgpool", size=size,
+                     stride=size if stride is None else stride,
+                     padding=padding, name=name, inputs=_single(input))
 
 
 def global_pool(name: Optional[str] = None,
@@ -357,8 +372,8 @@ class NetworkPlan:
                 elif sp.kind == "flatten":
                     shapes.append((h * w * c,))
                 else:
-                    shapes.append(((h - sp.size) // sp.size + 1,
-                                   (w - sp.size) // sp.size + 1, c))
+                    shapes.append((*ref.conv_out_shape(
+                        h, w, sp.size, sp.size, sp.stride, sp.padding), c))
             elif sp.kind == "dense":
                 if len(s0) != 1:
                     raise ValueError(f"node {names[i]!r}: dense before "
@@ -599,9 +614,9 @@ class NetworkPlan:
                     relu=sp.relu, pool=sp.pool, groups=g_,
                     dilation=sp.dilation)
             elif sp.kind == "pool":
-                h = ref.maxpool2d_ref(h, sp.size)
+                h = ref.maxpool2d_ref(h, sp.size, sp.stride, sp.padding)
             elif sp.kind == "avgpool":
-                h = ref.avgpool2d_ref(h, sp.size)
+                h = ref.avgpool2d_ref(h, sp.size, sp.stride, sp.padding)
             elif sp.kind == "globalpool":
                 h = ref.global_avgpool_ref(h)
             elif sp.kind == "flatten":
@@ -796,8 +811,12 @@ def int8_forward(qnet: QuantizedNetwork, x: jax.Array, *, backend,
     for i, (sp, w, b, rq, ms, tp) in enumerate(zip(
             plan.layers, qnet.weights, qnet.biases, qnet.requants,
             merges, tile_plans)):
+        src = list(ins[i])
+        if sp.kind == "dense" and src[0] >= 0 \
+                and plan.layers[src[0]].kind == "globalpool":
+            src = list(ins[src[0]])      # the map under the global pool
         with jax.named_scope(names[i]):
-            h = _int8_node(sp, [qin if j < 0 else acts[j] for j in ins[i]],
+            h = _int8_node(sp, [qin if j < 0 else acts[j] for j in src],
                            w, b, rq, ms, tp, geoms[i], backend)
         if rq is None and sp.kind in ("conv", "conv_transpose", "dense"):
             with jax.named_scope("output"):      # final layer: dequantize
@@ -822,15 +841,33 @@ def _int8_node(sp, src: List[jax.Array], w, b, rq, ms, tp, geom,
                relu=sp.relu, pool=sp.pool, out_scale=rq,
                plan=tp)
     elif sp.kind == "pool":
-        # max-pool commutes with the monotone int8 mapping
-        h = ref.maxpool2d_ref(h, sp.size)
+        # max-pool commutes with the monotone int8 mapping; padding is
+        # −128, which no window's maximum can be below
+        h = ref.maxpool2d_ref(h, sp.size, sp.stride, sp.padding)
     elif sp.kind == "avgpool":
         # window mean rounds back onto the same int8 grid
-        h = ref.avgpool2d_ref(h, sp.size)
+        h = ref.avgpool2d_ref(h, sp.size, sp.stride, sp.padding)
     elif sp.kind == "globalpool":
         h = ref.global_avgpool_ref(h)
     elif sp.kind == "flatten":
         h = h.reshape(h.shape[0], -1)
+    elif sp.kind == "dense" and h.ndim == 4:
+        # a dense layer over a global average pool (int8_forward hands it
+        # the pooled map): the mean is linear, so the layer runs on every
+        # position's int8 channels and averages its int32 accumulators.
+        # The pooled mean is then never rounded onto the map's int8 grid,
+        # whose step can exceed what sets one image's mean apart from
+        # another's (ResNet-50 at random weights, PERF.md)
+        n, hw = h.shape[0], h.shape[1] * h.shape[2]
+        rows = h.reshape(n * hw, h.shape[3])
+        # whole 128-row blocks for matmul_ws (N·H·W rows need not be)
+        rows = jnp.pad(rows, ((0, -(n * hw) % ref.LANES), (0, 0)))
+        acc = backend.matmul(rows, w, b)[:n * hw].astype(jnp.float32)
+        h = jnp.mean(acc.reshape(n, hw, -1), axis=1)
+        if sp.relu:
+            h = jnp.maximum(h, 0)
+        if rq is not None:
+            h = ref.requantize_ref(h, rq)
     elif sp.kind == "dense":
         h = backend.matmul(h, w, b)              # int32
         if sp.relu:

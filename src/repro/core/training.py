@@ -120,9 +120,9 @@ def float_forward(plan: NetworkPlan, params: Sequence[Optional[dict]],
             if qat and i != last_param:
                 h = fake_quant_act(h)
         elif sp.kind == "pool":
-            h = ref.maxpool2d_ref(h, sp.size)
+            h = ref.maxpool2d_ref(h, sp.size, sp.stride, sp.padding)
         elif sp.kind == "avgpool":
-            h = ref.avgpool2d_ref(h, sp.size)
+            h = ref.avgpool2d_ref(h, sp.size, sp.stride, sp.padding)
         elif sp.kind == "globalpool":
             h = ref.global_avgpool_ref(h)
         elif sp.kind == "flatten":

@@ -27,8 +27,9 @@ Mapping of the FPGA architecture (DESIGN.md §3):
   (rows × KH·KW·Cb) patch in VMEM, and one (KH·KW·Cb)-deep MXU matmul
   adds the cin slab into the accumulator.  Elsewhere each tap runs its
   own (HW×Cb)@(Cb×Kb) matmul on the shifted slice, the taps summed
-  before one accumulator update per slab; stride-s convolution reads
-  the shifted slices with stride s;
+  before one accumulator update per slab.  The body only ever sees a
+  stride-1 layer: ``setup_conv`` lays a stride-s layer out as a stride-1
+  conv over the s² space-to-depth phases of its padded map;
 * on the LAST cin step the fused epilogue runs in VMEM before writeback —
   ReLU → 2×2 max-pool → requantize(int8) — the FPGA "post-process in the
   output BRAMs before DMA-out" idiom, so a conv+relu+pool layer costs one
@@ -40,22 +41,22 @@ Mapping of the FPGA architecture (DESIGN.md §3):
 Tiling dataflow and halo math
 -----------------------------
 An output tile of ``h_tile × w_tile`` conv-output pixels at tile index
-(ty, tx) consumes the padded-input window starting at element
-``(ty·h_tile·s, tx·w_tile·s)`` with extent
+(ty, tx) consumes the (stride-1) input window starting at element
+``(ty·h_tile, tx·w_tile)`` with extent
 
-    in_tile = (tile − 1)·s + k        (per spatial dim, s = stride)
+    in_tile = tile + k − 1            (per spatial dim)
 
-so adjacent input windows overlap by a halo of ``k − s`` rows/columns
-(k − 1 for the stride-1 case) — re-read from HBM per tile, exactly like
-the FPGA re-DMAs the boundary rows of its image BRAM window.  The input
-BlockSpec of a tiled layer is element-indexed (``pl.Element``) because
-halo'd windows overlap: block strides (h_tile·s) differ from block
-extents (in_tile).  An untiled spatial dim spans the whole padded map, so
-a planner that tiles only H (banking.plan_tiles) keeps W, the sublane
-dim, at the array's own extent — the form Mosaic accepts.  The padded map
-is extended with extra zero rows/columns on the bottom/right so the LAST
-tile's window is always in bounds; the correspondingly padded output rows
-are sliced off after the call.
+so adjacent input windows overlap by a halo of ``k − 1`` rows/columns —
+re-read from HBM per tile, exactly like the FPGA re-DMAs the boundary
+rows of its image BRAM window.  The input BlockSpec of a tiled layer is
+element-indexed (``pl.Element``) because halo'd windows overlap: block
+strides (h_tile) differ from block extents (in_tile).  An untiled spatial
+dim spans the whole padded map, so a planner that tiles only H
+(banking.plan_tiles) keeps W, the sublane dim, at the array's own extent
+— the form Mosaic accepts.  The padded map is extended with extra zero
+rows/columns on the bottom/right so the LAST tile's window is always in
+bounds; the correspondingly padded output rows are sliced off after the
+call.
 
 The fused epilogue is tile-local: with ``pool=True`` tile sizes must be
 even (pool-aligned) so no 2×2 pool window straddles a tile edge — tile
@@ -90,7 +91,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.ref import (LANES, VMEM_LIMIT_BYTES, check_groups,
                                conv_out_shape, dilated_extent, folds_taps,
-                               halo_window, normalize_padding)
+                               halo_window, normalize_padding, phase_split)
 
 DMA_SUBLANES = 8    # second-minor alignment of an int8 map sliced by a DMA
 
@@ -106,7 +107,6 @@ class ConvGeom(NamedTuple):
     kh: int
     kw: int
     k: int
-    stride: int
     cin_banks: int
     kout_banks: int
     cb: int                   # channels per cin bank (within one group)
@@ -129,6 +129,7 @@ class ConvGeom(NamedTuple):
     int_path: bool
     requant: bool
     dilation: int = 1
+    strided: bool = False     # laid out from a stride > 1 layer
 
 
 def setup_conv(x, w, *, stride: int = 1, padding="VALID", groups: int = 1,
@@ -140,9 +141,19 @@ def setup_conv(x, w, *, stride: int = 1, padding="VALID", groups: int = 1,
     Returns ``(x_padded, w, geom)`` where ``x_padded`` carries the zero
     margins (padding + trailing-tile zero-extension — exact for the
     symmetric zero-point-0 int8 scheme) and ``geom`` is the resolved
-    :class:`ConvGeom`.  Raises exactly the errors the kernels contract
-    with the planner (banking invariant, group boundaries, sub-2×2
-    pooled outputs, pool-aligned tiles).
+    :class:`ConvGeom` of a stride-1 layer.  Raises exactly the errors the
+    kernels contract with the planner (banking invariant, group
+    boundaries, sub-2×2 pooled outputs, pool-aligned tiles).
+
+    A stride-s layer (s > 1) is laid out as the stride-1 conv it equals
+    (``space_to_depth``): its padded map becomes the phases that hold a
+    tap (``ref.phase_split``: s² of them, one for a 1×1 kernel) stacked
+    along the channels, its weights a ⌈e/s⌉×⌈e/s⌉ kernel over those
+    channels with zero taps where the kernel does not reach.  The
+    kernels, their compute body and the planner's VMEM count then see an
+    ordinary stride-1 layer with the same output, so no kernel slices
+    with a stride (Mosaic has no strided vector slice); ``geom.strided``
+    marks it for the kernel's name.
 
     Where a DMA cuts windows out of the map in HBM (spatial tiles, or
     ``manual_dma`` — conv2d_ws_pipe's own copies), Mosaic needs the map's
@@ -182,11 +193,20 @@ def setup_conv(x, w, *, stride: int = 1, padding="VALID", groups: int = 1,
             f"{dilated_extent(kh, dilation)}×{dilated_extent(kw, dilation)} "
             f"(kernel {kh}×{kw}, dilation={dilation}) exceeds the padded "
             f"input {h + pt + pb}×{w_dim + pl_ + pr}")
+    if pool and (oh < 2 or ow < 2):
+        # same error as banking.plan_tiles — planner and kernel agree
+        raise ValueError(
+            f"2×2 pool needs a ≥2×2 conv output, got {oh}×{ow}")
+    if stride > 1:
+        x, w = space_to_depth(x, w, stride=stride,
+                              pads=((pt, pb), (pl_, pr)), out=(oh, ow),
+                              groups=groups, dilation=dilation)
+        x, w, geom = setup_conv(
+            x, w, padding="VALID", groups=groups, cin_banks=cin_banks,
+            kout_banks=kout_banks, h_tile=h_tile, w_tile=w_tile, pool=pool,
+            requant=requant, manual_dma=manual_dma)
+        return x, w, geom._replace(strided=True)
     if pool:
-        if oh < 2 or ow < 2:
-            # same error as banking.plan_tiles — planner and kernel agree
-            raise ValueError(
-                f"2×2 pool needs a ≥2×2 conv output, got {oh}×{ow}")
         oh, ow = (oh // 2) * 2, (ow // 2) * 2     # floor semantics
     th = oh if h_tile in (0, None) else min(h_tile, oh)
     tw = ow if w_tile in (0, None) else min(w_tile, ow)
@@ -196,15 +216,15 @@ def setup_conv(x, w, *, stride: int = 1, padding="VALID", groups: int = 1,
             "tile edges", th, tw)
     n_th, n_tw = -(-oh // th), -(-ow // tw)
     tiled = (th, tw) != (oh, ow)
-    # halo'd input window per tile: (tile-1)·s + d·(k-1)+1, overlapping by
-    # the dilated kernel extent minus the stride
-    in_th = halo_window(th, stride, kh, dilation)
-    in_tw = halo_window(tw, stride, kw, dilation)
+    # halo'd input window per tile: tile + d·(k-1), overlapping by the
+    # dilated kernel extent minus one
+    in_th = halo_window(th, 1, kh, dilation)
+    in_tw = halo_window(tw, 1, kw, dilation)
     hp, wp = h + pt + pb, w_dim + pl_ + pr
     # extend the padded map so the LAST tile's window is in bounds; the
     # matching garbage output rows/cols are sliced off after the kernel
-    extra_h = max(0, (n_th - 1) * th * stride + in_th - hp)
-    extra_w = max(0, (n_tw - 1) * tw * stride + in_tw - wp)
+    extra_h = max(0, (n_th - 1) * th + in_th - hp)
+    extra_w = max(0, (n_tw - 1) * tw + in_tw - wp)
     extra_c = extra_k = 0
     if tiled or manual_dma:                    # DMA-aligned HBM layout
         extra_w += -(wp + extra_w) % DMA_SUBLANES
@@ -228,7 +248,7 @@ def setup_conv(x, w, *, stride: int = 1, padding="VALID", groups: int = 1,
     # per-bank blocks live inside ONE group: the cin sweep covers only the
     # C/groups channels a kout bank's kernel set reads (dense: the whole C)
     geom = ConvGeom(
-        n=n, kh=kh, kw=kw, k=k, stride=stride,
+        n=n, kh=kh, kw=kw, k=k,
         cin_banks=cin_banks, kout_banks=kout_banks,
         cb=cgrp // cin_banks, kb=k // kout_banks, cgrp=cgrp,
         bpg=kout_banks // groups,
@@ -239,6 +259,51 @@ def setup_conv(x, w, *, stride: int = 1, padding="VALID", groups: int = 1,
     return x, w, geom
 
 
+def space_to_depth(x, w, *, stride: int, pads, out, groups: int = 1,
+                   dilation: int = 1):
+    """A stride-``stride`` conv of ``x`` [N,H,W,C] by ``w`` [KH,KW,C/g,K]
+    under ``pads`` ((top, bottom), (left, right)) with output ``out`` =
+    (OH, OW), as a VALID stride-1 conv: returns its input
+    [N, OH+QH−1, OW+QW−1, g·PH·PW·C/g] and weights [QH, QW, PH·PW·C/g, K],
+    (QH, PH) and (QW, PW) the taps and phases of ``ref.phase_split``.
+
+    Channel (g, ry, rx, c) of input pixel (i, j) is padded-map pixel
+    (s·i + ry, s·j + rx) of channel c of group g, so each group's channels
+    stay one contiguous slice; weight tap (qy, qx) of channel (ry, rx, c)
+    is the kernel's tap (s·qy + ry, s·qx + rx) — a dilated kernel's taps
+    spread over its extent first — and zero past the kernel's edge.  The
+    padded map is cut or zero-extended to s·(OH+QH−1) rows and
+    s·(OW+QW−1) columns, which hold every row and column an output
+    reads.  Run in XLA, under the calling node's scope."""
+    n, h, wd, c = x.shape
+    kh, kw, cg, k = w.shape
+    (qh, ph), (qw, pw) = (phase_split(kh, stride, dilation),
+                          phase_split(kw, stride, dilation))
+    if dilation > 1:
+        w = jnp.zeros((dilated_extent(kh, dilation),
+                       dilated_extent(kw, dilation), cg, k), w.dtype
+                      ).at[::dilation, ::dilation].set(w)
+    hq, wq = out[0] + qh - 1, out[1] + qw - 1
+    (pt, _), (pl_, _) = pads
+    x = jnp.pad(x, ((0, 0), (pt, max(0, stride * hq - pt - h)),
+                    (pl_, max(0, stride * wq - pl_ - wd)), (0, 0)))
+    x = x[:, :stride * hq, :stride * wq].reshape(
+        n, hq, stride, wq, stride, groups, cg)[:, :, :ph, :, :pw]
+    x = x.transpose(0, 1, 3, 5, 2, 4, 6).reshape(n, hq, wq,
+                                                 groups * ph * pw * cg)
+    w = jnp.pad(w, ((0, stride * qh - w.shape[0]),
+                    (0, stride * qw - w.shape[1]), (0, 0), (0, 0)))
+    w = w.reshape(qh, stride, qw, stride, cg, k)[:, :ph, :, :pw]
+    return x, w.transpose(0, 2, 1, 3, 4, 5).reshape(qh, qw, ph * pw * cg, k)
+
+
+def kernel_name(base: str, g: ConvGeom):
+    """The ``pallas_call`` name of a layer pass: ``<base>_strided`` for a
+    layer laid out from a stride > 1 conv, so a profile keeps its time
+    apart; None (the wrapping function's name, ``<base>``) otherwise."""
+    return f"{base}_strided" if g.strided else None
+
+
 def halo_block_spec(g: ConvGeom, index_map) -> pl.BlockSpec:
     """BlockSpec of one grid step's halo'd input window.
 
@@ -246,12 +311,12 @@ def halo_block_spec(g: ConvGeom, index_map) -> pl.BlockSpec:
     indices.  An untiled layer reads one full-map block per channel bank.
     A tiled layer's spec is element-indexed (``pl.Element``) in every
     dim, as Mosaic requires once one dim is: a tiled spatial dim starts
-    at tile · tile_extent · stride and spans the halo'd extent, an
-    untiled one spans the whole padded map."""
+    at tile · tile_extent and spans the halo'd extent, an untiled one
+    spans the whole padded map."""
     def elem_map(*ids):
         b, ty, tx, c = index_map(*ids)
-        return (b, ty * g.th * g.stride if g.n_th > 1 else 0,
-                tx * g.tw * g.stride if g.n_tw > 1 else 0, c * g.cb)
+        return (b, ty * g.th if g.n_th > 1 else 0,
+                tx * g.tw if g.n_tw > 1 else 0, c * g.cb)
     if not g.tiled:
         return pl.BlockSpec((1, g.hp, g.wp, g.cb), index_map)
     return pl.BlockSpec(
@@ -260,7 +325,7 @@ def halo_block_spec(g: ConvGeom, index_map) -> pl.BlockSpec:
         elem_map)
 
 
-def conv_slab(x, w, acc_ref, patch_ref, *, th: int, tw: int, stride: int,
+def conv_slab(x, w, acc_ref, patch_ref, *, th: int, tw: int,
               dilation: int, acc_dtype):
     """Add one cin slab's convolution into the accumulator — the compute
     body both conv kernels share, so their results agree bit for bit.
@@ -279,9 +344,11 @@ def conv_slab(x, w, acc_ref, patch_ref, *, th: int, tw: int, stride: int,
       [M, KH·KW·CB] patch, and one MXU dot against the bank reshaped to
       [KH·KW·CB, KB] (a merge of leading dims) adds the slab into the
       [TH·W, KB] accumulator: no value changes layout;
-    * otherwise one dot per tap on its shifted slice (every stride-th row
-      and column), the dots summed and added into the [TH·TW, KB]
-      accumulator once per slab.  A 1×1 kernel is one tap."""
+    * otherwise one dot per tap on its shifted [TH, TW] slice, the dots
+      summed and added into the [TH·TW, KB] accumulator once per slab.  A
+      1×1 kernel is one tap.
+
+    Every layer reaches here at stride 1 (``setup_conv``)."""
     kh, kw, cb, kb = w.shape
     if patch_ref is not None:
         wide = x.shape[1]
@@ -302,9 +369,8 @@ def conv_slab(x, w, acc_ref, patch_ref, *, th: int, tw: int, stride: int,
         for dx in range(kw):
             xs = jax.lax.slice(
                 x, (dy * dilation, dx * dilation, 0),
-                (dy * dilation + (th - 1) * stride + 1,
-                 dx * dilation + (tw - 1) * stride + 1, cb),
-                (stride, stride, 1)).reshape(th * tw, cb)
+                (dy * dilation + th, dx * dilation + tw, cb)
+            ).reshape(th * tw, cb)
             d = jnp.dot(xs, w[dy, dx], preferred_element_type=acc_dtype)
             part = d if part is None else part + d
     acc_ref[...] += part
@@ -338,7 +404,7 @@ def conv_scratch(g: ConvGeom, dtype, acc_dtype) -> list:
     """VMEM scratch of the compute body (``conv_slab``): the 2-D
     accumulator and, where the body folds the taps, the tap patch; a
     folded accumulator spans the window's full width W."""
-    if not folds_taps(g.tw, g.stride, g.kh, g.kw):
+    if not folds_taps(g.tw, g.kh, g.kw):
         return [pltpu.VMEM((g.th * g.tw, g.kb), acc_dtype)]
     wide = g.in_tw if g.n_tw > 1 else g.wp
     return [pltpu.VMEM((g.th * wide, g.kb), acc_dtype),
@@ -346,7 +412,7 @@ def conv_scratch(g: ConvGeom, dtype, acc_dtype) -> list:
 
 
 def _conv_kernel(x_ref, w_ref, b_ref, s_ref, o_ref, acc_ref, patch_ref=None,
-                 *, th: int, tw: int, stride: int, cin_banks: int,
+                 *, th: int, tw: int, cin_banks: int,
                  relu: bool, pool: bool, requant: bool, acc_dtype,
                  dilation: int = 1):
     co = pl.program_id(4)
@@ -359,7 +425,7 @@ def _conv_kernel(x_ref, w_ref, b_ref, s_ref, o_ref, acc_ref, patch_ref=None,
             b_ref[...].astype(acc_dtype), acc_ref.shape)
 
     conv_slab(x_ref[0], w_ref[...], acc_ref, patch_ref, th=th, tw=tw,
-              stride=stride, dilation=dilation, acc_dtype=acc_dtype)
+              dilation=dilation, acc_dtype=acc_dtype)
 
     @pl.when(co == cin_banks - 1)
     def _epilogue():
@@ -382,7 +448,9 @@ def conv2d_ws(x, w, bias=None, out_scale=None, *, stride: int = 1,
     x: [N,H,W,C]; w: [KH,KW,C/groups,K]; bias: [K] or None → [N,OH,OW,K]
     (f32 accumulate for float inputs, int32 for int8 inputs).
 
-    stride / padding: any stride ≥ 1; "SAME" | "VALID" | int |
+    stride / padding: any stride ≥ 1 (a strided layer runs as the stride-1
+    conv over its space-to-depth phases, ``setup_conv``, under the kernel
+    name ``conv2d_ws_strided``); "SAME" | "VALID" | int |
     ((top,bottom),(left,right)).  Epilogue (applied in-VMEM on the last
     cin step, in this order): ``relu``, ``pool`` (2×2/2 max-pool, floor
     semantics), ``out_scale`` (requantize to int8; scalar or per-channel
@@ -441,11 +509,12 @@ def conv2d_ws(x, w, bias=None, out_scale=None, *, stride: int = 1,
     # (ko // bpg) · cin_banks — the cin sweep (co) walks only that slice.
     # Dense convs have bpg == kout_banks, so the offset is always 0.
     kernel = functools.partial(
-        _conv_kernel, th=th, tw=tw, stride=stride, cin_banks=cin_banks,
+        _conv_kernel, th=th, tw=tw, cin_banks=cin_banks,
         relu=relu, pool=pool, requant=requant, acc_dtype=acc_dtype,
-        dilation=dilation)
+        dilation=g.dilation)
     out = pl.pallas_call(
         kernel,
+        name=kernel_name("conv2d_ws", g),
         grid=(n, n_th, n_tw, kout_banks, cin_banks),
         in_specs=[
             halo_block_spec(g, lambda b, ty, tx, ko, co: (

@@ -63,7 +63,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.conv2d_ws import (conv_epilogue, conv_scratch, conv_slab,
-                                     setup_conv)
+                                     kernel_name, setup_conv)
 from repro.kernels.ref import VMEM_LIMIT_BYTES
 
 
@@ -77,7 +77,7 @@ def _window(ref, dim: int, start, size: int):
 
 
 def _pipe_kernel(x_hbm, w_hbm, b_ref, s_ref, o_ref, xb, wb, in_sem, w_sem,
-                 acc_ref, patch_ref=None, *, stride: int, cin_banks: int,
+                 acc_ref, patch_ref=None, *, cin_banks: int,
                  kout_banks: int, th: int, tw: int, cb: int, kb: int,
                  cgrp: int, bpg: int, relu: bool, pool: bool, requant: bool,
                  acc_dtype, dilation: int = 1):
@@ -103,8 +103,8 @@ def _pipe_kernel(x_hbm, w_hbm, b_ref, s_ref, o_ref, xb, wb, in_sem, w_sem,
         kernel's BlockSpec index maps."""
         coff = (sko // bpg) * cgrp + sco * cb
         in_dma = pltpu.make_async_copy(
-            x_hbm.at[sb, _window(x_hbm, 1, sty * th * stride, xb.shape[1]),
-                     _window(x_hbm, 2, stx * tw * stride, xb.shape[2]),
+            x_hbm.at[sb, _window(x_hbm, 1, sty * th, xb.shape[1]),
+                     _window(x_hbm, 2, stx * tw, xb.shape[2]),
                      _window(x_hbm, 3, coff, cb)],
             xb.at[slot], in_sem.at[slot])
         w_dma = pltpu.make_async_copy(
@@ -146,7 +146,7 @@ def _pipe_kernel(x_hbm, w_hbm, b_ref, s_ref, o_ref, xb, wb, in_sem, w_sem,
         # conv2d_ws's compute body on the same operand blocks in the same
         # order, hence bit-exact accumulation
         conv_slab(xb[slot], wb[slot], acc_ref, patch_ref, th=th, tw=tw,
-                  stride=stride, dilation=dilation, acc_dtype=acc_dtype)
+                  dilation=dilation, acc_dtype=acc_dtype)
         return 0
 
     jax.lax.fori_loop(0, cin_banks, cin_step, 0)
@@ -190,13 +190,13 @@ def conv2d_ws_pipe(x, w, bias=None, out_scale=None, *, stride: int = 1,
     scale = jnp.pad(scale, (0, g.k - k)).reshape(1, g.k)
 
     kernel = functools.partial(
-        _pipe_kernel, stride=g.stride,
-        cin_banks=g.cin_banks, kout_banks=g.kout_banks, th=g.th, tw=g.tw,
-        cb=g.cb, kb=g.kb, cgrp=g.cgrp, bpg=g.bpg,
+        _pipe_kernel, cin_banks=g.cin_banks, kout_banks=g.kout_banks,
+        th=g.th, tw=g.tw, cb=g.cb, kb=g.kb, cgrp=g.cgrp, bpg=g.bpg,
         relu=relu, pool=pool, requant=g.requant, acc_dtype=acc_dtype,
         dilation=g.dilation)
     out = pl.pallas_call(
         kernel,
+        name=kernel_name("conv2d_ws_pipe", g),
         grid=(g.n, g.n_th, g.n_tw, g.kout_banks),
         in_specs=[
             # feature map + weights stay in HBM: the kernel moves them

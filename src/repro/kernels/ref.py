@@ -78,16 +78,30 @@ VMEM_LIMIT_BYTES = 16 * 1024 * 1024
 ACC_SUBLANES = 8    # sublane tile of a 32-bit value (int32 / f32 accumulator)
 
 
-def folds_taps(tw: int, stride: int, kh: int, kw: int) -> bool:
+def folds_taps(tw: int, kh: int, kw: int) -> bool:
     """Whether the conv kernels' compute body (``conv2d_ws.conv_slab``)
     folds the KH·KW taps into one contraction over the flattened input
-    window: for a stride-1 kernel of several taps whose tile width is off
-    the 32-bit sublane tile.  There a per-tap dot's (TH·TW, KB) result
+    window: for a kernel of several taps whose tile width is off the
+    32-bit sublane tile.  There a per-tap dot's (TH·TW, KB) result
     changes layout on its way into the accumulator; at aligned widths the
     per-tap dots are cheaper than copying the taps into a patch (both
     measured on a v5e, PERF.md).  The one rule the kernels and the VMEM
-    planner share."""
-    return stride == 1 and kh * kw > 1 and tw % ACC_SUBLANES != 0
+    planner share; both see every layer at stride 1 (``phase_split``)."""
+    return kh * kw > 1 and tw % ACC_SUBLANES != 0
+
+
+def phase_split(k: int, stride: int, dilation: int = 1) -> Tuple[int, int]:
+    """(taps, phases) along one axis of a stride-``stride`` conv run, as
+    the conv kernels run it, as a stride-1 conv over the space-to-depth
+    phases of its padded map: phase r holds rows r, r+s, r+2s, …, so
+    output y reads taps q = 0…⌈e/s⌉−1 of every phase at row y + q, where
+    e = dilation·(k−1)+1 is the kernel's extent.  Only the first min(s, e)
+    phases hold a tap: a 1×1/s conv reads phase 0 alone, a plain
+    subsample.  Stride 1 is one phase of the kernel's own taps."""
+    if stride == 1:
+        return k, 1
+    e = dilated_extent(k, dilation)
+    return -(-e // stride), min(stride, e)
 
 
 def lane_legal_banks(dim: int, banks: int) -> bool:
@@ -186,36 +200,59 @@ def conv2d_ref_int8(x, w, bias=None, *, stride: int = 1,
     return out
 
 
-def maxpool2d_ref(x, size: int = 2, stride: int = None):
-    """Max pool over [N,H,W,C]; trailing rows/cols that don't fill a window
-    are dropped (floor semantics, matching the fused kernel epilogue)."""
+def _pool_pads(x, size: int, stride: int, padding: Padding):
+    """reduce_window padding of a ``size``×``size``/``stride`` pool over
+    the [N,H,W,C] map ``x``: SAME as ``normalize_padding`` resolves it
+    (the odd pad at the bottom and right), VALID none."""
+    (pt, pb), (pl_, pr) = normalize_padding(padding, size, size, stride,
+                                            x.shape[1], x.shape[2])
+    return ((0, 0), (pt, pb), (pl_, pr), (0, 0))
+
+
+def maxpool2d_ref(x, size: int = 2, stride: int = None,
+                  padding: Padding = "VALID"):
+    """Max pool over [N,H,W,C]; under VALID, trailing rows/cols that don't
+    fill a window are dropped (floor semantics, matching the fused kernel
+    epilogue).  Padded positions hold the dtype's least value (−128 for an
+    int8 map, −inf for a float one), so they never win a window."""
     stride = size if stride is None else stride
     init = jnp.iinfo(x.dtype).min if jnp.issubdtype(x.dtype, jnp.integer) \
         else -jnp.inf
     return jax.lax.reduce_window(
         x, jnp.asarray(init, x.dtype), jax.lax.max,
-        (1, size, size, 1), (1, stride, stride, 1), "VALID")
+        (1, size, size, 1), (1, stride, stride, 1),
+        _pool_pads(x, size, stride, padding))
 
 
-def avgpool2d_ref(x, size: int = 2, stride: int = None):
-    """Average pool over [N,H,W,C] (floor semantics, like maxpool2d_ref).
+def avgpool2d_ref(x, size: int = 2, stride: int = None,
+                  padding: Padding = "VALID"):
+    """Average pool over [N,H,W,C] (floor semantics under VALID, like
+    maxpool2d_ref); a padded window averages only the map positions it
+    covers.
 
     Integer inputs accumulate the window sum in int32 and round the mean
     back to the input dtype — the int8 feature-map grid is preserved
     (mean of same-scale values stays on the same scale), so the unfused
     int8 avg-pool layer needs no requantization."""
     stride = size if stride is None else stride
+    pads = _pool_pads(x, size, stride, padding)
+    window, strides = (1, size, size, 1), (1, stride, stride, 1)
+    count = size * size
+    if any(p != (0, 0) for p in pads):
+        count = jax.lax.reduce_window(
+            jnp.ones((1, *x.shape[1:3], 1), jnp.float32), jnp.float32(0),
+            jax.lax.add, window, strides, pads)
     if jnp.issubdtype(x.dtype, jnp.integer):
         s = jax.lax.reduce_window(
-            x.astype(jnp.int32), jnp.int32(0), jax.lax.add,
-            (1, size, size, 1), (1, stride, stride, 1), "VALID")
-        mean = jnp.round(s.astype(jnp.float32) / (size * size))
+            x.astype(jnp.int32), jnp.int32(0), jax.lax.add, window, strides,
+            pads)
+        mean = jnp.round(s.astype(jnp.float32) / count)
         info = jnp.iinfo(x.dtype)
         return jnp.clip(mean, info.min, info.max).astype(x.dtype)
     s = jax.lax.reduce_window(
-        x.astype(jnp.float32), jnp.float32(0), jax.lax.add,
-        (1, size, size, 1), (1, stride, stride, 1), "VALID")
-    return (s / (size * size)).astype(x.dtype)
+        x.astype(jnp.float32), jnp.float32(0), jax.lax.add, window, strides,
+        pads)
+    return (s / count).astype(x.dtype)
 
 
 def global_avgpool_ref(x):

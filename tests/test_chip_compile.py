@@ -15,7 +15,11 @@ the tile plans the int8 vgg_imagenet program runs on the chip:
   4×4) and conv5_3 plan (14×14×512 → 512 with the fused pool), where
   the map width is off the sublane tile: the planner's VMEM count must
   cover the scoped allocation Mosaic reports for the kernel;
-* ``matmul_ws`` for the 8×256 @ 256×1000 int8 classifier head.
+* ``matmul_ws`` for the 8×256 @ 256×1000 int8 classifier head;
+* ResNet-50's strided layers at batch 32 under the plans the program runs
+  them on (the 7×7/2 stem, ``res3a_2``, ``res5a_2``, ``res4a_proj``):
+  each compiles as the stride-1 conv over its space-to-depth phases,
+  under the kernel's ``_strided`` name, within the planner's VMEM count.
 
 The topology is described inside a module-scoped fixture, never while a
 module is imported: only one process at a time may load the TPU library,
@@ -138,3 +142,36 @@ def test_matmul_classifier_head(one_chip):
     compiled = _compile(f, one_chip, ((BATCH, 256), jnp.int8),
                         ((256, 1000), jnp.int8), ((1000,), jnp.int32))
     assert "tpu_custom_call" in compiled.as_text()
+
+
+# (H, W, C, K, kernel, ReLU) of ResNet-50 v1.5's strided layers
+RESNET50_STRIDED = {
+    "conv1": (224, 224, 3, 64, 7, True),
+    "res3a_2": (56, 56, 128, 128, 3, True),
+    "res5a_2": (14, 14, 512, 512, 3, True),
+    "res4a_proj": (28, 28, 512, 1024, 1, False),
+}
+
+
+@pytest.mark.parametrize("layer", sorted(RESNET50_STRIDED))
+def test_resnet50_strided_layer_plans_fit_mosaic(one_chip, monkeypatch,
+                                                 layer):
+    h, w, c, k, kk, relu = RESNET50_STRIDED[layer]
+    tp = banking.plan_tiles(h, w, c, k, kk, kk, stride=2, padding="SAME",
+                            in_bytes=1, out_bytes=1)
+    assert tp.fits_vmem, tp
+    kernel, mod = ((conv2d_ws_pipe, pipe_mod) if tp.pipelined
+                   else (conv2d_ws, seq_mod))
+    kw = dict(stride=2, padding="SAME", cin_banks=tp.cin_banks,
+              kout_banks=tp.kout_banks, h_tile=tp.h_tile, relu=relu)
+    shapes = [((32, h, w, c), jnp.int8), ((kk, kk, c, k), jnp.int8),
+              ((k,), jnp.int32), ((k,), jnp.float32)]
+
+    def f(x, wt, b, s):
+        return kernel(x, wt, b, s, interpret=False, **kw)
+    text = _compile(f, one_chip, *shapes).as_text()
+    name = "conv2d_ws_pipe" if tp.pipelined else "conv2d_ws"
+    assert re.search(rf"%{name}_strided\.\d+ = \S+ custom-call", text)
+    scoped = _scoped_vmem_bytes(monkeypatch, mod, kernel, one_chip, shapes,
+                                **kw)
+    assert tp.working_set_bytes >= scoped, (tp.working_set_bytes, scoped)
