@@ -37,9 +37,12 @@ from __future__ import annotations
 
 import weakref
 from dataclasses import dataclass, replace
+from functools import cached_property
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import AxisType, NamedSharding
+from jax.sharding import PartitionSpec as P
 
 from repro import obs
 from repro.core.banking import divisor_banks
@@ -277,6 +280,47 @@ class MultiCoreScheduler:
             return SpatialShardedBackend(inner, self.config.n_cores)
         return KoutShardedBackend(inner, self.config.n_cores)
 
+    @cached_property
+    def mesh(self):
+        """The mesh of the first ``n_cores`` devices when batch mode runs
+        one IP core per device (``n_cores > 1`` with that many devices
+        present), else ``None``.  Its axis is Auto: ``shard_map`` takes
+        closed-over arrays only from such a mesh."""
+        cores = self.config.n_cores
+        if (self.config.mode != "batch" or cores == 1
+                or jax.device_count() < cores):
+            return None
+        return jax.make_mesh((cores,), ("cores",), (AxisType.Auto,),
+                             devices=jax.devices()[:cores])
+
+    def place(self, qnet):
+        """``qnet`` with its arrays replicated over ``mesh``, for a
+        program that closes over them: every device then holds the
+        weights once, and no batch copies them from the first device to
+        the others.  Unchanged where there is no mesh."""
+        if self.mesh is None:
+            return qnet
+        with obs.span("sched.place", network=qnet.plan.name,
+                      cores=self.config.n_cores):
+            fields = ("weights", "biases", "requants", "in_scale",
+                      "out_dequant", "merge_scales")
+            placed = jax.device_put([getattr(qnet, f) for f in fields],
+                                    NamedSharding(self.mesh, P()))
+            return replace(qnet, **dict(zip(fields, placed)))
+
+    def put(self, host_batch) -> jax.Array:
+        """The host batch on the device(s) ``run`` takes it from: with a
+        mesh, each device's slice of the batch straight from the host
+        (the slices ``run``'s shard_map hands each device); otherwise,
+        or for a batch the cores do not divide, the default device."""
+        if self.mesh is None or host_batch.shape[0] % self.config.n_cores:
+            return jax.device_put(host_batch)
+        return jax.device_put(host_batch, self._batch_sharding)
+
+    @cached_property
+    def _batch_sharding(self) -> NamedSharding:
+        return NamedSharding(self.mesh, P("cores"))
+
     def run(self, program, x: jax.Array) -> jax.Array:
         """batch mode: split the batch over cores.  kout / spatial modes:
         pass through — the cores divide kernels or row bands inside the
@@ -293,7 +337,10 @@ class MultiCoreScheduler:
         explicit);
         otherwise vmapped virtual cores on one device.  The counters
         ``sched.batch.device`` / ``sched.batch.virtual`` count which of
-        the two served each batch-mode run.
+        the two served each batch-mode run, and ``sched.batch.placed``
+        the device runs whose batch arrived already split over the mesh
+        (``put``) — for a program built on ``place``d weights, runs that
+        copy nothing between devices.
 
         Each run is an ``sched.run`` trace span (mode, cores, batch,
         virtual-vs-device) when obs is enabled — the per-core/mode
@@ -310,8 +357,10 @@ class MultiCoreScheduler:
         if pad:
             x = jnp.concatenate(
                 [x, jnp.zeros((pad, *x.shape[1:]), x.dtype)])
-        if jax.device_count() >= cores:
+        if self.mesh is not None:
             obs.metrics.counter("sched.batch.device").inc()
+            if getattr(x, "sharding", None) == self._batch_sharding:
+                obs.metrics.counter("sched.batch.placed").inc()
             with obs.span("sched.run", mode="batch", cores=cores, batch=n,
                           padded=pad, virtual=False):
                 return self._on_devices(program)(x)[:n]
@@ -323,19 +372,14 @@ class MultiCoreScheduler:
             return ys.reshape(n + pad, *ys.shape[2:])[:n]
 
     def _on_devices(self, program):
-        """``program`` data-parallel over the first ``n_cores`` devices:
-        each device runs it on its own batch shard (jitted once per
-        program)."""
+        """``program`` data-parallel over ``mesh``: each device runs it on
+        its own batch shard (jitted once per program)."""
         fn = self._per_device.get(program)
         if fn is None:
-            from jax.sharding import PartitionSpec as P
-            cores = self.config.n_cores
-            mesh = jax.make_mesh((cores,), ("cores",),
-                                 devices=jax.devices()[:cores])
             # a weak reference, so the wrapper (the entry's value) never
             # keeps its key alive once the program is evicted
             prog = weakref.ref(program)
             fn = self._per_device[program] = jax.jit(jax.shard_map(
-                lambda x: prog()(x), mesh=mesh, in_specs=P("cores"),
+                lambda x: prog()(x), mesh=self.mesh, in_specs=P("cores"),
                 out_specs=P("cores"), check_vma=False))
         return fn

@@ -26,7 +26,7 @@ Three pieces, composable and individually testable:
   threads.
 
 * :class:`ProgramCache` — a bounded LRU of compiled
-  ``(network, backend)`` programs.  One engine serves the whole zoo off
+  ``(network, backend, mesh)`` programs.  One engine serves the whole zoo off
   one backend/scheduler; eviction and recompile are *measured* (hit /
   miss / eviction counters, ``engine.compile`` spans), bounded
   (``capacity``), and observable (``cache.size`` gauge).
@@ -222,7 +222,8 @@ class RequestQueue:
 
 
 class ProgramCache:
-    """Bounded LRU of compiled programs, keyed by ``(network, backend)``.
+    """Bounded LRU of compiled programs, keyed by ``(network, backend,
+    mesh)``.
 
     ``get`` is get-or-build: a hit refreshes recency, a miss runs
     ``build()`` (the caller wraps it in an ``engine.compile`` span) and
@@ -376,7 +377,7 @@ class ContinuousBatchingEngine:
             classes=qnet.plan.activation_shapes()[-1][-1],
             tune=tune, backend_name=backend_name, sched=sched)
         self._models[name] = entry
-        self._compiled(entry, backend_name)     # eager default program
+        self._compiled(entry, backend_name, sched)  # eager default program
         return name
 
     def _shard_backend_name(self, sched) -> str:
@@ -419,9 +420,11 @@ class ContinuousBatchingEngine:
 
     # -- compilation ---------------------------------------------------------
 
-    def _compiled(self, entry: _Model, backend_name: str):
-        """(program, tile_plans, core_config) for one (model, backend)
-        point, through the LRU cache."""
+    def _compiled(self, entry: _Model, backend_name: str, sched):
+        """(program, tile_plans, core_config) for one (model, backend,
+        mesh) point, through the LRU cache.  The program closes over
+        ``sched.place``'s copy of the weights, so it serves only
+        schedulers on the same mesh (``None``: one device)."""
         from repro.core.convcore import ConvCoreConfig
         from repro.core.network import make_int8_program, program_tile_plans
 
@@ -435,11 +438,11 @@ class ContinuousBatchingEngine:
                     tile_plans = entry.tune.tile_plans
                 else:
                     tile_plans = program_tile_plans(entry.qnet.plan, cfg)
-                program = make_int8_program(entry.qnet, cfg,
+                program = make_int8_program(sched.place(entry.qnet), cfg,
                                             tile_plans=tile_plans)
             return program, tile_plans, cfg
 
-        return self.cache.get((entry.name, backend_name), build)
+        return self.cache.get((entry.name, backend_name, sched.mesh), build)
 
     # -- admission / submission ----------------------------------------------
 
@@ -580,7 +583,6 @@ class ContinuousBatchingEngine:
         return cached
 
     def _dispatch(self, fb: FormedBatch) -> None:
-        import jax
         entry = self._models[fb.model]
         n_real = len(fb.requests)
         pad = self.batch - n_real
@@ -599,9 +601,9 @@ class ContinuousBatchingEngine:
                 chunk = np.concatenate(
                     [chunk, np.zeros((pad, *entry.input_shape), np.float32)])
         backend_name, sched, _ = self._route_for(entry, n_real)
-        program, _, _ = self._compiled(entry, backend_name)
+        program, _, _ = self._compiled(entry, backend_name, sched)
         with obs.span("engine.put", **span_args):
-            x = jax.device_put(chunk)
+            x = sched.put(chunk)
         dev = sched.run(program, x)
         # async dispatch: the device result stays unmaterialized; the
         # next batch forms and launches while this one computes

@@ -375,18 +375,49 @@ def test_batch_mode_runs_on_real_devices():
     """n_cores=4 with four devices present: MultiCoreScheduler's batch
     mode splits each formed batch across the devices (shard_map), never
     the vmapped virtual cores, and serves logits bit-identical to one
-    device."""
+    device.  ``sched.batch.placed`` counts every one of those batches
+    (weights and batch already on the mesh), and none at n_cores=1."""
     _run_on_four_devices("""
         out = {}
+        device = obs.metrics.counter("sched.batch.device")
+        placed = obs.metrics.counter("sched.batch.placed")
         for cores in (1, 4):
             with ContinuousBatchingEngine(batch=4, n_cores=cores,
                                           backend="pallas") as eng:
                 eng.add_model(qnet)
-                out[cores] = eng.submit(x)
+                out[cores] = np.concatenate([eng.submit(x),
+                                             eng.submit(x[::-1])])
+            want = 0 if cores == 1 else 4
+            assert device.value == placed.value == want, \
+                (cores, device.value, placed.value)
         np.testing.assert_array_equal(out[1], out[4])
-        assert obs.metrics.counter("sched.batch.device").value == 2
         assert obs.metrics.counter("sched.batch.virtual").value == 0
     """)
+
+
+def test_batch_mode_copies_nothing_between_devices():
+    """With the weights' constants hoisted into program arguments (as
+    the benchmark runs), batch mode on four devices copies nothing from
+    one device to another once its program is built: the weights were
+    replicated over the mesh when it was, and each batch's quarters go
+    from the host straight to their devices.  The guard is set globally:
+    the context manager is thread-local and would not reach the engine's
+    worker thread."""
+    _run_on_four_devices("""
+        with ContinuousBatchingEngine(batch=4, n_cores=1,
+                                      backend="pallas") as eng:
+            eng.add_model(qnet)
+            want = eng.submit(x)
+        with ContinuousBatchingEngine(batch=4, n_cores=4,
+                                      backend="pallas") as eng:
+            eng.add_model(qnet)
+            first = eng.submit(x[:4])
+            jax.config.update("jax_transfer_guard_device_to_device",
+                              "disallow")
+            rest = eng.submit(x)                       # two batches more
+        np.testing.assert_array_equal(first, want[:4])
+        np.testing.assert_array_equal(rest, want)
+    """, env={"JAX_USE_SIMPLIFIED_JAXPR_CONSTANTS": "1"})
 
 
 def test_device_programs_live_as_long_as_the_lru_entry():
@@ -399,6 +430,11 @@ def test_device_programs_live_as_long_as_the_lru_entry():
         other = network.lenet(input_shape=(16, 16, 1))
         y = rng.normal(size=(4, 16, 16, 1)).astype(np.float32)
         qother = network.quantize_network(other, other.init_params(rng), y)
+        with ContinuousBatchingEngine(batch=4, n_cores=1,
+                                      backend="pallas") as eng:
+            eng.add_model(qnet)
+            want = eng.submit(x)
+        obs.enable()
         with ContinuousBatchingEngine(batch=4, n_cores=4, backend="pallas",
                                       cache_capacity=1) as eng:
             a = eng.add_model(qnet, name="a")
@@ -410,14 +446,59 @@ def test_device_programs_live_as_long_as_the_lru_entry():
             gc.collect()
             assert len(sched._per_device) == 1, len(sched._per_device)
             assert eng.metrics.counter("cache.evictions").value >= 3
+        # every compile placed its weights again: a, b, a, b, a
+        places = [e["args"]["network"] for e in obs.tracer.events()
+                  if e["name"] == "sched.place"]
+        assert places == ["lenet"] * 5, places
+        device = obs.metrics.counter("sched.batch.device").value
+        assert obs.metrics.counter("sched.batch.placed").value == device
         np.testing.assert_array_equal(first, second)
+        np.testing.assert_array_equal(first, want)
     """)
 
 
-def _run_on_four_devices(body: str) -> None:
+def test_routed_batch_modes_each_run_their_own_mesh():
+    """``route=True`` with a tune plan: formed batches of 1, 2 and 4 on a
+    four-core budget run batch mode on one device, a two-device mesh and
+    a four-device mesh.  Each mesh gets its own placed program (the cache
+    key holds the mesh), so no scheduler runs a program closed over
+    another mesh's weights, and every size serves one device's logits."""
+    _run_on_four_devices("""
+        from repro.core.autotune import autotune_network, route_batch
+        tiny = network.NetworkPlan(
+            name="tiny", input_shape=(4, 4, 1),
+            layers=(network.conv(1, kernel=3, padding="SAME"),
+                    network.flatten(), network.dense(1)))
+        z = rng.normal(size=(4, 4, 4, 1)).astype(np.float32)
+        qtiny = network.quantize_network(tiny, tiny.init_params(rng), z)
+        tune = autotune_network(tiny)
+        assert [route_batch(tune.layers, n, 4)[:2] for n in (1, 2, 4)] \
+            == [("batch", 1), ("batch", 2), ("batch", 4)]
+        with ContinuousBatchingEngine(batch=4, n_cores=1,
+                                      backend="pallas") as eng:
+            eng.add_model(qtiny)
+            want = eng.submit(z)
+        with ContinuousBatchingEngine(batch=4, n_cores=4, backend="pallas",
+                                      route=True) as eng:
+            eng.add_model(qtiny, tune=tune)
+            got = [eng.submit(z[:n]) for n in (2, 4, 1)]
+            meshes = sorted(0 if k[2] is None else k[2].size
+                            for k in eng.cache.keys())
+        assert meshes == [0, 2, 4], meshes
+        for g in got:
+            np.testing.assert_array_equal(g, want[:len(g)])
+        device = obs.metrics.counter("sched.batch.device").value
+        assert device == 2, device
+        assert obs.metrics.counter("sched.batch.placed").value == device
+    """)
+
+
+def _run_on_four_devices(body: str, env=None) -> None:
     """Run ``body`` in a subprocess that sees four CPU devices, after a
     prelude that builds a quantized 12×12 lenet (``qnet``, images ``x``,
-    ``rng``), so the four-device flag never leaks into this process."""
+    ``rng``), so the four-device flag never leaks into this process.
+    ``env`` adds variables to the subprocess's environment, which JAX
+    reads as it is imported."""
     import os
     import subprocess
     import sys
@@ -439,7 +520,8 @@ def _run_on_four_devices(body: str) -> None:
     script = (textwrap.dedent(prelude) + textwrap.dedent(body)
               + 'print("DEVICES_OK")\n')
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env = dict(os.environ, PYTHONPATH="src", JAX_PLATFORMS="cpu")
+    env = dict(os.environ, PYTHONPATH="src", JAX_PLATFORMS="cpu",
+               **(env or {}))
     r = subprocess.run([sys.executable, "-c", script], capture_output=True,
                        text=True, env=env, cwd=repo, timeout=560)
     assert "DEVICES_OK" in r.stdout, r.stdout[-1500:] + r.stderr[-4000:]
