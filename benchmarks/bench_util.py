@@ -11,10 +11,9 @@ import jax
 
 class Timing(float):
     """Median wall-time per call in microseconds, carrying the full stats
-    record the calibration fitter needs: ``min`` / ``median`` / ``iqr``
-    and the raw sample list.  A float subclass, so every existing
-    ``time_fn`` call site keeps working unchanged while calibration code
-    reads ``.iqr_us`` to reject noisy samples."""
+    record: ``min`` / ``median`` / ``iqr`` and the raw sample list.  A
+    float subclass, so every ``time_fn`` call site reads it as the
+    median."""
 
     def __new__(cls, samples_us):
         times = sorted(samples_us)
@@ -70,7 +69,7 @@ class Timing(float):
 def time_fn(fn, *args, iters: int = 5, warmup: int = 2) -> Timing:
     """Median wall-time per call in microseconds (blocks on results).
     Returns a :class:`Timing` — a float (the median) that also carries
-    min / IQR / the sample list for calibration-grade noise rejection."""
+    min / IQR / the sample list."""
     for _ in range(warmup):
         jax.block_until_ready(fn(*args))
     times = []

@@ -1,24 +1,20 @@
 """Kernel microbenchmarks: banked conv + WS-GEMM variants (functional CPU
 timings + analytic VMEM working sets from banking.py), plus the
 sequential-vs-pipelined conv head-to-head over the DMA-bound shapes from
-the zoo so the perfmodel crossover predictor can be eyeballed against
-measurement.  Interpret-mode caveat for the head-to-head: the manual DMAs
-execute eagerly in Python on CPU, so measured_us there reflects emulation
-overhead, not overlap — the model columns (seq/pipe cycles, the predictor
-verdict) are the cross-PR signal; on a TPU host the same rows time native
-Mosaic."""
+the zoo, with the §5.2 model's price of each variant and the plan's own
+kernel choice alongside the measurement.  Interpret-mode caveat for the
+head-to-head: the manual DMAs execute eagerly in Python on CPU, so
+measured_us there reflects emulation overhead, not overlap; on a TPU
+host the same rows time native Mosaic."""
 
 from __future__ import annotations
 
 import jax.numpy as jnp
 import numpy as np
 
-import os
-
 from benchmarks.bench_util import emit, time_fn
 from repro.core import perfmodel
 from repro.core.banking import plan_banks, plan_tiles
-from repro.core.calibration import load_table
 from repro.kernels import ref
 from repro.kernels.conv2d_ws import conv2d_ws
 from repro.kernels.conv2d_ws_pipe import conv2d_ws_pipe
@@ -61,7 +57,7 @@ def run():
     # --- sequential vs pipelined head-to-head (DMA-bound zoo shapes) ------
     # depthwise 3×3 (the dma_bound_board family), 1×1 pointwise, and a
     # large-map tiled layer: one row per (shape, kernel variant) with the
-    # crossover predictor's verdict alongside the measurement
+    # plan's kernel choice alongside the measurement
     cases = [
         ("depthwise3x3", dict(h=16, w=16, c=32, k=32, kh=3, kw=3,
                               groups=32, pad="SAME", h_tile=0, w_tile=0)),
@@ -71,11 +67,6 @@ def run():
                                 groups=1, pad="SAME", h_tile=16,
                                 w_tile=16)),
     ]
-    # fitted table (benchmarks/calibrate.py): the head-to-head rows then
-    # carry the CALIBRATED verdict alongside the analytic one, so a
-    # crossover flip after calibration is visible right in the kernel rows
-    calib = load_table(os.environ.get("CALIBRATION_JSON",
-                                      "CALIBRATION.json"))
     for name, c_ in cases:
         cb, kb = ref.grouped_banks(c_["c"], c_["k"], c_["groups"])
         xi8 = jnp.asarray(
@@ -88,7 +79,7 @@ def run():
         plan = plan_tiles(c_["h"], c_["w"], c_["c"], c_["k"], c_["kh"],
                           c_["kw"], padding=c_["pad"], groups=c_["groups"],
                           in_bytes=1, out_bytes=1, cin_banks=cb,
-                          kout_banks=kb, kernel="auto")
+                          kout_banks=kb)
         psums = perfmodel.psum_count(c_["h"], c_["w"], c_["c"], c_["k"],
                                      c_["kh"], c_["kw"], padding=c_["pad"],
                                      groups=c_["groups"])
@@ -96,13 +87,7 @@ def run():
         model = (f"model_seq_cycles={est['sequential_cycles']};"
                  f"model_pipe_cycles={est['pipelined_cycles']};"
                  f"model_speedup={est['speedup']:.3f};"
-                 f"predictor_pipelined={int(plan.pipelined)}")
-        if calib is not None:
-            cal = perfmodel.pipeline_estimate(plan, psums, calib=calib)
-            model += (f";calib_seq_cycles={cal['sequential_cycles']};"
-                      f"calib_pipe_cycles={cal['pipelined_cycles']};"
-                      f"calib_pipelined="
-                      f"{int(cal['pipelined_cycles'] < cal['sequential_cycles'])}")
+                 f"plan_pipelined={int(plan.pipelined)}")
         for variant, fn in (("seq", conv2d_ws), ("pipe", conv2d_ws_pipe)):
             us = time_fn(lambda fn=fn: fn(
                 xi8, wi8, padding=c_["pad"], groups=c_["groups"],

@@ -1,34 +1,27 @@
-"""Plan autotuner: search (TilePlan × kernel variant × scheduler mode ×
-core count) against the measurement-calibrated cost model.
+"""Plan autotuner: search (TilePlan × scheduler mode × core count)
+against the §5.2 cost model.
 
 ``banking.plan_tiles`` is a greedy descent: from the paper's 4×4 banking
 it applies whichever single move shrinks the working set most until the
 plan fits VMEM.  That finds *a* legal plan, not the cheapest one — the
-descent stops at the first fit, never revisits bank counts that trade
-VMEM headroom for DMA traffic (input bytes scale with ``kout_banks``
-revisits!), and its pipelined/sequential verdict trusts the analytic
-crossover.  The FPGA-mapper literature is unanimous that accelerator
-CNN planners win by design-space exploration against a measured cost
-model; this module is that exploration:
+descent stops at the first fit and never revisits bank counts that
+trade VMEM headroom for DMA traffic (input bytes scale with
+``kout_banks`` revisits!).  This module explores that space:
 
 * :func:`autotune_layer` — enumerate the LEGAL candidate space for one
   conv layer (pool-aligned tile halving chains × divisor bank sets,
   pruned by ``fits_vmem`` and group alignment), price every candidate
-  under BOTH kernel variants with ``perfmodel.pipeline_estimate(...,
-  calib=...)``, and return the cheapest (deterministic tie-break).  The
-  greedy ``plan_tiles`` plan is always seeded into the candidate set, so
-  the tuned plan is never worse than the fallback *by construction*.
+  for the kernel variant its plan carries (``TilePlan.pipelined``) with
+  ``perfmodel.pipeline_estimate``, and return the cheapest
+  (deterministic tie-break).  The greedy ``plan_tiles`` plan is always
+  seeded into the candidate set, so the tuned plan is never worse than
+  the fallback *by construction*.
 * :func:`autotune_network` — run the layer search over a
   ``NetworkPlan`` and then search (scheduler mode × core count) for the
   whole network, returning a :class:`NetworkTunePlan` whose
   ``tile_plans`` list threads through ``NetworkPlan.tile_plans`` /
   ``make_int8_program`` / ``MultiCoreScheduler`` unchanged at the call
   sites.
-
-With ``calib=None`` the search prices candidates on the analytic §5.2
-model (still a strict improvement over greedy descent — same model,
-bigger search space); with a fitted ``CalibrationTable`` the search
-optimizes what was *measured*, which is the point.
 """
 
 from __future__ import annotations
@@ -90,11 +83,11 @@ def candidate_states(oh: int, ow: int, cgrp: int, k: int, groups: int,
 
 @dataclass(frozen=True)
 class LayerTune:
-    """The tuner's verdict for one node: the chosen plan, its calibrated
-    chosen-variant cycle count, and the greedy fallback it beat (or
-    matched).  ``source`` is "autotuned" when the chosen plan differs
-    from the greedy ``plan_tiles(kernel="auto")`` plan, "greedy" when
-    the search confirmed the fallback was already optimal."""
+    """The tuner's verdict for one node: the chosen plan, its cycle
+    count, and the greedy fallback it beat (or matched).  ``source`` is
+    "autotuned" when the chosen plan differs from the greedy
+    ``plan_tiles`` plan, "greedy" when the search confirmed the fallback
+    was already optimal."""
     name: str
     plan: Optional[TilePlan]
     cycles: int
@@ -111,20 +104,13 @@ class LayerTune:
         return "greedy" if self.plan == self.greedy_plan else "autotuned"
 
 
-def _variant_cost(plan: TilePlan, psums: int, cfg, calib) -> Tuple[int, int]:
-    est = perfmodel.pipeline_estimate(plan, psums, cfg, calib)
-    return est["sequential_cycles"], est["pipelined_cycles"]
-
-
 def plan_cost(plan: TilePlan, psums: int,
-              cfg: perfmodel.IPCoreConfig = perfmodel.IPCoreConfig(),
-              calib=None) -> int:
-    """Calibrated cycle count of one layer pass under ``plan``, priced
-    for the kernel variant the plan carries (``TilePlan.pipelined``) —
-    the single cost definition the tuner, its tests, and the benchmark
+              cfg: perfmodel.IPCoreConfig = perfmodel.IPCoreConfig()) -> int:
+    """Cycle count of one layer pass under ``plan``, priced for the
+    kernel variant the plan carries (``TilePlan.pipelined``) — the
+    single cost definition the tuner, its tests, and the benchmark
     reports share."""
-    seq, pipe = _variant_cost(plan, psums, cfg, calib)
-    return pipe if plan.pipelined else seq
+    return perfmodel.pipeline_estimate(plan, psums, cfg)["cycles"]
 
 
 def autotune_layer(h: int, w: int, c: int, k: int, kh: int = 3, kw: int = 3,
@@ -135,16 +121,15 @@ def autotune_layer(h: int, w: int, c: int, k: int, kh: int = 3, kw: int = 3,
                    cin_banks: int = 4, kout_banks: int = 4,
                    vmem_budget: Optional[int] = banking.VMEM_LIMIT_BYTES,
                    cfg: perfmodel.IPCoreConfig = perfmodel.IPCoreConfig(),
-                   calib=None, name: str = "conv",
+                   name: str = "conv",
                    psums: Optional[int] = None) -> LayerTune:
-    """Exhaustive (TilePlan × kernel variant) search for one conv layer.
+    """Exhaustive TilePlan search for one conv layer.
 
     Every candidate is built through ``banking.plan_tiles``'s own
     ``build`` geometry (same halo math, same byte accounting), pruned by
-    ``fits_vmem``, and priced by ``perfmodel.pipeline_estimate`` under
-    ``calib`` for BOTH kernel variants; the cheapest (cost, then a fixed
-    structural tie-break) wins, so the result is deterministic given a
-    fixed CalibrationTable.  The greedy ``plan_tiles(kernel="auto")``
+    ``fits_vmem``, and priced by ``plan_cost`` for the kernel variant it
+    carries; the cheapest (cost, then a fixed structural tie-break)
+    wins, so the result is deterministic.  The greedy ``plan_tiles``
     plan for the same arguments is seeded into the candidate set: the
     tuned plan can only ever match or beat it under the same model.
 
@@ -162,8 +147,8 @@ def autotune_layer(h: int, w: int, c: int, k: int, kh: int = 3, kw: int = 3,
         groups=groups, dilation=dilation, in_bytes=in_bytes,
         acc_bytes=acc_bytes,
         out_bytes=out_bytes, cin_banks=cin_banks, kout_banks=kout_banks,
-        vmem_budget=vmem_budget, kernel="auto", calib=calib)
-    greedy_cost = plan_cost(greedy, psums, cfg, calib)
+        vmem_budget=vmem_budget)
+    greedy_cost = plan_cost(greedy, psums, cfg)
 
     oh, ow = conv_out_shape(h, w, kh, kw, stride, padding, dilation)
     if pool:
@@ -178,11 +163,11 @@ def autotune_layer(h: int, w: int, c: int, k: int, kh: int = 3, kw: int = 3,
             budget=budget)
 
     # (cost, structural tie-break, plan): the tie-break prefers fewer
-    # tiles, coarser banking, then the sequential kernel — a fixed total
-    # order, so equal-cost candidate sets always resolve the same way
+    # tiles, then coarser banking — a fixed total order, so equal-cost
+    # candidate sets always resolve the same way
     def key(plan: TilePlan, cost: int):
         return (cost, plan.n_tiles, plan.kout_banks, plan.cin_banks,
-                plan.pipelined, plan.h_tile, plan.w_tile)
+                plan.h_tile, plan.w_tile)
 
     best_plan, best_key = greedy, key(greedy, greedy_cost)
     n_cands = 0
@@ -198,12 +183,9 @@ def autotune_layer(h: int, w: int, c: int, k: int, kh: int = 3, kw: int = 3,
             n_cands += 1
             with obs.span("autotune.candidate", layer=name, h_tile=th,
                           w_tile=tw, cin_banks=cbn, kout_banks=kbn):
-                seq, pipe = _variant_cost(cand, psums, cfg, calib)
-                for pipelined, cost in ((False, seq), (True, pipe)):
-                    p = replace(cand, pipelined=pipelined)
-                    k_ = key(p, cost)
-                    if k_ < best_key:
-                        best_plan, best_key = p, k_
+                k_ = key(cand, plan_cost(cand, psums, cfg))
+            if k_ < best_key:
+                best_plan, best_key = cand, k_
     obs.metrics.counter("autotune.candidates").inc(n_cands)
     return LayerTune(name=name, plan=best_plan, cycles=best_key[0],
                      greedy_plan=greedy, greedy_cycles=greedy_cost,
@@ -220,7 +202,7 @@ class NetworkTunePlan:
     """A tuned execution recipe for one network: per-layer plans (the
     ``tile_plans`` property is a drop-in for ``NetworkPlan.tile_plans``
     output — pass it to ``make_int8_program(..., tile_plans=...)``), the
-    winning scheduler (mode, core count), and the calibrated totals for
+    winning scheduler (mode, core count), and the modelled totals for
     both the tuned and the greedy-fallback plan sets."""
     network: str
     layers: Tuple[LayerTune, ...]
@@ -229,7 +211,6 @@ class NetworkTunePlan:
     cycles: int = 0                 # tuned total, 1 core
     greedy_cycles: int = 0          # greedy-fallback total, 1 core
     schedule_cycles_: int = 0       # tuned total at (mode, n_cores)
-    calibrated: bool = False        # a CalibrationTable priced the search
 
     @property
     def tile_plans(self) -> List[Optional[TilePlan]]:
@@ -299,9 +280,9 @@ def _spatial_halo_plan(tp: TilePlan, bands: int) -> TilePlan:
 
 
 def schedule_cycles(layers: Sequence[LayerTune], mode: str, cores: int,
-                    cfg: perfmodel.IPCoreConfig = perfmodel.IPCoreConfig(),
-                    calib=None) -> int:
-    """Calibrated whole-network cycles under one (scheduler mode, core
+                    cfg: perfmodel.IPCoreConfig = perfmodel.IPCoreConfig()
+                    ) -> int:
+    """Whole-network cycles under one (scheduler mode, core
     count) point:
 
     * batch — throughput pricing: compute divides by the core count,
@@ -313,8 +294,8 @@ def schedule_cycles(layers: Sequence[LayerTune], mode: str, cores: int,
     * spatial — per-layer compute divides by the row-band count and the
       bands' ``kh − stride`` halo re-reads are charged to DMA.
 
-    Layers without a plan (dense GEMMs, merge nodes) price on calibrated
-    compute cycles with the same per-mode division."""
+    Layers without a plan (dense GEMMs, merge nodes) price on compute
+    cycles with the same per-mode division."""
     total = 0
     for lt in layers:
         tp, p = lt.plan, lt.psums
@@ -322,8 +303,7 @@ def schedule_cycles(layers: Sequence[LayerTune], mode: str, cores: int,
             if not p:
                 continue
             eff = cores if mode in ("batch", "kout") else 1
-            total += perfmodel.calibrated_cycles(
-                p, replace(cfg, ip_cores=eff), calib)
+            total += perfmodel.cycles(p, replace(cfg, ip_cores=eff))
             continue
         if mode == "batch":
             eff, priced = cores, tp
@@ -333,18 +313,15 @@ def schedule_cycles(layers: Sequence[LayerTune], mode: str, cores: int,
         else:
             eff = _spatial_shards(tp, cores)
             priced = _spatial_halo_plan(tp, eff)
-        est = perfmodel.pipeline_estimate(
-            priced, p, replace(cfg, ip_cores=eff), calib)
-        total += est["pipelined_cycles" if tp.pipelined
-                     else "sequential_cycles"]
+        total += plan_cost(priced, p, replace(cfg, ip_cores=eff))
     return total
 
 
 def route_batch(layers: Sequence[LayerTune], batch: int, n_cores: int,
                 cfg: perfmodel.IPCoreConfig = perfmodel.IPCoreConfig(),
-                calib=None, modes: Sequence[str] = SCHEDULER_MODES
+                modes: Sequence[str] = SCHEDULER_MODES
                 ) -> Tuple[str, int, int]:
-    """Pick the scheduler mode the calibrated model predicts fastest for
+    """Pick the scheduler mode the cost model predicts fastest for
     ONE formed batch of ``batch`` images on an ``n_cores`` budget.
     Returns ``(mode, cores, predicted_cycles)``.
 
@@ -367,7 +344,7 @@ def route_batch(layers: Sequence[LayerTune], batch: int, n_cores: int,
     best = None
     for mode in modes:
         cores = min(batch, n_cores) if mode == "batch" else n_cores
-        cycles = batch * schedule_cycles(layers, mode, cores, cfg, calib)
+        cycles = batch * schedule_cycles(layers, mode, cores, cfg)
         if best is None or cycles < best[2]:
             best = (mode, cores, cycles)
     return best
@@ -377,14 +354,13 @@ def autotune_network(plan, cin_banks: int = 4, kout_banks: int = 4,
                      in_bytes: int = 1,
                      vmem_budget: Optional[int] = banking.VMEM_LIMIT_BYTES,
                      cfg: perfmodel.IPCoreConfig = perfmodel.IPCoreConfig(),
-                     calib=None,
                      modes: Sequence[str] = SCHEDULER_MODES,
                      core_counts: Sequence[int] = CORE_COUNTS
                      ) -> NetworkTunePlan:
     """Tune every conv layer of a ``NetworkPlan`` (same walk and bank
     legalization as ``NetworkPlan.tile_plans``, so the tuned list is a
     drop-in replacement), then search (scheduler mode × core count) for
-    the whole network under the calibrated model.  Deterministic: modes
+    the whole network under the cost model.  Deterministic: modes
     are scanned in the given order and core counts ascending, with
     strict improvement required to move — ties resolve to the earliest
     (fewest-cores) point."""
@@ -400,7 +376,7 @@ def autotune_network(plan, cin_banks: int = 4, kout_banks: int = 4,
     for i, sp in enumerate(plan.layers):
         if sp.kind not in ("conv", "conv_transpose"):
             p = psum_rows[names[i]]
-            cyc = perfmodel.calibrated_cycles(p, cfg, calib) if p else 0
+            cyc = perfmodel.cycles(p, cfg) if p else 0
             tunes.append(LayerTune(name=names[i], plan=None, cycles=cyc,
                                    greedy_cycles=cyc, psums=p))
             continue
@@ -422,20 +398,20 @@ def autotune_network(plan, cin_banks: int = 4, kout_banks: int = 4,
             out_bytes=4 if i == last_param else in_bytes,
             cin_banks=cin_banks, kout_banks=kout_banks,
             vmem_budget=vmem_budget,
-            cfg=cfg, calib=calib, name=names[i],
+            cfg=cfg, name=names[i],
             psums=psum_rows[names[i]]))
     total = sum(lt.cycles for lt in tunes)
     greedy_total = sum(lt.greedy_cycles for lt in tunes)
-    best = ("batch", 1, schedule_cycles(tunes, "batch", 1, cfg, calib))
+    best = ("batch", 1, schedule_cycles(tunes, "batch", 1, cfg))
     with obs.span("autotune.schedule_sweep", network=plan.name):
         for mode in modes:
             for cores in sorted(core_counts):
                 with obs.span("autotune.schedule", mode=mode, cores=cores):
-                    cyc = schedule_cycles(tunes, mode, cores, cfg, calib)
+                    cyc = schedule_cycles(tunes, mode, cores, cfg)
                 if cyc < best[2]:
                     best = (mode, cores, cyc)
     return NetworkTunePlan(
         network=plan.name, layers=tuple(tunes),
         scheduler_mode=best[0], n_cores=best[1],
         cycles=total, greedy_cycles=greedy_total,
-        schedule_cycles_=best[2], calibrated=calib is not None)
+        schedule_cycles_=best[2])

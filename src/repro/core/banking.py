@@ -47,7 +47,7 @@ space-to-depth form the kernels run (``tile_plan``).
 
 from __future__ import annotations
 
-from dataclasses import dataclass, replace
+from dataclasses import dataclass
 from typing import Optional
 
 from repro.kernels.ref import (LANES, VMEM_LIMIT_BYTES, check_groups,
@@ -151,8 +151,8 @@ class TilePlan:
     docstring (``conv_vmem_bytes``), which is what must fit the VMEM
     budget.  Pallas's implicit pipeline double-buffers the DMA'd blocks
     and conv2d_ws_pipe materializes the same two slots as explicit VMEM
-    scratch, so it is identical for both kernel variants and
-    ``pipelined`` never changes whether a plan fits."""
+    scratch, so it is identical for both kernel variants and the kernel
+    choice (``pipelined``) never changes whether a plan fits."""
     cin_banks: int
     kout_banks: int
     h_tile: int
@@ -175,9 +175,6 @@ class TilePlan:
     groups: int = 1                   # grouped conv: kout banks stay inside
                                       # group boundaries; image blocks are
                                       # the per-group C/groups slice
-    pipelined: bool = False           # run this layer on conv2d_ws_pipe
-                                      # (explicit ping-pong DMA) instead of
-                                      # the implicitly pipelined conv2d_ws
 
     @property
     def fits_vmem(self) -> bool:
@@ -190,6 +187,19 @@ class TilePlan:
     @property
     def tiled(self) -> bool:
         return self.n_tiles > 1
+
+    @property
+    def n_slabs(self) -> int:
+        """Steps of the kernel's weight-stationary sweep: one per (spatial
+        tile × cin bank × kout bank)."""
+        return self.n_tiles * self.cin_banks * self.kout_banks
+
+    @property
+    def pipelined(self) -> bool:
+        """Run this layer on conv2d_ws_pipe (explicit ping-pong DMA)
+        rather than conv2d_ws: only a sweep of more than one slab has a
+        next slab whose DMA can overlap the current one's compute."""
+        return self.n_slabs > 1
 
     @property
     def halo_read_factor(self) -> float:
@@ -316,8 +326,7 @@ def plan_tiles(h: int, w: int, c: int, k: int, kh: int = 3, kw: int = 3, *,
                groups: int = 1, dilation: int = 1, in_bytes: int = 1,
                acc_bytes: int = 4, out_bytes: Optional[int] = None,
                cin_banks: int = 4, kout_banks: int = 4,
-               vmem_budget: Optional[int] = VMEM_LIMIT_BYTES,
-               kernel: str = "auto", calib=None) -> TilePlan:
+               vmem_budget: Optional[int] = VMEM_LIMIT_BYTES) -> TilePlan:
     """Jointly choose (h_tile, cin_banks, kout_banks) so the laid-out
     per-grid-step working set fits ``vmem_budget``.
 
@@ -347,24 +356,11 @@ def plan_tiles(h: int, w: int, c: int, k: int, kh: int = 3, kw: int = 3, *,
     ``out_bytes`` is the epilogue output element size (1 when the fused
     requantize writes int8; defaults to ``acc_bytes``).
 
-    ``kernel`` selects the conv kernel variant the plan will run on:
-    ``"sequential"`` (conv2d_ws), ``"pipelined"`` (conv2d_ws_pipe, the
-    explicit ping-pong DMA kernel), or ``"auto"`` — consult
-    ``perfmodel.pipeline_estimate`` and set ``TilePlan.pipelined`` only
-    where the overlap model says it wins (tiny layers lose to the
-    per-slab protocol overhead and stay sequential).  The choice never
-    affects VMEM fitting: both variants hold the same two buffered
-    copies of each block (see ``working_set_bytes``).
-
-    ``calib`` (a ``core.calibration.CalibrationTable``) makes the
-    ``kernel="auto"`` crossover consult the MEASUREMENT-calibrated model
-    instead of the analytic one; the tile/bank descent itself is VMEM
-    geometry and does not depend on it.  ``core/autotune.py`` supersedes
-    this greedy descent with a full search of the candidate space — this
-    function remains the fallback when no tuner/table is present."""
-    if kernel not in ("auto", "pipelined", "sequential"):
-        raise ValueError(f"kernel must be auto|pipelined|sequential, "
-                         f"got {kernel!r}")
+    The kernel variant follows from the plan (``TilePlan.pipelined``)
+    and never affects VMEM fitting: both variants hold the same two
+    buffered copies of each block (see ``working_set_bytes``).
+    ``core/autotune.py`` searches the candidate space this greedy
+    descent walks."""
     cgrp = c // groups
     # the requested banking degrades to the largest legal counts: divisors
     # of the channel slices, kout banks on group boundaries, lane-legal
@@ -401,22 +397,10 @@ def plan_tiles(h: int, w: int, c: int, k: int, kh: int = 3, kw: int = 3, *,
                          in_bytes=in_bytes, acc_bytes=acc_bytes,
                          out_bytes=out_bytes, budget=budget)
 
-    def choose_kernel(plan: TilePlan) -> TilePlan:
-        if kernel == "sequential":
-            return plan
-        if kernel == "pipelined":
-            return replace(plan, pipelined=True)
-        from repro.core import perfmodel
-        psums = perfmodel.psum_count(h, w, c, k, kh, kw, stride=stride,
-                                     padding=padding, groups=groups,
-                                     dilation=dilation)
-        est = perfmodel.pipeline_estimate(plan, psums, calib=calib)
-        return replace(plan, pipelined=est["profitable"])
-
     state = (oh, cin_banks, kout_banks)
     plan = build(*state)
     if vmem_budget is None:
-        return choose_kernel(plan)
+        return plan
     min_tile = 2 if pool else 1
     while not plan.fits_vmem:
         th, cbn, kbn = state
@@ -434,10 +418,10 @@ def plan_tiles(h: int, w: int, c: int, k: int, kh: int = 3, kw: int = 3, *,
                       if p.working_set_bytes < plan.working_set_bytes]
         if not candidates:
             # nothing shrinks further: best effort
-            return choose_kernel(plan)
+            return plan
         plan, state = min(candidates,
                           key=lambda pm: pm[0].working_set_bytes)
-    return choose_kernel(plan)
+    return plan
 
 
 def divisor_banks(dim: int, want: int) -> int:

@@ -195,13 +195,6 @@ class ConvCoreConfig:
     wrap8: bool = False           # bit-faithful 8-bit psum wrap (Fig. 6)
     auto_bank: bool = True        # fit spatial tiles + banks to VMEM
     vmem_budget: int = banking.VMEM_LIMIT_BYTES   # per-core VMEM target
-    kernel: str = "auto"          # conv variant per layer: "auto" lets the
-                                  # perfmodel crossover predictor choose
-                                  # conv2d_ws_pipe vs conv2d_ws;
-                                  # "pipelined"/"sequential" force one
-    calib: Optional[object] = None  # core.calibration.CalibrationTable:
-                                  # measured model terms for the planner's
-                                  # crossover; None → analytic §5.2 model
 
 
 class ConvCore:
@@ -230,8 +223,7 @@ class ConvCore:
             groups=groups, in_bytes=in_bytes, acc_bytes=4,
             out_bytes=out_bytes, cin_banks=cfg.cin_banks,
             kout_banks=cfg.kout_banks,
-            vmem_budget=cfg.vmem_budget if cfg.auto_bank else None,
-            kernel=cfg.kernel, calib=cfg.calib)
+            vmem_budget=cfg.vmem_budget if cfg.auto_bank else None)
 
     def apply_layer(self, x: jax.Array, w: jax.Array,
                     bias: Optional[jax.Array] = None,

@@ -473,20 +473,14 @@ class NetworkPlan:
 
     def tile_plans(self, cin_banks: int = 4, kout_banks: int = 4,
                    in_bytes: int = 1,
-                   vmem_budget: Optional[int] = banking.VMEM_LIMIT_BYTES,
-                   kernel: str = "auto", calib=None
+                   vmem_budget: Optional[int] = banking.VMEM_LIMIT_BYTES
                    ) -> List[Optional[banking.TilePlan]]:
         """Per-node spatial-tile × channel-bank plans (None for nodes
         without a conv).  int8-datapath sizes by default; the final
         parametric layer (no fused requantize) keeps a 4-byte epilogue
         output, every other conv writes int8.  ``vmem_budget=None``
-        disables fitting (whole-map tiles — the seed dataflow).
-        ``kernel`` picks the conv variant per layer ("auto" → the
-        perfmodel crossover predictor sets ``TilePlan.pipelined`` where
-        the explicit DMA pipeline wins; see banking.plan_tiles).
-        ``calib`` (a core.calibration.CalibrationTable) prices the
-        crossover under measured terms instead of the analytic defaults;
-        core/autotune.py searches the full plan space against it.
+        disables fitting (whole-map tiles — the seed dataflow).  Each
+        plan carries its conv kernel variant (``TilePlan.pipelined``).
 
         Transposed convs are planned on their stride-1 EQUIVALENT conv
         (the zero-inserted map + clipped equivalence pads —
@@ -517,7 +511,7 @@ class NetworkPlan:
                 dilation=sp.dilation, in_bytes=in_bytes,
                 out_bytes=4 if i == last_param else in_bytes,
                 cin_banks=cin_banks, kout_banks=kout_banks,
-                vmem_budget=vmem_budget, kernel=kernel, calib=calib))
+                vmem_budget=vmem_budget))
         return plans
 
     def conv_geometries(self) -> List[Optional[Tuple[int, int]]]:
@@ -547,23 +541,19 @@ class NetworkPlan:
 
     def perf_report(self, cfg: perfmodel.IPCoreConfig =
                     perfmodel.IPCoreConfig(),
-                    tile_plans: Optional[Sequence] = None,
-                    calib=None) -> dict:
+                    tile_plans: Optional[Sequence] = None) -> dict:
         """The §5.2 cycle model summed over the network, including the
         20-core full-board configuration (perfmodel.network_report).
         With ``tile_plans`` (e.g. from :meth:`tile_plans`) the model also
         prices tile revisits and halo re-reads against the DMA interface,
         keeping large-map GOPS honest.  DAG branches serialize on the
-        single core, so the sum over nodes is the schedule length.
-        ``calib`` applies a measured CalibrationTable to every term;
-        omitted, the report is bit-identical to the analytic model."""
+        single core, so the sum over nodes is the schedule length."""
         return perfmodel.network_report(self.psum_table(), cfg,
-                                        tile_plans=tile_plans, calib=calib)
+                                        tile_plans=tile_plans)
 
     def train_report(self, cfg: perfmodel.IPCoreConfig =
                      perfmodel.IPCoreConfig(),
-                     tile_plans: Optional[Sequence] = None,
-                     calib=None) -> dict:
+                     tile_plans: Optional[Sequence] = None) -> dict:
         """The §5.2 cycle model of one TRAINING step over this plan:
         forward + backward ≈ 3× the forward psums (input-gradient
         transposed conv + weight-gradient correlation each match the
@@ -575,7 +565,7 @@ class NetworkPlan:
                   for shp in self.param_shapes()]
         return perfmodel.train_report(self.psum_table(), cfg,
                                       weight_bytes=wbytes,
-                                      tile_plans=tile_plans, calib=calib)
+                                      tile_plans=tile_plans)
 
     # -- execution ----------------------------------------------------------
 
@@ -661,9 +651,7 @@ def program_tile_plans(plan: NetworkPlan, core_config) -> List:
         cin_banks=core_config.cin_banks,
         kout_banks=core_config.kout_banks, in_bytes=1,
         vmem_budget=(core_config.vmem_budget if core_config.auto_bank
-                     else None),
-        kernel=getattr(core_config, "kernel", "auto"),
-        calib=getattr(core_config, "calib", None))
+                     else None))
 
 
 @dataclass(frozen=True)

@@ -11,16 +11,12 @@ The paper counts one psum (a 3×3×1 weighted sum) as one "operation"; we
 also report standard MAC-ops (1 psum = KH·KW MACs = 2·KH·KW flops) so the
 numbers are comparable with TPU rooflines (DESIGN.md §3).
 
-Calibration layer (core/calibration.py): every cost entry point below
-takes an optional ``calib=`` — a ``CalibrationTable`` of fitted
-correction factors (compute-overhead factor, effective DMA bytes/cycle,
-per-slab pipeline overhead) from measured microbenchmarks
-(benchmarks/calibrate.py).  The contract is strict separation: with
-``calib=None`` (the default) every function below is bit-identical to
-the uncalibrated analytic model, so the paper anchors (0.224 / 4.48
-GOPS) stay exact — CI asserts this with a fitted table loaded.  The
-table is duck-typed here (attributes, not an import) so perfmodel never
-depends on the calibration layer it feeds.
+``pipeline_estimate`` and ``network_report`` price a layer pass under
+a ``banking.TilePlan`` on the same FPGA terms (8 DMA bytes per cycle, a
+16-cycle per-slab ping-pong cost).  They are the paper-anchor reference
+and the model ``core/autotune.py`` prices candidates with; which TPU
+kernel a layer runs is the plan's own property (``TilePlan.pipelined``),
+not a verdict of this model.
 """
 
 from __future__ import annotations
@@ -164,59 +160,21 @@ def tile_traffic(plan) -> dict:
             "kout_revisits": plan.kout_banks}
 
 
-def dma_cycles(total_bytes: int, cfg: IPCoreConfig = IPCoreConfig(),
-               calib=None) -> int:
-    """DMA cycles for ``total_bytes`` on the shared interface.  A
-    ``calib`` table with a fitted ``dma_bytes_per_cycle`` overrides the
-    config's analytic bandwidth (None keeps it — and ``calib=None`` is
-    bit-identical to the uncalibrated model)."""
-    bpc = cfg.dma_bytes_per_cycle
-    if calib is not None and getattr(calib, "dma_bytes_per_cycle", None):
-        bpc = calib.dma_bytes_per_cycle
-    return math.ceil(total_bytes / max(bpc, 1e-9))
+def dma_cycles(total_bytes: int,
+               cfg: IPCoreConfig = IPCoreConfig()) -> int:
+    """DMA cycles for ``total_bytes`` on the shared interface."""
+    return math.ceil(total_bytes / max(cfg.dma_bytes_per_cycle, 1e-9))
 
 
 # Per-slab cost of the explicit ping-pong protocol (descriptor setup,
-# semaphore wait, buffer swap) — the reason tiny layers stay sequential:
-# when the overlappable work per slab is smaller than the per-slab
-# bookkeeping, the steady-state overlap never amortizes it.  This module
-# constant is the NO-TABLE default: a fitted ``CalibrationTable`` carries
-# its own ``pipeline_overhead_cycles`` (measured, not assumed), and the
-# crossover predictor uses that value whenever a table is passed.
+# semaphore wait, buffer swap): when the overlappable work per slab is
+# smaller than this bookkeeping, the steady-state overlap never
+# amortizes it.
 PIPELINE_OVERHEAD_CYCLES = 16
 
 
-def pipeline_overhead_cycles(calib=None) -> float:
-    """The per-slab protocol cost the crossover predictor charges: the
-    fitted table's value when one is loaded, the 16-cycle analytic
-    constant otherwise (CI pins the constant)."""
-    if calib is None:
-        return PIPELINE_OVERHEAD_CYCLES
-    return float(getattr(calib, "pipeline_overhead_cycles",
-                         PIPELINE_OVERHEAD_CYCLES))
-
-
-def calibrated_cycles(n_psums: int, cfg: IPCoreConfig = IPCoreConfig(),
-                      calib=None) -> int:
-    """Compute cycles with the fitted compute-overhead factor applied
-    (the exemplar's measured ``overhead_factor`` idiom).  ``calib=None``
-    returns ``cycles`` unchanged — bit-identical, not approximately."""
-    base = cycles(n_psums, cfg)
-    if calib is None:
-        return base
-    return math.ceil(base * float(getattr(calib, "compute_factor", 1.0)))
-
-
-def pipeline_slabs(plan) -> int:
-    """Number of (spatial tile × kout bank × cin bank) slabs one layer
-    pass streams through the ping-pong buffers — the weight-stationary
-    sweep order of both conv kernels."""
-    return plan.n_tiles * plan.kout_banks * plan.cin_banks
-
-
 def pipeline_estimate(plan, psums: int,
-                      cfg: IPCoreConfig = IPCoreConfig(),
-                      calib=None) -> dict:
+                      cfg: IPCoreConfig = IPCoreConfig()) -> dict:
     """Sequential-vs-pipelined cost of one layer pass under ``plan``.
 
     * sequential (``conv2d_ws`` without overlap credit):
@@ -226,29 +184,17 @@ def pipeline_estimate(plan, psums: int,
       costlier one, the last slab's compute drains →
       fill + (n−1)·max(d, c) + drain, plus per-slab protocol overhead,
 
-    with d = ⌈D/n⌉, c = ⌈C/n⌉ the per-slab shares.  Priced entirely on
-    the §5.2 cycle model (``cycles``) and the ``tile_traffic`` /
-    ``dma_cycles`` machinery — the paper anchors are untouched.  The
-    ``profitable`` verdict is what ``banking.plan_tiles(kernel="auto")``
-    uses to set ``TilePlan.pipelined`` per layer.
-
-    ``calib`` applies the fitted corrections (compute-overhead factor,
-    effective DMA bandwidth, measured per-slab overhead) to every term —
-    the crossover can flip when measurement disagrees with the analytic
-    assumptions; ``calib=None`` is bit-identical to the uncalibrated
-    estimate."""
-    n = max(pipeline_slabs(plan), 1)
-    dma = dma_cycles(tile_traffic(plan)["total_bytes"], cfg, calib)
-    compute = calibrated_cycles(psums, cfg, calib) if psums else 0
-    # fitted fixed per-layer-pass cost (kernel dispatch): identical for
-    # both variants and every candidate plan of a layer, so it keeps
-    # totals honest without ever changing a verdict; 0 with no table
-    base = 0 if calib is None else math.ceil(
-        float(getattr(calib, "per_call_overhead_cycles", 0.0)))
+    with n = ``plan.n_slabs`` and d = ⌈D/n⌉, c = ⌈C/n⌉ the per-slab
+    shares.  Priced entirely on the §5.2 cycle model (``cycles``) and the
+    ``tile_traffic`` / ``dma_cycles`` machinery — the paper anchors are
+    untouched.  ``cycles`` is the price of the variant the plan runs
+    (``TilePlan.pipelined``)."""
+    n = max(plan.n_slabs, 1)
+    dma = dma_cycles(tile_traffic(plan)["total_bytes"], cfg)
+    compute = cycles(psums, cfg) if psums else 0
     d, c = -(-dma // n), -(-compute // n)
-    sequential = dma + compute + base
-    pipelined = d + (n - 1) * max(d, c) + c + base \
-        + math.ceil(n * pipeline_overhead_cycles(calib))
+    sequential = dma + compute
+    pipelined = d + (n - 1) * max(d, c) + c + n * PIPELINE_OVERHEAD_CYCLES
     return {
         "n_slabs": n,
         "dma_cycles": dma,
@@ -256,15 +202,14 @@ def pipeline_estimate(plan, psums: int,
         "sequential_cycles": sequential,
         "pipelined_cycles": pipelined,
         "speedup": sequential / pipelined if pipelined else 1.0,
-        "profitable": pipelined < sequential,
+        "cycles": pipelined if plan.pipelined else sequential,
     }
 
 
 def network_report(layers: Sequence[Tuple[str, int]],
                    cfg: IPCoreConfig = IPCoreConfig(),
                    full_board_cores: int = 20,
-                   tile_plans: Optional[Sequence] = None,
-                   calib=None) -> dict:
+                   tile_plans: Optional[Sequence] = None) -> dict:
     """Per-layer + total cycles/seconds/GOPS for a layer list
     [(name, psums_per_image), ...], for ``cfg`` and for the paper's
     full-board configuration (ip_cores=20, batch-sharded replication).
@@ -276,50 +221,41 @@ def network_report(layers: Sequence[Tuple[str, int]],
     per slab, pipelined overlaps them through the ping-pong buffers —
     with tile revisits and halo re-reads priced by ``tile_traffic``.
     Priced rows carry both variants (``cycles_sequential`` /
-    ``cycles_pipelined`` / ``pipeline_speedup``) so the crossover is
-    auditable per layer.  The DMA interface is SHARED across
+    ``cycles_pipelined`` / ``pipeline_speedup``) so the two variants
+    compare per layer.  The DMA interface is SHARED across
     replicated IP cores, so full-board cycles floor at the same DMA time:
     that is what keeps the 20-core GOPS honest on large maps.  Each
     priced row carries ``dma_bound`` / ``dma_bound_board`` flags — on
     depthwise/grouped layers the psum count collapses by the group factor
     while the feature-map traffic stays put, so the shared-DMA floor, not
     compute, is what binds (visibly so on the full board, where compute
-    divides by the core count and the DMA interface does not).
-
-    ``calib`` prices every row under the fitted corrections
-    (core/calibration.py); ``calib=None`` keeps the analytic model
-    bit-identical."""
+    divides by the core count and the DMA interface does not)."""
     board = replace(cfg, ip_cores=full_board_cores)
     if tile_plans is None:
         tile_plans = [None] * len(layers)
     per_layer: List[dict] = []
     total = total_board = 0
     for (name, p), tp in zip(layers, tile_plans):
-        compute = calibrated_cycles(p, cfg, calib) if p else 0
-        compute_board = calibrated_cycles(p, board, calib) if p else 0
+        compute = cycles(p, cfg) if p else 0
+        compute_board = cycles(p, board) if p else 0
         row = {"name": name, "psums": p, "cycles": compute}
         if tp is not None:
             traffic = tile_traffic(tp)
-            dma = dma_cycles(traffic["total_bytes"], cfg, calib)
-            pipelined = bool(getattr(tp, "pipelined", False))
-            est = pipeline_estimate(tp, p, cfg, calib)
-            est_board = pipeline_estimate(tp, p, board, calib)
-            chosen = est["pipelined_cycles" if pipelined
-                         else "sequential_cycles"]
-            chosen_board = est_board["pipelined_cycles" if pipelined
-                                     else "sequential_cycles"]
+            dma = dma_cycles(traffic["total_bytes"], cfg)
+            est = pipeline_estimate(tp, p, cfg)
             row.update(dma_bytes=traffic["total_bytes"], dma_cycles=dma,
                        halo_read_factor=traffic["halo_read_factor"],
                        n_tiles=tp.n_tiles,
-                       cycles=chosen if p else dma,
-                       pipelined=pipelined,
+                       cycles=est["cycles"] if p else dma,
+                       pipelined=tp.pipelined,
                        cycles_sequential=est["sequential_cycles"],
                        cycles_pipelined=est["pipelined_cycles"],
                        pipeline_speedup=est["speedup"],
                        dma_bound=dma >= compute,
                        dma_bound_board=dma >= compute_board)
             total += row["cycles"]
-            total_board += chosen_board if p else dma
+            total_board += (pipeline_estimate(tp, p, board)["cycles"]
+                            if p else dma)
         else:
             total += compute
             total_board += compute_board
@@ -353,8 +289,7 @@ def train_report(layers: Sequence[Tuple[str, int]],
                  cfg: IPCoreConfig = IPCoreConfig(),
                  weight_bytes: Optional[Sequence[int]] = None,
                  full_board_cores: int = 20,
-                 tile_plans: Optional[Sequence] = None,
-                 calib=None) -> dict:
+                 tile_plans: Optional[Sequence] = None) -> dict:
     """§5.2 cycle model of one TRAINING step over a layer list
     [(name, forward_psums_per_image), ...].
 
@@ -384,18 +319,18 @@ def train_report(layers: Sequence[Tuple[str, int]],
     revisit the same tiles, which the 2× psum accounting already covers
     at compute level."""
     fwd = network_report(layers, cfg, full_board_cores=full_board_cores,
-                         tile_plans=tile_plans, calib=calib)
+                         tile_plans=tile_plans)
     board = replace(cfg, ip_cores=full_board_cores)
     if weight_bytes is None:
         weight_bytes = [None] * len(layers)
     bwd_rows: List[dict] = []
     bwd_total = bwd_board = 0
     for (name, p), wb in zip(layers, weight_bytes):
-        compute = calibrated_cycles(2 * p, cfg, calib) if p else 0
-        compute_board = calibrated_cycles(2 * p, board, calib) if p else 0
+        compute = cycles(2 * p, cfg) if p else 0
+        compute_board = cycles(2 * p, board) if p else 0
         row = {"name": name, "psums_bwd": 2 * p, "cycles": compute}
         if wb:
-            dma = dma_cycles(wb, cfg, calib)
+            dma = dma_cycles(wb, cfg)
             row.update(dw_bytes=wb, dw_dma_cycles=dma,
                        cycles=max(compute, dma))
             bwd_total += row["cycles"]

@@ -268,7 +268,7 @@ class MultiCoreScheduler:
     @classmethod
     def from_tune(cls, tune) -> "MultiCoreScheduler":
         """Scheduler for an autotuned network plan: the (scheduler mode ×
-        core count) the search priced cheapest under the calibrated
+        core count) the search priced cheapest under the §5.2 cost
         model (see core/autotune.autotune_network)."""
         return cls(SchedulerConfig.for_tune(tune))
 

@@ -43,9 +43,9 @@ VMEM working set: 2·input + 2·weight + 2·output blocks plus the compute
 body's scratch (accumulator, and the tap patch where the taps fold) — the
 laid-out bytes ``banking.TilePlan.working_set_bytes`` already budgets (the
 implicit pipeline double-buffers the same blocks), so any plan that fits
-the sequential kernel fits this one.  ``banking.plan_tiles(kernel="auto")`` consults
-``perfmodel.pipeline_estimate`` to choose per layer; the backend
-dispatches on ``TilePlan.pipelined``.
+the sequential kernel fits this one.  A layer runs here when its plan
+sweeps more than one slab (``TilePlan.pipelined``: one slab has no next
+slab to prefetch); the backend dispatches on that property.
 
 Interpret-mode note: ``make_async_copy`` executes eagerly under
 ``interpret=True`` (the DMA completes at ``start()``), so CPU validation
@@ -168,8 +168,8 @@ def conv2d_ws_pipe(x, w, bias=None, out_scale=None, *, stride: int = 1,
                    dilation: int = 1, interpret: bool = False):
     """Drop-in replacement for ``conv2d_ws`` with explicit double-buffered
     DMA (see the module docstring).  Same signature, same contracts, same
-    results bit-for-bit; ``banking.plan_tiles`` decides per layer which
-    variant a compiled network runs (``TilePlan.pipelined``)."""
+    results bit-for-bit; a layer's tile plan decides which variant a
+    compiled network runs (``TilePlan.pipelined``)."""
     k = w.shape[3]
     x, w, g = setup_conv(x, w, stride=stride, padding=padding,
                          groups=groups, cin_banks=cin_banks,

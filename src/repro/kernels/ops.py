@@ -221,8 +221,8 @@ def conv2d(x, w, bias=None, *, stride: int = 1, padding="VALID",
     ``pipelined=True`` routes the layer through ``conv2d_ws_pipe`` (the
     explicit double-buffered manual-DMA kernel) instead of ``conv2d_ws``
     — bit-exact on every path, so this is purely a performance choice;
-    ``banking.plan_tiles(kernel="auto")`` makes it per layer and the
-    backends forward ``TilePlan.pipelined`` here.
+    a layer's tile plan makes it (``TilePlan.pipelined``: more than one
+    slab) and the backends forward it here.
     """
     if wrap8 and out_scale is not None:
         raise ValueError("wrap8 and out_scale are mutually exclusive: the "

@@ -15,7 +15,7 @@ One import surface for everything observable in the runtime:
 * ``obs.watch_compiles()`` — counts every jit cache miss in
   ``obs.metrics``' ``jax.compiles`` counter;
 * ``obs.profile.profile_network`` — per-layer wall time / psums /
-  achieved GOPS / calibrated-model prediction over any compiled
+  achieved GOPS / §5.2 cost-model prediction over any compiled
   ``NetworkPlan`` program, plus the drift detector (obs/profile.py),
   both called explicitly, offline.
 

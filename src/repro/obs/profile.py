@@ -1,11 +1,5 @@
 """Per-layer profiler + model-drift detection.
 
-PR 7 made the cost model *calibrated* (benchmarks/calibrate.py fits a
-``CalibrationTable`` onto the §5.2 terms) but only compared it against
-reality inside offline benchmark scripts (``network_bench``'s
-``measured_vs_predicted`` section).  This module makes that comparison a
-*runtime* capability:
-
 * :func:`profile_network` runs a quantized ``NetworkPlan`` program
   layer-at-a-time through the SAME int8 node semantics the compiled
   program executes (``network.int8_forward`` with a node hook — the
@@ -13,18 +7,15 @@ reality inside offline benchmark scripts (``network_bench``'s
   (§4.2), so the walk is the hardware schedule, not an approximation),
   wall-clocking each node with monotonic clocks and emitting one
   :class:`LayerProfile` per node: wall µs, psums, achieved GOPS (the
-  paper's psums/second accounting), and the cost model's predicted µs —
-  calibrated when a table is passed, analytic otherwise.
+  paper's psums/second accounting), and the §5.2 cost model's predicted
+  µs for the layer's plan and kernel variant.
 
 * :class:`DriftDetector` flags layers whose measured/predicted ratio
-  leaves a configurable band — the live version of the offline
-  ``measured_vs_predicted`` check.  A drifting layer means the
-  calibration no longer describes the machine (thermal throttling, a
-  toolchain change, a mis-fitted table) and the autotuner's verdicts
-  are stale: re-run benchmarks/calibrate.py.  Events also land in
-  ``obs.metrics`` (counter ``obs.drift.events``) and as instant marks
-  in the trace, so a Perfetto view shows *where* the model lost the
-  machine.
+  leaves a configurable band.  The prediction is the analytic FPGA
+  time, so the ratio says how far a machine sits from the modelled IP
+  core, layer by layer.  Events also land in ``obs.metrics`` (counter
+  ``obs.drift.events``) and as instant marks in the trace, so a
+  Perfetto view shows *where* the model lost the machine.
 
 Profiling imports jax lazily and is only ever called explicitly, offline:
 an eager layer walk compiles op by op, so it never runs on a serving
@@ -39,10 +30,8 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 from repro import obs
 
-# measured/predicted inside [lo, hi] is "calibration holds"; outside is
-# drift.  The default band is generous (2× each way) because even a
-# fitted table carries per-layer error — the offline fit reports mean
-# |error|, not worst-case.
+# measured/predicted inside [lo, hi] is "the model holds"; outside is
+# drift.  The default band is generous: 2× each way.
 DEFAULT_DRIFT_BAND = (0.5, 2.0)
 
 
@@ -58,7 +47,6 @@ class LayerProfile:
     gops: float                        # achieved, psums·batch / wall / 1e9
     predicted_us: Optional[float]      # None: the model prices it free
     pipelined: Optional[bool]          # conv nodes: kernel variant
-    calibrated: bool
 
     @property
     def ratio(self) -> Optional[float]:
@@ -73,7 +61,7 @@ class LayerProfile:
                 "wall_us": self.wall_us, "psums": self.psums,
                 "batch": self.batch, "gops": self.gops,
                 "predicted_us": self.predicted_us, "ratio": self.ratio,
-                "pipelined": self.pipelined, "calibrated": self.calibrated}
+                "pipelined": self.pipelined}
 
 
 @dataclass(frozen=True)
@@ -97,7 +85,6 @@ class NetworkProfile:
     network: str
     batch: int
     records: Tuple[LayerProfile, ...]
-    calibrated: bool
     drift: Tuple[DriftEvent, ...] = ()
 
     @property
@@ -110,7 +97,6 @@ class NetworkProfile:
 
     def to_dict(self) -> Dict[str, Any]:
         return {"network": self.network, "batch": self.batch,
-                "calibrated": self.calibrated,
                 "total_wall_us": self.total_wall_us,
                 "layers": [r.to_dict() for r in self.records],
                 "drift": [d.to_dict() for d in self.drift]}
@@ -153,31 +139,25 @@ class DriftDetector:
         return events
 
 
-def _predicted_us(sp_kind: str, psums: int, tile_plan, calib,
-                  cfg) -> Optional[float]:
+def _predicted_us(psums: int, tile_plan, cfg) -> Optional[float]:
     """The cost model's wall-time prediction for one node, priced exactly
-    the way the planner/autotuner price it (perfmodel.pipeline_estimate
-    for planned convs, calibrated compute cycles for GEMMs); None for
-    nodes the model prices free (merges, pools, flatten — the fused
-    epilogue / output-BRAM crossbar absorb them)."""
+    the way ``network_report`` prices it (``perfmodel.pipeline_estimate``
+    for the kernel variant a planned conv runs, compute cycles for
+    GEMMs); None for nodes the model prices free (merges, pools, flatten
+    — the fused epilogue / output-BRAM crossbar absorb them)."""
     from repro.core import perfmodel
-    clock = float(getattr(calib, "clock_hz", None) or cfg.clock_hz)
     if tile_plan is not None:
-        est = perfmodel.pipeline_estimate(tile_plan, psums, cfg, calib)
-        cyc = est["pipelined_cycles" if tile_plan.pipelined
-                  else "sequential_cycles"]
-        return cyc / clock * 1e6
-    if not psums:
+        cyc = perfmodel.pipeline_estimate(tile_plan, psums, cfg)["cycles"]
+    elif psums:
+        cyc = perfmodel.cycles(psums, cfg)
+    else:
         return None
-    cyc = perfmodel.calibrated_cycles(psums, cfg, calib)
-    if calib is not None:
-        cyc += float(getattr(calib, "per_call_overhead_cycles", 0.0))
-    return cyc / clock * 1e6
+    return cyc / cfg.clock_hz * 1e6
 
 
 def profile_network(qnet, x, *, core_config=None,
                     tile_plans: Optional[Sequence] = None,
-                    calib=None, warmup: int = 1,
+                    warmup: int = 1,
                     drift: Optional[DriftDetector] = None,
                     perf_cfg=None) -> NetworkProfile:
     """Profile one int8 forward pass layer-at-a-time.
@@ -189,13 +169,10 @@ def profile_network(qnet, x, *, core_config=None,
     (one record per node, asserted in tests).  Each node gets a
     ``layer:<name>`` span in the trace when obs is enabled.
 
-    ``calib`` (a core.calibration.CalibrationTable) prices the predicted
-    column under the fitted terms — measured and predicted then share a
-    scale through the fitted ``clock_hz`` and the measured/predicted
-    ratio is meaningful; without a table the predicted column is the
-    analytic §5.2 FPGA time (a cross-platform reference, NOT comparable
-    to interpret-mode wall time — pass a ``drift`` detector only with a
-    table).  ``warmup`` extra passes absorb first-call compilation.
+    The predicted column is the analytic §5.2 FPGA time at
+    ``perf_cfg``'s clock (a cross-platform reference, not a prediction
+    of interpret-mode wall time).  ``warmup`` extra passes absorb
+    first-call compilation.
 
     Eager per-node dispatch is slower than the fused jitted program —
     profiling is a diagnostic mode, never the serving path."""
@@ -240,15 +217,14 @@ def profile_network(qnet, x, *, core_config=None,
         psums = psum_rows[names[i]]
         t0, t1 = intervals[i]
         wall = (t1 - t0) / 1e3
-        pred = _predicted_us(sp.kind, psums, tile_plans[i], calib, cfg)
+        pred = _predicted_us(psums, tile_plans[i], cfg)
         rec = LayerProfile(
             index=i, name=names[i], kind=sp.kind, wall_us=wall,
             psums=psums, batch=batch,
             gops=(psums * batch) / (wall * 1e-6) / 1e9 if wall > 0 else 0.0,
             predicted_us=pred,
             pipelined=(bool(tile_plans[i].pipelined)
-                       if tile_plans[i] is not None else None),
-            calibrated=calib is not None)
+                       if tile_plans[i] is not None else None))
         records.append(rec)
         if obs.enabled():
             # the measured walk as trace events with their REAL intervals
@@ -264,5 +240,4 @@ def profile_network(qnet, x, *, core_config=None,
     if drift is not None:
         events = tuple(drift.check(records))
     return NetworkProfile(network=plan.name, batch=batch,
-                          records=tuple(records), calibrated=calib is not None,
-                          drift=events)
+                          records=tuple(records), drift=events)

@@ -40,7 +40,7 @@ Three pieces, composable and individually testable:
   and launches (slot reuse across in-flight batches), and results
   materialize (``np.asarray``) only at retirement.  With ``route=True``
   and a per-model ``NetworkTunePlan``, each formed batch is routed
-  through the ``MultiCoreScheduler`` mode the calibrated perf model
+  through the ``MultiCoreScheduler`` mode the §5.2 cost model
   predicts fastest for that *(network, formed-batch-size)* pair
   (``core/autotune.route_batch``) — small deadline-launched batches take
   the single-image kout/spatial modes, full batches take batch sharding.
@@ -312,7 +312,7 @@ class ContinuousBatchingEngine:
     def __init__(self, *, batch: int = 8, n_cores: int = 1,
                  backend: str = "pallas", deadline_ms: float = 5.0,
                  bulk_aging_ms: float = 50.0, cache_capacity: int = 4,
-                 max_inflight: int = 2, calib=None, route: bool = False,
+                 max_inflight: int = 2, route: bool = False,
                  clock: Callable[[], int] = time.perf_counter_ns):
         if batch < 1:
             raise ValueError(f"batch must be >= 1, got {batch}")
@@ -322,7 +322,6 @@ class ContinuousBatchingEngine:
         self.batch = batch
         self.n_cores = n_cores
         self.backend = backend
-        self.calib = calib
         self.route = route
         self.clock = clock
         self.metrics = obs.MetricsRegistry()
@@ -429,8 +428,7 @@ class ContinuousBatchingEngine:
         from repro.core.network import make_int8_program, program_tile_plans
 
         def build():
-            cfg = ConvCoreConfig(backend=backend_name, int8=True,
-                                 calib=self.calib)
+            cfg = ConvCoreConfig(backend=backend_name, int8=True)
             with obs.span("engine.compile", network=entry.qnet.plan.name,
                           model=entry.name, backend=backend_name,
                           batch=self.batch):
@@ -573,8 +571,7 @@ class ContinuousBatchingEngine:
                                               SchedulerConfig)
             budget = self.n_cores if self.n_cores > 1 \
                 else max(entry.tune.n_cores, 1)
-            mode, cores, _ = route_batch(entry.tune.layers, n_real,
-                                         budget, calib=self.calib)
+            mode, cores, _ = route_batch(entry.tune.layers, n_real, budget)
             sched = MultiCoreScheduler(
                 SchedulerConfig(n_cores=cores, mode=mode))
             bname = self._shard_backend_name(sched)

@@ -155,7 +155,7 @@ class ConvNetEngine:
     verdict replaces ``n_cores`` — kout/spatial verdicts compile the
     program against the matching sharded backend, batch verdicts shard
     the formed batches.  ``route=True`` additionally re-routes each
-    *formed* batch through the scheduler mode the calibrated perf model
+    *formed* batch through the scheduler mode the §5.2 cost model
     predicts fastest for its actual size (``autotune.route_batch``).
 
     Telemetry: counters (requests / batches / padded), the honest
@@ -170,7 +170,7 @@ class ConvNetEngine:
     traces, beside the device's operations."""
 
     def __init__(self, qnet, *, batch: int = 8, n_cores: int = 1,
-                 backend: str = "pallas", tune=None, calib=None,
+                 backend: str = "pallas", tune=None,
                  deadline_ms: float = 5.0,
                  bulk_aging_ms: float = 50.0, max_inflight: int = 2,
                  route: bool = False):
@@ -179,12 +179,10 @@ class ConvNetEngine:
         self.batch = batch
         self.input_shape = qnet.plan.input_shape
         self.tune = tune
-        self.calib = calib
         self.engine = ContinuousBatchingEngine(
             batch=batch, n_cores=n_cores, backend=backend,
             deadline_ms=deadline_ms, bulk_aging_ms=bulk_aging_ms,
-            cache_capacity=4, max_inflight=max_inflight, calib=calib,
-            route=route)
+            cache_capacity=4, max_inflight=max_inflight, route=route)
         self.model = self.engine.add_model(qnet, tune=tune)
 
     @property

@@ -10,7 +10,7 @@ the repo's established guarantees on the new workload class:
 * QAT round trip (train the float shadow → quantize_network →
   make_int8_program) holds per-pixel accuracy within the established 2%;
 * the §5.2 paper anchors (0.224 / 4.48 GOPS, 3,154,176 psums) remain
-  exact with ``calib=None`` — dense prediction is additive, not a drift;
+  exact — dense prediction is additive, not a drift;
 * the transposed-conv psum pricing exposes both the naive (~stride²×)
   and the zero-skipping MAC counts;
 * over-dilated layers fail loudly in ``plan_tiles`` (the satellite
@@ -88,13 +88,15 @@ def test_zoo_bit_exact_all_scheduler_modes(make, mode):
 
 @pytest.mark.parametrize("mode", ["batch", "kout", "spatial"])
 @pytest.mark.parametrize("make", ZOO)
-def test_zoo_pipelined_kernel_bit_exact(make, mode):
+def test_zoo_pipelined_kernel_bit_exact(make, mode, monkeypatch):
     """The forced-pipelined compile (every conv, transposed ones via the
     eq-conv lowering included, on conv2d_ws_pipe) is bit-identical to the
-    sequential compile under every scheduler mode."""
+    forced-sequential compile under every scheduler mode."""
     plan, params, xf, qnet = _setup(make)
     outs = []
-    for kernel in ("sequential", "pipelined"):
+    for forced in (False, True):
+        monkeypatch.setattr(banking.TilePlan, "pipelined",
+                            property(lambda self, v=forced: v))
         sched = scheduler.MultiCoreScheduler(
             scheduler.SchedulerConfig(n_cores=2, mode=mode))
         name = "pallas"
@@ -103,7 +105,7 @@ def test_zoo_pipelined_kernel_bit_exact(make, mode):
             register_backend(sb)
             name = sb.name
         program = network.make_int8_program(
-            qnet, ConvCoreConfig(backend=name, int8=True, kernel=kernel))
+            qnet, ConvCoreConfig(backend=name, int8=True))
         outs.append(sched.run(program, xf))
     np.testing.assert_array_equal(np.asarray(outs[0]), np.asarray(outs[1]))
 

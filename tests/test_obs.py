@@ -14,8 +14,7 @@ import pytest
 
 from repro import obs
 from repro.obs.metrics import Histogram, MetricsRegistry, default_buckets
-from repro.obs.profile import (DEFAULT_DRIFT_BAND, DriftDetector,
-                               LayerProfile, profile_network)
+from repro.obs.profile import DriftDetector, LayerProfile, profile_network
 from repro.obs.trace import NOOP_SPAN, Tracer
 
 
@@ -217,7 +216,6 @@ def test_profile_layer_set_matches_plan_topology():
     plan = qnet.plan
     assert len(prof.records) == len(plan.layers)
     assert prof.layer_names == list(plan.node_names())
-    assert not prof.calibrated
     for i, r in enumerate(prof.records):
         assert r.index == i
         assert r.wall_us > 0
@@ -242,38 +240,35 @@ def test_profile_emits_layer_spans_when_enabled():
 
 
 def test_drift_detector_fires_on_miscalibrated_table():
-    from repro.core.calibration import CalibrationTable
     qnet, x = _lenet_qnet()
-    # an absurd table: claims every compute cycle costs 1e6 real cycles,
-    # so predictions are ~6 orders too slow — every priced layer drifts
-    # below the band (machine much faster than the "calibration")
-    bad = CalibrationTable(compute_factor=1e6, clock_hz=112e6)
-    det = DriftDetector()
-    prof = profile_network(qnet, x, warmup=0, calib=bad, drift=det)
-    assert prof.calibrated
+    # a band no layer reaches (wall time a billion times the analytic
+    # FPGA time), so every priced layer drifts below it
+    band = (1e9, 2e9)
+    det = DriftDetector(band=band)
+    prof = profile_network(qnet, x, warmup=0, drift=det)
     priced = [r for r in prof.records if r.predicted_us]
     assert priced
     assert len(prof.drift) == len(priced)
     for ev in prof.drift:
-        assert ev.ratio < DEFAULT_DRIFT_BAND[0]
-        assert ev.band == DEFAULT_DRIFT_BAND
+        assert ev.ratio < band[0]
+        assert ev.band == band
     assert obs.metrics.counter("obs.drift.events").value == len(prof.drift)
 
 
 def test_drift_detector_band_and_floor():
     rec = LayerProfile(index=0, name="c1", kind="conv", wall_us=100.0,
                        psums=1000, batch=1, gops=0.01, predicted_us=110.0,
-                       pipelined=False, calibrated=True)
+                       pipelined=False)
     assert DriftDetector().check([rec]) == []        # ratio ~0.9: in band
     fast = LayerProfile(index=1, name="c2", kind="conv", wall_us=10.0,
                         psums=1000, batch=1, gops=0.1, predicted_us=110.0,
-                        pipelined=False, calibrated=True)
+                        pipelined=False)
     assert len(DriftDetector().check([fast])) == 1   # ratio ~0.09: drift
     # the noise floor suppresses tiny layers
     assert DriftDetector(min_wall_us=50.0).check([fast]) == []
     free = LayerProfile(index=2, name="pool", kind="maxpool", wall_us=5.0,
                         psums=0, batch=1, gops=0.0, predicted_us=None,
-                        pipelined=None, calibrated=True)
+                        pipelined=None)
     assert DriftDetector().check([free]) == []       # unpriced: no signal
     with pytest.raises(ValueError):
         DriftDetector(band=(2.0, 0.5))

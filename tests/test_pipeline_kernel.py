@@ -6,15 +6,22 @@ and its planner/cost-model contract:
   float accumulator paths, whole networks under every scheduler mode;
 * VMEM accounting: the ping-pong working set IS the working set
   ``plan_tiles`` already budgets (the ×2 double-buffer term), so the
-  ``pipelined`` choice never changes whether a plan fits, and budget
+  kernel choice never changes whether a plan fits, and budget
   degradation still yields legal plans, dense and grouped;
-* the crossover predictor: §5.2 anchors untouched, depthwise
-  ``dma_bound_board`` layers marked profitable, tiny layers left
-  sequential, and ``network_report`` pricing consistent both ways.
+* the slab rule (``TilePlan.pipelined`` ⇔ more than one slab) and the
+  §5.2 model's pricing of it: anchors untouched, depthwise
+  ``dma_bound_board`` layers pipelined, one-slab layers sequential, and
+  ``network_report`` charging each row its plan's kernel;
+* the one-slab layers of ResNet-50 run bit-identically on either kernel.
 
 On a TPU host these tests compile natively (the CI smoke lane);
 elsewhere they run in interpret mode.
 """
+
+import functools
+import json
+import os
+import sys
 
 import jax
 import jax.numpy as jnp
@@ -243,72 +250,62 @@ if HAVE_HYPOTHESIS:
         """The ping-pong working set never exceeds the budget the planner
         promised: ``working_set_bytes`` (whose ×2 term IS the two
         ping-pong slots, counted as laid out in VMEM) fits whenever the
-        plan claims to, the ``pipelined`` flag changes no byte counts,
-        and budget degradation still yields legal plans — dense and
-        grouped."""
+        plan claims to, the kernel is the plan's slab rule, and budget
+        degradation still yields legal plans — dense and grouped."""
         if k % groups:
             k = groups * max(1, k // groups)
         oh, ow = ref.conv_out_shape(h, w, 3, 3, 1, "SAME")
         if pool and (oh < 2 or ow < 2):
             pool = False
         cb, kb = banking.grouped_banks(c, k, groups)
-        plans = {
-            mode: plan_tiles(h, w, c, k, stride=1, padding="SAME",
-                             pool=pool, groups=groups, in_bytes=1,
-                             out_bytes=1, cin_banks=cb, kout_banks=kb,
-                             vmem_budget=budget, kernel=mode)
-            for mode in ("sequential", "pipelined", "auto")
-        }
-        seq, pipe = plans["sequential"], plans["pipelined"]
-        # identical geometry and bytes — only the kernel choice differs
-        assert seq.working_set_bytes == pipe.working_set_bytes
-        assert (seq.h_tile, seq.w_tile, seq.cin_banks, seq.kout_banks) \
-            == (pipe.h_tile, pipe.w_tile, pipe.cin_banks, pipe.kout_banks)
-        assert not seq.pipelined and pipe.pipelined
+        p = plan_tiles(h, w, c, k, stride=1, padding="SAME", pool=pool,
+                       groups=groups, in_bytes=1, out_bytes=1,
+                       cin_banks=cb, kout_banks=kb, vmem_budget=budget)
+        assert p.pipelined == (p.n_slabs > 1)
+        assert p.n_slabs == p.n_tiles * p.cin_banks * p.kout_banks
         lay = banking.laid_out_bytes
-        for p in plans.values():
-            # explicit ping-pong buffers: 2 input + 2 weight + 2 output
-            # (+ bias/scale) slots, then the compute body's scratch and
-            # values, each as laid out in VMEM — first principles, must
-            # equal the planner's promise.  A width off the 8-row sublane
-            # tile folds the taps: accumulator over the window's padded
-            # width, tap patch (nine 128-lane column blocks), window and
-            # its flattened copy, one tap's rows, two accumulator-sized
-            # values; otherwise per-tap dots: the accumulator, window,
-            # one tap slice and four accumulator-sized values.
-            cgb, kgb = c // groups // p.cin_banks, k // p.kout_banks
-            th, tw = p.h_tile, p.w_tile
-            win = lay((p.in_h_tile if p.tiled else h + 2, w + 2, cgb), 1)
-            pth, ptw = (th // 2, tw // 2) if pool else (th, tw)
-            pingpong = 2 * (win + lay((3, 3, cgb, kgb), 1)
-                            + lay((pth, ptw, kgb), 1)
-                            + 2 * lay((1, kgb), 4))
-            if tw % 8:
-                wide = -(-(w + 2) // 8) * 8
-                rows = (th - 1) * wide + tw
-                acc = lay((th * wide, kgb), 4)
-                pingpong += (acc + lay((rows, 9 * 128), 1) + 2 * win
-                             + lay((rows, cgb), 1) + 2 * acc)
-            else:
-                acc = lay((th * tw, kgb), 4)
-                pingpong += acc + win + lay((th * tw, cgb), 1) + 4 * acc
-            assert p.working_set_bytes == pingpong
-            assert p.fits_vmem == (pingpong <= budget)
-            # legality under degradation, dense and grouped
-            assert (c // groups) % p.cin_banks == 0
-            assert k % p.kout_banks == 0 and p.kout_banks % groups == 0
-            assert p.w_tile == p.out_w
-            if groups == 1:
-                assert ref.lane_legal_banks(c, p.cin_banks)
-                assert ref.lane_legal_banks(k, p.kout_banks)
-            assert p.n_h_tiles * p.h_tile >= p.out_h
-            assert p.n_w_tiles * p.w_tile >= p.out_w
-            if pool:
-                assert p.h_tile % 2 == 0 and p.w_tile % 2 == 0
+        # explicit ping-pong buffers: 2 input + 2 weight + 2 output
+        # (+ bias/scale) slots, then the compute body's scratch and
+        # values, each as laid out in VMEM — first principles, must
+        # equal the planner's promise.  A width off the 8-row sublane
+        # tile folds the taps: accumulator over the window's padded
+        # width, tap patch (nine 128-lane column blocks), window and
+        # its flattened copy, one tap's rows, two accumulator-sized
+        # values; otherwise per-tap dots: the accumulator, window,
+        # one tap slice and four accumulator-sized values.
+        cgb, kgb = c // groups // p.cin_banks, k // p.kout_banks
+        th, tw = p.h_tile, p.w_tile
+        win = lay((p.in_h_tile if p.tiled else h + 2, w + 2, cgb), 1)
+        pth, ptw = (th // 2, tw // 2) if pool else (th, tw)
+        pingpong = 2 * (win + lay((3, 3, cgb, kgb), 1)
+                        + lay((pth, ptw, kgb), 1)
+                        + 2 * lay((1, kgb), 4))
+        if tw % 8:
+            wide = -(-(w + 2) // 8) * 8
+            rows = (th - 1) * wide + tw
+            acc = lay((th * wide, kgb), 4)
+            pingpong += (acc + lay((rows, 9 * 128), 1) + 2 * win
+                         + lay((rows, cgb), 1) + 2 * acc)
+        else:
+            acc = lay((th * tw, kgb), 4)
+            pingpong += acc + win + lay((th * tw, cgb), 1) + 4 * acc
+        assert p.working_set_bytes == pingpong
+        assert p.fits_vmem == (pingpong <= budget)
+        # legality under degradation, dense and grouped
+        assert (c // groups) % p.cin_banks == 0
+        assert k % p.kout_banks == 0 and p.kout_banks % groups == 0
+        assert p.w_tile == p.out_w
+        if groups == 1:
+            assert ref.lane_legal_banks(c, p.cin_banks)
+            assert ref.lane_legal_banks(k, p.kout_banks)
+        assert p.n_h_tiles * p.h_tile >= p.out_h
+        assert p.n_w_tiles * p.w_tile >= p.out_w
+        if pool:
+            assert p.h_tile % 2 == 0 and p.w_tile % 2 == 0
 
 
 # ---------------------------------------------------------------------------
-# Whole networks: every scheduler mode, planner-auto kernel choice
+# Whole networks: every scheduler mode, kernel choice from the plan
 # ---------------------------------------------------------------------------
 
 
@@ -323,14 +320,16 @@ def _net_setup(make):
 
 
 @pytest.mark.parametrize("mode", ["batch", "kout", "spatial"])
-def test_pipelined_network_bit_exact_all_scheduler_modes(mode):
-    """make_int8_program with kernel="pipelined" (every conv forced onto
-    conv2d_ws_pipe) is bit-identical to the sequential compile under all
-    three scheduler modes — the TilePlan.pipelined flag must survive the
-    shard-plan rewrites (kout re-banking, spatial slicing)."""
+def test_pipelined_network_bit_exact_all_scheduler_modes(mode, monkeypatch):
+    """make_int8_program with every conv forced onto conv2d_ws_pipe is
+    bit-identical to the all-sequential compile under all three
+    scheduler modes — the kernel choice must survive the shard-plan
+    rewrites (kout re-banking, spatial slicing)."""
     qnet, x8 = _net_setup(network.mobilenet_small)
     outs = []
-    for kernel in ("sequential", "pipelined"):
+    for forced in (False, True):
+        monkeypatch.setattr(banking.TilePlan, "pipelined",
+                            property(lambda self, v=forced: v))
         sched = scheduler.MultiCoreScheduler(
             scheduler.SchedulerConfig(n_cores=2, mode=mode))
         name = "pallas"
@@ -339,14 +338,14 @@ def test_pipelined_network_bit_exact_all_scheduler_modes(mode):
             register_backend(sb)
             name = sb.name
         program = network.make_int8_program(
-            qnet, ConvCoreConfig(backend=name, int8=True, kernel=kernel))
+            qnet, ConvCoreConfig(backend=name, int8=True))
         outs.append(sched.run(program, x8))
     np.testing.assert_array_equal(np.asarray(outs[0]), np.asarray(outs[1]))
 
 
 def test_auto_kernel_network_matches_ref():
-    """The default compile (kernel="auto" — the planner mixes variants
-    per layer) stays bit-exact against the ref backend."""
+    """The default compile (each layer's plan picks its kernel, so the
+    variants mix per layer) stays bit-exact against the ref backend."""
     qnet, x8 = _net_setup(network.mobilenet_small)
     a = network.make_int8_program(
         qnet, ConvCoreConfig(backend="pallas", int8=True))(x8)
@@ -356,7 +355,7 @@ def test_auto_kernel_network_matches_ref():
 
 
 # ---------------------------------------------------------------------------
-# The crossover predictor (no kernels: pure cost model — fast)
+# The slab rule and its §5.2 pricing (no kernels: pure cost model — fast)
 # ---------------------------------------------------------------------------
 
 
@@ -373,9 +372,8 @@ def test_pipeline_estimate_model_identities():
     """fill + steady-state + drain from first principles: with D = n·d
     and C = n·c exactly, pipelined = d + (n−1)·max(d,c) + c + n·overhead,
     sequential = D + C, and a 1-slab pipe is pure fill+drain+overhead."""
-    plan = plan_tiles(32, 32, 8, 8, in_bytes=1, out_bytes=1,
-                      kernel="sequential")
-    n = perfmodel.pipeline_slabs(plan)
+    plan = plan_tiles(32, 32, 8, 8, in_bytes=1, out_bytes=1)
+    n = plan.n_slabs
     psums = perfmodel.psum_count(32, 32, 8, 8)
     est = perfmodel.pipeline_estimate(plan, psums)
     d = -(-est["dma_cycles"] // n)
@@ -385,20 +383,21 @@ def test_pipeline_estimate_model_identities():
     assert est["pipelined_cycles"] == (
         d + (n - 1) * max(d, c) + c
         + n * perfmodel.PIPELINE_OVERHEAD_CYCLES)
-    assert est["profitable"] == (
-        est["pipelined_cycles"] < est["sequential_cycles"])
+    assert not plan.pipelined
+    assert est["cycles"] == est["sequential_cycles"]
     # perfect overlap bound: pipelining can never beat the slower phase
     assert est["pipelined_cycles"] >= max(est["dma_cycles"],
                                           est["compute_cycles"])
 
 
 def test_predictor_marks_depthwise_dma_bound_profitable():
-    """Acceptance: on every MobileNet zoo plan, each depthwise layer the
-    perf model flags dma_bound_board is marked pipelined-profitable (the
-    DMA-floor diagnosis converted into recovered throughput)."""
+    """On every MobileNet zoo plan, each depthwise layer the perf model
+    flags dma_bound_board sweeps one slab per group, so the slab rule
+    pipelines it, and the one-core model prices that as a gain."""
     for make in (network.mobilenet_small, network.mobilenet_v2ish):
         plan = make()
-        tps = plan.tile_plans()           # kernel="auto"
+        tps = plan.tile_plans()
+        by_name = dict(zip(plan.node_names(), tps))
         rep = perfmodel.network_report(plan.psum_table(), tile_plans=tps)
         geoms = dict(zip(plan.node_names(), plan.conv_geometries()))
         dw_rows = [r for r in rep["layers"]
@@ -406,36 +405,39 @@ def test_predictor_marks_depthwise_dma_bound_profitable():
                    and r.get("dma_bound_board")]
         assert dw_rows, "zoo plan must contain DMA-bound depthwise layers"
         for r in dw_rows:
+            assert by_name[r["name"]].n_slabs > 1, r
             assert r["pipelined"], r
             assert r["pipeline_speedup"] > 1.0, r
         assert rep["pipelined_layers"] >= len(dw_rows)
 
 
 def test_predictor_leaves_tiny_layers_sequential():
-    """Per-slab protocol overhead keeps the pipeline off layers with
-    almost nothing to overlap — auto must make a real choice, not a
-    constant one."""
-    tiny = plan_tiles(6, 6, 4, 4, kernel="auto")
-    assert not tiny.pipelined
+    """A one-slab sweep has no next slab to prefetch, so it stays on the
+    sequential kernel — the rule makes a real choice, not a constant
+    one."""
+    tiny = plan_tiles(6, 6, 4, 4)
+    assert tiny.n_slabs == 1 and not tiny.pipelined
     # one lane-legal bank each way: the slabs come from row tiles
-    big = plan_tiles(256, 256, 16, 16, kernel="auto")
-    assert big.n_tiles > 1 and big.pipelined
+    big = plan_tiles(256, 256, 16, 16)
+    assert big.cin_banks == big.kout_banks == 1
+    assert big.n_slabs == big.n_tiles > 1 and big.pipelined
 
 
 def test_network_report_prices_chosen_variant():
-    """Priced rows expose both variants and charge the chosen one; the
-    sequential total can only go down when the planner pipelines.
+    """Priced rows expose both variants and charge the one each row's
+    plan runs (``TilePlan.pipelined``, the slab rule); against an
+    all-sequential pricing of the same plans the one-core total can only
+    go down.
 
-    The planner ranks the variants on one core's cycles.  On the 20-core
-    board compute divides by 20 and the shared DMA interface does not,
-    so a depthwise row — one slab per group — has almost nothing left to
-    overlap and loses to the 16-cycle per-slab overhead (mobilenet_small
-    d1: 8 slabs, 645 board cycles sequential, 727 pipelined).  Dense rows
-    gain on the board: vgg_imagenet, whose pipelined rows are all dense,
-    keeps the full-board total at or below the sequential one.
-    mobilenet_small's pointwise rows are one lane-legal bank (one slab)
-    and stay sequential, so nothing offsets its depthwise losses: its
-    board total rises by exactly their sum."""
+    On the 20-core board compute divides by 20 and the shared DMA
+    interface does not, so a depthwise row — one slab per group — has
+    almost nothing left to overlap and loses to the 16-cycle per-slab
+    overhead (mobilenet_small d1: 8 slabs, 645 board cycles sequential,
+    727 pipelined).  Dense rows gain on the board: vgg_imagenet, whose
+    pipelined rows are all dense, keeps the full-board total at or below
+    the sequential one.  mobilenet_small's pointwise rows are one
+    lane-legal bank (one slab) and stay sequential, so nothing offsets
+    its depthwise losses: its board total rises by exactly their sum."""
     board = perfmodel.IPCoreConfig(ip_cores=20)
     board_losers = {"mobilenet_small": {"d1", "d2", "d3"},
                     "vgg_imagenet": set()}
@@ -443,22 +445,28 @@ def test_network_report_prices_chosen_variant():
         plan = getattr(network, name)()
         table, plans = plan.psum_table(), plan.tile_plans()
         auto = perfmodel.network_report(table, tile_plans=plans)
-        seq = perfmodel.network_report(
-            table, tile_plans=plan.tile_plans(kernel="sequential"))
-        assert auto["pipelined_layers"] > 0 and seq["pipelined_layers"] == 0
-        assert auto["cycles"] < seq["cycles"]
-        board_delta, lost = 0, set()
+        assert auto["pipelined_layers"] > 0
+        seq_total = seq_board = board_delta = 0
+        lost = set()
         for r, tp in zip(auto["layers"], plans):
             if "pipelined" not in r:
+                seq_total += r["cycles"]
+                seq_board += perfmodel.cycles(r["psums"], board)
                 continue
+            assert r["pipelined"] == tp.pipelined == (tp.n_slabs > 1)
             chosen = (r["cycles_pipelined"] if r["pipelined"]
                       else r["cycles_sequential"])
+            est = perfmodel.pipeline_estimate(tp, r["psums"], board)
             if r["psums"]:
                 assert r["cycles"] == chosen
                 assert chosen == min(r["cycles_pipelined"],
                                      r["cycles_sequential"])
+                seq_total += r["cycles_sequential"]
+                seq_board += est["sequential_cycles"]
+            else:
+                seq_total += r["cycles"]
+                seq_board += r["dma_cycles"]
             if r["psums"] and r["pipelined"]:
-                est = perfmodel.pipeline_estimate(tp, r["psums"], board)
                 delta = est["pipelined_cycles"] - est["sequential_cycles"]
                 board_delta += delta
                 if delta > 0:
@@ -469,15 +477,91 @@ def test_network_report_prices_chosen_variant():
             assert r["cycles_sequential"] >= r["dma_cycles"]
             assert r["cycles_pipelined"] >= r["dma_cycles"]
         assert lost == losers, name
-        assert (auto["full_board"]["cycles"] - seq["full_board"]["cycles"]
-                == board_delta), name
+        assert auto["cycles"] < seq_total, name
+        assert auto["full_board"]["cycles"] - seq_board == board_delta, name
         if not losers:
-            assert auto["full_board"]["cycles"] <= seq["full_board"]["cycles"]
+            assert auto["full_board"]["cycles"] <= seq_board
 
 
-def test_forced_kernel_modes():
-    p_seq = plan_tiles(32, 32, 8, 8, kernel="sequential")
-    p_pipe = plan_tiles(32, 32, 8, 8, kernel="pipelined")
-    assert not p_seq.pipelined and p_pipe.pipelined
-    with pytest.raises(ValueError):
-        plan_tiles(32, 32, 8, 8, kernel="bogus")
+# ---------------------------------------------------------------------------
+# The slab rule: TilePlan.pipelined ⇔ n_slabs > 1
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("geom, n_slabs", [
+    # (h, w, c, k, th, cin_banks, kout_banks, groups)
+    ((8, 8, 8, 8, 8, 1, 1, 1), 1),            # one slab
+    ((16, 16, 8, 8, 4, 1, 1, 1), 4),          # four row tiles
+    ((8, 8, 256, 128, 8, 2, 1, 1), 2),        # two cin banks
+    ((8, 8, 128, 256, 8, 1, 2, 1), 2),        # two kout banks
+    ((8, 8, 8, 8, 8, 1, 2, 2), 2),            # grouped, a kout bank per group
+], ids=["one_slab", "row_tiles", "two_cin_banks", "two_kout_banks",
+        "grouped_two_kout_banks"])
+def test_slab_rule(geom, n_slabs):
+    h, w, c, k, th, cb, kb, groups = geom
+    plan = banking.tile_plan(h, w, c, k, 3, 3, th, w, cb, kb,
+                             padding="SAME", groups=groups)
+    assert plan.n_slabs == plan.n_tiles * cb * kb == n_slabs
+    assert plan.pipelined == (n_slabs > 1)
+
+
+_ROOT = os.path.abspath(os.path.join(os.path.dirname(__file__), os.pardir))
+
+
+@functools.lru_cache(maxsize=None)
+def _harness_plan(name):
+    """The benchmark configuration's plan, as its harness builds it, and
+    the tile plans its int8 program runs."""
+    if _ROOT not in sys.path:
+        sys.path.insert(0, _ROOT)
+    from perfbench.harness.model import build_plan
+    with open(os.path.join(_ROOT, "perfbench", "configs",
+                           f"{name}.json")) as f:
+        plan = build_plan(json.load(f))
+    return plan, network.program_tile_plans(plan, ConvCoreConfig(int8=True))
+
+
+_RESNET50_ONE_SLAB = ("res2a_1", "res2a_2", "res2b_2", "res2c_2", "res3a_2",
+                      "res3b_2", "res3c_2", "res3d_2")
+
+
+@pytest.mark.parametrize("name, sequential", [
+    ("vgg16", ()), ("unet", ()), ("resnet50", _RESNET50_ONE_SLAB)],
+    ids=["vgg16", "unet", "resnet50"])
+def test_benchmark_configs_sequential_layers(name, sequential):
+    """Which conv layers of the benchmark's configurations run on
+    conv2d_ws: exactly the ones whose plan sweeps a single slab."""
+    plan, tps = _harness_plan(name)
+    convs = [(n, tp) for n, tp in zip(plan.node_names(), tps)
+             if tp is not None]
+    assert convs
+    assert tuple(n for n, tp in convs if not tp.pipelined) == sequential
+    assert all(tp.pipelined == (tp.n_slabs > 1) for _, tp in convs)
+
+
+@pytest.mark.parametrize("layer", ["res2a_1", "res2a_2", "res3a_2",
+                                   "res3b_2"])
+def test_resnet50_one_slab_layers_kernel_parity(layer):
+    """Both kernels give the same int8 map, bit for bit, on ResNet-50's
+    one-slab layers at their own shapes and plans (batch 1, fused ReLU
+    and requantize)."""
+    plan, tps = _harness_plan("resnet50")
+    names = plan.node_names()
+    i = names.index(layer)
+    sp, tp = plan.layers[i], tps[i]
+    src = plan.resolved_inputs()[i][0]
+    h, w, c = plan.activation_shapes()[src]
+    assert tp.n_slabs == 1
+    rng = np.random.default_rng(i)
+    x = jnp.asarray(rng.integers(-128, 128, (1, h, w, c)), jnp.int8)
+    wt = jnp.asarray(rng.integers(-128, 128,
+                                  (*sp.kernel, c, sp.features)), jnp.int8)
+    b = jnp.asarray(rng.integers(-2048, 2048, (sp.features,)), jnp.int32)
+    run = functools.partial(
+        ops.conv2d, x, wt, b, stride=sp.stride, padding=sp.padding,
+        cin_banks=tp.cin_banks, kout_banks=tp.kout_banks, relu=sp.relu,
+        out_scale=jnp.float32(2.0 ** -12))
+    seq, pipe = run(pipelined=False), run(pipelined=True)
+    assert seq.dtype == jnp.int8 and seq.shape == (1, tp.out_h, tp.out_w,
+                                                   sp.features)
+    np.testing.assert_array_equal(np.asarray(seq), np.asarray(pipe))
